@@ -41,11 +41,17 @@ class _WindowStaging:
     is asynchronous, ``slots >= prefetch_depth + 1`` rings guarantee a
     buffer is never rewritten while a previous transfer could still be
     copying from it.
+
+    That holds where the transfer COPIES (an accelerator). On the CPU
+    backend ``jax.device_put`` of an aligned numpy array is zero-copy —
+    the device array IS the host buffer, for as long as it lives — so a
+    slot :meth:`transferred` there is retired: its memory now belongs to
+    the device array and the slot allocates anew on its next turn.
     """
 
     def __init__(self, slots: int):
         self._slots = max(2, int(slots))
-        self._rings: Dict[tuple, List[Dict[str, np.ndarray]]] = {}
+        self._rings: Dict[tuple, List[Optional[Dict[str, np.ndarray]]]] = {}
         self._idx: Dict[tuple, int] = {}
 
     def stack(self, batches: List[Dict[str, np.ndarray]]) -> Dict[str, np.ndarray]:
@@ -56,22 +62,39 @@ class _WindowStaging:
         )
         ring = self._rings.get(sig)
         if ring is None:
-            ring = [
-                {
-                    key: np.empty((k,) + v.shape, v.dtype)
-                    for key, v in first.items()
-                }
-                for _ in range(self._slots)
-            ]
-            self._rings[sig] = ring
+            ring = self._rings[sig] = [None] * self._slots
             self._idx[sig] = 0
         i = self._idx[sig]
         self._idx[sig] = (i + 1) % len(ring)
         buf = ring[i]
+        if buf is None:  # first turn, or retired on its last one
+            buf = ring[i] = {
+                key: np.empty((k,) + v.shape, v.dtype)
+                for key, v in first.items()
+            }
         for j, b in enumerate(batches):
             for key, v in b.items():
                 buf[key][j] = v
         return buf
+
+    def transferred(self, buf: Dict[str, np.ndarray], out) -> None:
+        """Tell the ring that ``buf`` went to the device as ``out``. Where
+        any leaf of ``out`` lives on a CPU-platform device the transfer
+        may have been zero-copy, so ``buf``'s memory is given away: the
+        slot that held it allocates anew on its next turn. A ``buf`` that
+        is not a ring slot is ignored."""
+        import jax
+
+        if not any(
+            d.platform == "cpu"
+            for leaf in jax.tree.leaves(out)
+            for d in leaf.devices()
+        ):
+            return
+        for ring in self._rings.values():
+            for i, slot in enumerate(ring):
+                if slot is buf:
+                    ring[i] = None
 
 
 def normalize_images(batch: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
@@ -312,14 +335,21 @@ class TrainPipeline:
         import jax
 
         if self.mesh is None:
-            return jax.device_put(batch) if window else batch
-        shardings = self._shardings(batch, window=window)
-        if self.process_count > 1:
-            return {
-                k: jax.make_array_from_process_local_data(shardings[k], v)
-                for k, v in batch.items()
-            }
-        return jax.device_put(batch, shardings)
+            if not window:
+                return batch
+            out = jax.device_put(batch)
+        else:
+            shardings = self._shardings(batch, window=window)
+            if self.process_count > 1:
+                out = {
+                    k: jax.make_array_from_process_local_data(shardings[k], v)
+                    for k, v in batch.items()
+                }
+            else:
+                out = jax.device_put(batch, shardings)
+        if window and self._staging is not None:
+            self._staging.transferred(batch, out)
+        return out
 
     def _make_windows(self) -> Iterator[Dict[str, np.ndarray]]:
         """Stack ``window_size`` consecutive batches into one staged tree."""

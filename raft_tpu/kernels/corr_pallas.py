@@ -35,10 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept both
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 from raft_tpu.models.corr import CorrBlock
 
@@ -162,7 +158,7 @@ def fused_volume_pyramid(
         out_shape=out_shapes,
         grid_spec=grid_spec,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # the VMEM-resident fmap2 plus double-buffered level-0 output
             # blocks exceed the 16 MB default at Sintel scale
             vmem_limit_bytes=96 * 1024 * 1024,

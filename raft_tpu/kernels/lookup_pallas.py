@@ -39,10 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept both
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 __all__ = ["lookup_pyramid_pallas"]
 
@@ -152,7 +148,7 @@ def lookup_pyramid_pallas(
             (tq, num_levels * s * s), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # the unrolled per-tap loop keeps ~S volume-tile temporaries on
             # the VMEM stack; the 16 MB default is too tight at useful tiles
             vmem_limit_bytes=100 * 1024 * 1024,

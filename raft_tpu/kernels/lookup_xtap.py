@@ -73,14 +73,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept both
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from raft_tpu.models.corr import CorrBlock, lookup_pyramid, project_taps
 
@@ -93,24 +88,6 @@ __all__ = [
 
 # lane-dim gathers address at most one 128-lane register row
 MAX_LANES = 128
-
-# Whether this jax carries the def_partition API the partition rule needs
-# (``sharding_rule``/``need_replication_factors``). On older jax the rule
-# cannot be registered — and compiling ANY custom_partitioning-wrapped
-# call composed with a mesh segfaults XLA on the old-API path — so
-# :func:`_partitioned_xtap` then skips the wrapper entirely: single-device
-# fused kernels are unaffected (the wrapper is an identity there), while
-# mesh composition replicates the lookup. Tests and the multichip dryrun
-# gate their mesh x fused coverage on this flag.
-try:
-    import inspect as _inspect
-
-    PARTITION_RULE_ACTIVE = (
-        "sharding_rule"
-        in _inspect.signature(custom_partitioning.def_partition).parameters
-    )
-except (TypeError, ValueError):  # pragma: no cover - exotic jax builds
-    PARTITION_RULE_ACTIVE = False
 
 # widest y-dot level the kernel accepts: wider levels would need more than
 # 4 chunked gathers per tap row and fall back to the XLA separable path
@@ -449,7 +426,7 @@ def _xtap_project_kernel(
 class _XtapStatic(NamedTuple):
     """Hashable static config of one x-tap pallas_call: everything the
     kernel needs besides the operand arrays themselves. One instance keys
-    one :func:`_partitioned_xtap` custom-partitioning op (lru-cached), and
+    one :func:`_partitioned_xtap` mesh-aware call (lru-cached), and
     :func:`_invoke_xtap` rebuilds the pallas_call from it at ANY query
     count — the global q in a single-device trace, the per-shard q when
     GSPMD partitions the op over a mesh."""
@@ -525,7 +502,7 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
         for t in ts
     ] + [pl.BlockSpec((tq, f.shape[1]), lambda i: (i, 0)) for f in flats]
     out_dtype = jnp.dtype(st.out_dtype) if st.out_dtype else jnp.float32
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         # double-buffered row blocks exceed the 16 MB default; the
         # ydot-in-kernel variant additionally stages raw volume blocks +
         # the batched dot's padded operands (measured 65.5 MB at batch 8),
@@ -570,12 +547,10 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
 
 
 def _partition_dim0(mesh, dim0, q: int):
-    """The q-axis sharding the partition rule will actually use: ``dim0``
-    (the propagated mesh axes) when q divides evenly over them, else
-    ``None`` — replicate rather than let the kernel see padded rows
-    (correctness over parallelism for odd shapes; JAX itself rejects
-    uneven shardings at jit boundaries, this guards internally proposed
-    ones)."""
+    """The q-axis sharding the kernel will actually use under ``mesh``:
+    ``dim0`` (mesh axis name or tuple of names) when q divides evenly
+    over it, else ``None`` — run the kernel whole rather than let it see
+    padded rows (correctness over parallelism for odd shapes)."""
     if dim0 is None:
         return None
     axes = dim0 if isinstance(dim0, tuple) else (dim0,)
@@ -587,107 +562,52 @@ def _partition_dim0(mesh, dim0, q: int):
 
 @functools.lru_cache(maxsize=None)
 def _partitioned_xtap(st: _XtapStatic):
-    """The x-tap pallas_call wrapped in ``custom_partitioning``.
+    """The x-tap pallas_call, ``shard_map``-ped over the ambient mesh.
 
-    GSPMD cannot see inside a TPU custom call, so without a rule the SPMD
-    partitioner would replicate the kernel (all-gathering its operands)
-    under a mesh — the exact failure mode VERDICT r3 flagged for the
-    fused-deployment x multi-chip composition. The rule below states what
-    is true of the kernel: every query row is independent, all q-carrying
-    operands (cents, ts, flats) shard identically on dim 0, everything
-    else (projection weights, bias, dequant scales, the tap/lane dims)
-    must be replicated. The per-shard lowering is just
-    :func:`_invoke_xtap` at the local q — same kernel, smaller grid.
+    The SPMD partitioner cannot see inside a TPU custom call, so under a
+    mesh it would replicate the kernel (all-gathering its operands). What
+    is true of the kernel: every query row is independent, all
+    q-carrying operands (cents, ts, flats) shard identically on dim 0,
+    everything else (projection weights, bias, dequant scales, the
+    tap/lane dims) is replicated. So when the program is traced under an
+    ambient mesh (``parallel.mesh.traced_under`` — the sharded step and
+    serve programs enter it), the call is a ``shard_map`` of
+    :func:`_invoke_xtap` over ALL mesh axes on the q dim: same kernel,
+    local q, smaller grid. (``custom_partitioning`` expressed the same
+    rule without needing the mesh, but the TPU compiler has no
+    partitioner for it: "Custom emitter for CustomSPMDPartitioning not
+    found".)
 
-    Falls back to full replication when q does not divide evenly over the
-    proposed axes (the partitioner then inserts the reshards), so odd
-    shapes stay correct, merely unpartitioned."""
-    if not PARTITION_RULE_ACTIVE:
-        # old-jax def_partition cannot take the rule, and its legacy
-        # code path segfaults XLA when the wrapped call compiles under a
-        # mesh — return the bare kernel instead: identical single-device
-        # behavior, replicated (correct, unpartitioned) under sharding.
-        return functools.partial(_invoke_xtap, st)
+    With no ambient mesh this is the bare kernel. When q does not divide
+    evenly over the mesh the kernel also runs whole (the partitioner then
+    inserts the reshards), so odd shapes stay correct, merely
+    unpartitioned."""
     nt, nf = len(st.widths), len(st.flat_levels)
     n_pre = 1 + (2 if st.project else 0) + (1 if st.has_scales else 0)
     n_args = n_pre + nt + nf
     q_positions = (0,) + tuple(range(n_pre, n_args))
-    # ranks: cents (q,2); w_mat (C,K) + bias (1,K); scales (1,L); ts
-    # (q,S,wl); flats (q,F)
-    ranks = (
-        [2] + ([2, 2] if st.project else []) + ([2] if st.has_scales else [])
-        + [3] * nt + [2] * nf
-    )
 
     def call(*arrays):
-        return _invoke_xtap(st, *arrays)
-
-    f = custom_partitioning(call)
-
-    # Shardy rule: factor 'q' ties every query dim; all other dims get
-    # unique need-replication factors (the kernel consumes whole rows).
-    fresh = iter(f"f{k}" for k in range(sum(ranks) + 1))
-    repl = []
-    op_strs = []
-    for pos, rank in enumerate(ranks):
-        facs = []
-        for d in range(rank):
-            if d == 0 and pos in q_positions:
-                facs.append("q")
-            else:
-                name = next(fresh)
-                repl.append(name)
-                facs.append(name)
-        op_strs.append(" ".join(facs))
-    res_fac = next(fresh)
-    repl.append(res_fac)
-    rule = f"{', '.join(op_strs)} -> q {res_fac}"
-
-    def _dim0(arg_shapes):
-        """The mesh axes the q dim is sharded over (None = unsharded)."""
-        for p in q_positions:
-            spec = arg_shapes[p].sharding.spec
-            if len(spec) and spec[0] is not None:
-                return spec[0]
-        return None
-
-    def _arg_shardings(mesh, dim0):
-        return tuple(
-            NamedSharding(
-                mesh,
-                P(*([dim0 if (d == 0 and pos in q_positions) else None
-                     for d in range(rank)])),
+        mesh = jax.sharding.get_abstract_mesh()
+        dim0 = None
+        if not mesh.empty and mesh.size > 1:
+            dim0 = _partition_dim0(
+                mesh, tuple(mesh.axis_names), arrays[0].shape[0]
             )
-            for pos, rank in enumerate(ranks)
-        )
-
-    def partition(mesh, arg_shapes, result_shape):
-        dim0 = _partition_dim0(mesh, _dim0(arg_shapes), arg_shapes[0].shape[0])
-        def lower_fn(*arrays):
+        if dim0 is None:
             return _invoke_xtap(st, *arrays)
-        return (
-            mesh,
-            lower_fn,
-            NamedSharding(mesh, P(dim0, None)),
-            _arg_shardings(mesh, dim0),
-        )
+        return jax.shard_map(
+            functools.partial(_invoke_xtap, st),
+            mesh=mesh,
+            in_specs=tuple(
+                P(dim0) if pos in q_positions else P()
+                for pos in range(n_args)
+            ),
+            out_specs=P(dim0),
+            check_vma=False,  # pallas_call outputs carry no vma
+        )(*arrays)
 
-    def infer_sharding(mesh, arg_shapes, result_shape):
-        # same divisibility guard as partition(): otherwise, for uneven q,
-        # the inferred sharding would disagree with the actually-replicated
-        # lowering and GSPMD would insert wasteful reshards
-        dim0 = _partition_dim0(
-            mesh, _dim0(arg_shapes), arg_shapes[0].shape[0]
-        )
-        return NamedSharding(mesh, P(dim0, None))
-
-    f.def_partition(
-        partition,
-        infer_sharding_from_operands=infer_sharding,
-        sharding_rule=rule,
-        need_replication_factors=tuple(repl),
-    )
-    return f
+    return call
 
 
 def lookup_pyramid_fused(

@@ -318,8 +318,19 @@ class RAFT(nn.Module):
             raise ValueError("context output must match the feature grid")
 
         pyramid = self.corr_block.build_pyramid(fmap1, fmap2)
-        pyramid = tuple(
-            lvl.reshape((b, h8 * w8) + lvl.shape[1:]) for lvl in pyramid
+        packed = isinstance(pyramid, dict)  # the fused block's packed form
+        if packed and "scales" in pyramid:
+            # int8 dequant scales are ONE (1, L) row per build (amax over
+            # the whole batch) — they cannot be held per slot
+            raise ValueError(
+                "corr_dtype='int8' pyramids cannot live in the resident "
+                "slot pool (per-build dequant scales); serve int8 with "
+                "pool_capacity=0"
+            )
+        # every leaf (levels, and the packed form's flat rows) is q-major
+        pyramid = jax.tree.map(
+            lambda lvl: lvl.reshape((b, h8 * w8) + lvl.shape[1:]),
+            pyramid if packed else tuple(pyramid),
         )
 
         hidden_size = self.update_block.hidden_state_size
@@ -358,10 +369,14 @@ class RAFT(nn.Module):
         """
         coords1 = state["coords1"]
         b, h8, w8, _ = coords1.shape
-        pyramid = [
-            lvl.reshape((lvl.shape[0] * lvl.shape[1],) + lvl.shape[2:])
-            for lvl in state["pyramid"]
-        ]
+        pyramid = jax.tree.map(
+            lambda lvl: lvl.reshape(
+                (lvl.shape[0] * lvl.shape[1],) + lvl.shape[2:]
+            ),
+            state["pyramid"],
+        )
+        if not isinstance(pyramid, dict):
+            pyramid = list(pyramid)
         body = partial(
             _refinement_step,
             coords0=coords_grid(b, h8, w8),

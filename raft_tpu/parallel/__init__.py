@@ -8,6 +8,7 @@ from raft_tpu.parallel.mesh import (
     make_mesh,
     replicated,
     shard_batch,
+    traced_under,
     window_batch_sharding,
 )
 from raft_tpu.parallel.serve_shard import (
@@ -29,6 +30,7 @@ __all__ = [
     "make_mesh",
     "replicated",
     "shard_batch",
+    "traced_under",
     "window_batch_sharding",
     "make_serve_mesh",
     "row_sharding",

@@ -21,6 +21,7 @@ pipelines should feed ``jax.process_index()``-local shards
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -33,6 +34,7 @@ __all__ = [
     "batch_sharding",
     "replicated",
     "shard_batch",
+    "traced_under",
     "window_batch_sharding",
     "BATCH_SPEC",
     "WINDOW_BATCH_SPEC",
@@ -79,9 +81,10 @@ def make_mesh(
     lands on physically adjacent chips (halo exchanges ride neighbor ICI
     links) and (b) the ``data`` all-reduce maps onto torus rings instead of
     whatever order ``jax.devices()`` happens to enumerate. On virtual/CPU
-    device sets (tests, the driver's host-platform dryrun) ``mesh_utils``
-    has no topology to read and we fall back to a plain row-major reshape —
-    identical behavior to before, and placement is meaningless there anyway.
+    device sets (tests, the host-platform dryrun) ``mesh_utils`` has no
+    topology to read and we fall back to a plain row-major reshape —
+    placement is meaningless there. On TPU devices a ``mesh_utils`` failure
+    raises: no quiet enumeration-order mesh on the chip.
     """
     devs = list(devices if devices is not None else jax.devices())
     if data is None:
@@ -91,25 +94,40 @@ def make_mesh(
     n = data * space
     if n > len(devs):
         raise ValueError(f"mesh {data}x{space} needs {n} devices, have {len(devs)}")
+    from jax.experimental import mesh_utils
+
     try:
-        from jax.experimental import mesh_utils
-
         grid = mesh_utils.create_device_mesh((data, space), devices=devs[:n])
-    except Exception as e:
-        # non-TPU (CPU/virtual) device sets or topologies mesh_utils cannot
-        # factor — sequential order is the best available assignment there.
-        # On a real TPU slice this fallback silently degrades collective/halo
-        # placement, so it must be visible, never silent.
+    except Exception:
+        # CPU/virtual device sets have no topology for mesh_utils to
+        # factor — sequential order is the only assignment there. On TPU
+        # devices enumeration order would silently degrade collective/halo
+        # placement, so the failure propagates instead.
         if any(d.platform == "tpu" for d in devs[:n]):
-            import warnings
-
-            warnings.warn(
-                f"mesh_utils.create_device_mesh failed on a TPU slice "
-                f"({e!r}); falling back to enumeration-order placement — "
-                "all-reduce/halo traffic may not ride adjacent ICI links"
-            )
+            raise
         grid = np.asarray(devs[:n]).reshape(data, space)
     return Mesh(grid, ("data", "space"))
+
+
+def traced_under(mesh: Optional[Mesh], fn):
+    """``fn``, traced with ``mesh`` as the ambient (abstract) mesh.
+
+    The sharded step and serve programs wrap their bodies in this so
+    code deep inside the model that must know the mesh at trace time —
+    the fused lookup kernel ``shard_map``s itself over it
+    (``kernels.lookup_xtap._partitioned_xtap``) — can read it from
+    ``jax.sharding.get_abstract_mesh()``. The context is entered INSIDE
+    the traced function, so ``jax.jit(...)``'s own ``lower``/cache
+    surface is untouched. ``mesh=None`` returns ``fn`` unchanged."""
+    if mesh is None:
+        return fn
+
+    @functools.wraps(fn)
+    def inner(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return inner
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

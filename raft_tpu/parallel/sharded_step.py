@@ -17,7 +17,7 @@ import optax
 from jax.sharding import Mesh
 
 from raft_tpu.parallel.mesh import (
-    batch_sharding, replicated, window_batch_sharding,
+    batch_sharding, replicated, traced_under, window_batch_sharding,
 )
 from raft_tpu.train.state import TrainState
 
@@ -60,7 +60,7 @@ def make_sharded_train_step(
     rep = replicated(mesh)
     bsh = batch_sharding(mesh)
     return jax.jit(
-        step_fn,
+        traced_under(mesh, step_fn),
         in_shardings=(rep, bsh),
         out_shardings=(rep, rep),
         donate_argnums=(0,) if donate else (),
@@ -104,7 +104,7 @@ def make_sharded_window_step(
     )
     rep = replicated(mesh)
     return jax.jit(
-        fn,
+        traced_under(mesh, fn),
         in_shardings=(rep, window_batch_sharding(mesh)),
         out_shardings=(rep, rep),
         donate_argnums=(0,) if donate else (),

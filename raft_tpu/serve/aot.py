@@ -54,13 +54,14 @@ import pickle
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu.serve.errors import ArtifactMismatch
+from raft_tpu.utils.runtime import enable_persistent_cache
 
 __all__ = [
     "ProgramSpec",
@@ -150,21 +151,6 @@ def compile_events() -> int:
     _ensure_listener()
     with _events_lock:
         return _backend_compiles
-
-
-def enable_persistent_cache(cache_dir: str) -> None:
-    """Wire the JAX persistent compilation cache at ``cache_dir``.
-
-    Process-global config (every jit in the process benefits); must run
-    before the programs it should capture compile. Thresholds are
-    dropped to zero because serve programs are exactly the thing worth
-    caching — the default min-compile-time heuristic is tuned for
-    notebooks, not replica boot.
-    """
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 # ---------------------------------------------------------------------------
@@ -533,10 +519,18 @@ def load_artifact(path: str, engine_fp: Optional[Dict[str, Any]] = None):
 
 
 def load_programs(
-    artifact: Dict[str, Any], keys: Optional[List[ProgramKey]] = None
+    artifact: Dict[str, Any],
+    devices: Sequence[Any],
+    keys: Optional[List[ProgramKey]] = None,
 ) -> Dict[ProgramKey, Any]:
     """Deserialize executables from a loaded artifact (``keys=None``
-    loads everything; passing the live spec keys skips stale extras)."""
+    loads everything; passing the live spec keys skips stale extras).
+
+    ``devices`` are the ones the programs execute on
+    (``engine.dispatch_devices``). Left to JAX's default — every device
+    of the backend — a one-device program loaded in a process that sees
+    more (a 4-chip host, the 8-virtual-device test mesh) refuses its
+    arguments at the first run ("expected ... 8 shards")."""
     from jax.experimental import serialize_executable
 
     programs = artifact["programs"]
@@ -547,7 +541,7 @@ def load_programs(
     for k in wanted:
         payload, in_tree, out_tree = programs[k]
         out[k] = serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree
+            payload, in_tree, out_tree, execution_devices=list(devices)
         )
     return out
 
@@ -570,7 +564,9 @@ def warm_engine(engine) -> Dict[str, Any]:
     if cfg.warmup_artifact:
         try:
             art = load_artifact(cfg.warmup_artifact, fingerprint(engine))
-            execs = load_programs(art, [s.key for s in specs])
+            execs = load_programs(
+                art, engine.dispatch_devices, [s.key for s in specs]
+            )
             loaded = len(execs)
         except ArtifactMismatch as e:
             # degrade to compile, never refuse to boot

@@ -209,7 +209,10 @@ class ServeConfig:
             replica that must compile (first boot, artifact mismatch)
             pays XLA compilation only once per (program, jaxlib,
             backend) across process restarts. Process-global JAX config;
-            ``None`` leaves the cache untouched.
+            ``None`` leaves the cache untouched. When the environment
+            sets ``JAX_COMPILATION_CACHE_DIR`` that directory is used
+            and a differing path here is ignored with one log line
+            (``aot.enable_persistent_cache``).
         warmup_workers: thread-pool width for concurrent AOT compilation
             during warmup/artifact build (independent programs compile in
             parallel); 0 = auto (``min(8, cpu_count)``).

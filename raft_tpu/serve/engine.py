@@ -339,7 +339,8 @@ class ServeEngine:
         self._logger = logger
         if cfg.compilation_cache_dir:
             # the fallback boot tier: wire the JAX persistent compile
-            # cache before anything here can compile (process-global)
+            # cache before anything here can compile (process-global;
+            # JAX_COMPILATION_CACHE_DIR, when set, wins over this path)
             aot.enable_persistent_cache(cfg.compilation_cache_dir)
         self._router = BucketRouter(cfg.buckets)
         self._queue = MicroBatchQueue(
@@ -419,11 +420,17 @@ class ServeEngine:
                 "out_shardings": self._row_sharding,
             }
 
+        # on the serve mesh every program body is traced under it, so the
+        # fused lookup kernel shard_maps itself over the mesh's rows
+        from raft_tpu.parallel.mesh import traced_under
+
+        apply = traced_under(self._mesh, model.apply)
+
         def _pair_fwd(variables, p1, p2, num_flow_updates):
             # positional static arg: pjit rejects kwargs once explicit
             # in_shardings are given (the mesh path), and the AOT lowering
             # passes the iteration count as a plain value either way
-            return model.apply(
+            return apply(
                 variables, p1, p2, train=False, emit_all=False,
                 num_flow_updates=num_flow_updates,
             )
@@ -476,12 +483,12 @@ class ServeEngine:
         self._encode = self._iterate = None
         if cfg.stream_cache_size > 0:
             self._encode = jax.jit(
-                partial(model.apply, train=False, method="encode_frame"),
+                partial(apply, train=False, method="encode_frame"),
                 **_sh("rep", "row"),
             )
             if cfg.pool_capacity == 0:
                 def _iterate_fwd(variables, f1, f2, ctx, num_flow_updates):
-                    return model.apply(
+                    return apply(
                         variables, f1, f2, ctx, train=False, emit_all=False,
                         method="iterate", num_flow_updates=num_flow_updates,
                     )
@@ -633,6 +640,17 @@ class ServeEngine:
         artifact fingerprint keys on this, so an artifact built at one
         mesh size refuses — typed, degrading to compile — at another."""
         return self.config.mesh_devices
+
+    @property
+    def dispatch_devices(self) -> list:
+        """The devices this engine's programs execute on, in assignment
+        order: the serve mesh's, or the one device that holds the
+        weights. A warmup artifact's executables are loaded for exactly
+        these (``aot.load_programs``) — not for every device the process
+        can see."""
+        if self._mesh is not None:
+            return list(self._mesh.devices.flat)
+        return list(jax.tree.leaves(self._dev_vars)[0].devices())
 
     def _pad_rows(self, x: np.ndarray) -> np.ndarray:
         """Pad a (1, ...) single-row dispatch to the smallest mesh rung.
@@ -837,6 +855,9 @@ class ServeEngine:
             # smoke check exists exactly so a bad artifact costs boot
             # time, never readiness (docs/failure_model.md)
             self._aot_execs = {}
+            # the failed smoke may have left a resident slot table whose
+            # buffers carry the failed dispatch's error: allocate anew
+            self._pools.clear()
             specs = aot.program_specs(self)
             self._aot_execs = aot.compile_programs(
                 specs, self.config.warmup_workers

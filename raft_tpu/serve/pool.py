@@ -231,6 +231,11 @@ class PoolPrograms:
             return kw
 
         R = self.resid_len
+        # traced under the serve mesh so the fused lookup kernel
+        # shard_maps itself over the slot rows (identity off-mesh)
+        from raft_tpu.parallel.mesh import traced_under
+
+        apply = traced_under(mesh, model.apply)
 
         def _with_hist(rows):
             # admission rows start with a sentinel-seeded residual
@@ -249,7 +254,7 @@ class PoolPrograms:
 
         self.begin_pair = jax.jit(
             lambda variables, image1, image2: _with_hist(
-                model.apply(
+                apply(
                     variables, image1, image2, train=False,
                     method="begin_pair",
                 )
@@ -263,7 +268,7 @@ class PoolPrograms:
         self.begin_features = jax.jit(
             lambda variables, fmap1, fmap2, context_out, init_flow: (
                 _with_hist(
-                    model.apply(
+                    apply(
                         variables, fmap1, fmap2, context_out,
                         init_flow=init_flow, train=False,
                         method="begin_refinement",
@@ -274,7 +279,7 @@ class PoolPrograms:
         )
 
         def _step(variables, state, thresh, streak, min_iters):
-            out = model.apply(variables, state, train=False,
+            out = apply(variables, state, train=False,
                               method="iterate_step")
             # Convergence telemetry (ISSUE 11): per-slot RMS of this
             # iteration's flow update (1/8-grid pixels), rolled into the
@@ -341,7 +346,7 @@ class PoolPrograms:
             ),
         )
         self.final = jax.jit(
-            partial(model.apply, train=False, method="finalize_flow"),
+            partial(apply, train=False, method="finalize_flow"),
             **sh(("rep", "row", "row"), "row"),
         )
         # The module-level bodies are wrapped in per-instance lambdas
@@ -350,7 +355,7 @@ class PoolPrograms:
         # pool every engine's insert/gather signatures into one global
         # count and break the per-engine `program_counts()` accounting
         # (every other pool program already gets a fresh identity from
-        # its `partial(model.apply, ...)` / closure).
+        # its `partial(apply, ...)` / closure).
         #
         # Donation is single-device only: deserializing an SPMD
         # executable that carries input-output aliasing segfaults on

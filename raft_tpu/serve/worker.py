@@ -1195,6 +1195,22 @@ class RemoteWorkerHandle:
         self.terminate()
 
 
+def _refuse_if_parent_holds_tpu() -> None:
+    """One process per chip: a parent that has initialised JAX on the TPU
+    holds it, and a spawned worker that needs it would fail or hang —
+    refuse up front with the reason. No-op on CPU and in a parent that
+    has stayed off JAX."""
+    from raft_tpu.utils.runtime import holds_tpu
+
+    if holds_tpu():
+        raise ServeError(
+            "cannot spawn a worker process: this process has initialised "
+            "JAX on the TPU and holds the chip, so a child that needs it "
+            "would fail or hang (one process per chip) — spawn workers "
+            "from a parent that stays off JAX, or use the thread backend"
+        )
+
+
 def start_remote_worker(
     factory: Callable[..., Any],
     overrides: Optional[Dict[str, Any]] = None,
@@ -1215,6 +1231,7 @@ def start_remote_worker(
     """
     import multiprocessing as mp
 
+    _refuse_if_parent_holds_tpu()
     tmpdir = tempfile.mkdtemp(prefix="raft-remote-")
     ep_file = os.path.join(tmpdir, "endpoint")
     spec = {
@@ -1435,6 +1452,7 @@ class ProcessEngineClient:
             )
         import multiprocessing as mp
 
+        _refuse_if_parent_holds_tpu()
         self._tmpdir = tempfile.mkdtemp(prefix="raft-worker-")
         path = os.path.join(self._tmpdir, "ctl.sock")
         listener = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)

@@ -155,7 +155,7 @@ def main(argv=None) -> dict:
     if not args.no_verify:
         t0 = time.monotonic()
         art = aot.load_artifact(args.out, aot.fingerprint(engine))
-        execs = aot.load_programs(art)
+        execs = aot.load_programs(art, engine.dispatch_devices)
         report["verified_programs"] = len(execs)
         report["verify_load_s"] = round(time.monotonic() - t0, 3)
         if args.replicas > 1:
@@ -165,7 +165,8 @@ def main(argv=None) -> dict:
             t0 = time.monotonic()
             loads = [
                 len(aot.load_programs(
-                    aot.load_artifact(args.out, aot.fingerprint(engine))
+                    aot.load_artifact(args.out, aot.fingerprint(engine)),
+                    engine.dispatch_devices,
                 ))
                 for _ in range(args.replicas)
             ]

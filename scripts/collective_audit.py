@@ -9,8 +9,8 @@ byte totals by ICI bandwidth to produce predicted scaling tables.
 Three audited programs:
   A. data=8 training step (the b=8/chip DP scaling config): expect one
      gradient all-reduce tree totaling ~the parameter bytes and nothing
-     q-sized (the custom_partitioning rule keeps the fused kernel's
-     operands sharded — an all-gather of the correlation volume would be
+     q-sized (the fused kernel shard_maps over the mesh, so its
+     operands stay sharded — an all-gather of the correlation volume would be
      the scaling-killer this audit exists to rule out).
   B. space=8 batch-1 inference at the published Sintel geometry (the
      latency path): per-pair compute divides by 8, halo exchanges
@@ -34,6 +34,7 @@ Usage:
 """
 
 import argparse
+import functools
 import json
 import os as _os
 import re
@@ -243,8 +244,15 @@ class CollectiveDriftError(AssertionError):
     envelope the scaling predictions (and the multi-chip CI lane) rest on."""
 
 
-def check_train_structure(colls: dict, params: int, iters: int) -> None:
-    """Assert a DP train program's collectives match STRUCTURE_PINS."""
+def check_train_structure(
+    colls: dict, params: int, iters: int, steps: int = 1
+) -> None:
+    """Assert a DP train program's collectives match STRUCTURE_PINS.
+
+    ``iters`` is the refinement-iteration count summed over the
+    program's train steps; ``steps`` is how many train steps it scans (a
+    fused window runs the per-step encoder reshard once per step, so the
+    all-to-all pin scales with it)."""
     p = STRUCTURE_PINS
     ar = sum(colls.get("all-reduce", []))
     lo = p["train_ar_lower_x_params"] * params
@@ -262,10 +270,11 @@ def check_train_structure(colls: dict, params: int, iters: int) -> None:
             f"killer the partitioning rule exists to prevent"
         )
     a2a = colls.get("all-to-all", [])
-    if len(a2a) > p["train_max_all_to_all_count"]:
+    if len(a2a) > p["train_max_all_to_all_count"] * steps:
         raise CollectiveDriftError(
             f"{len(a2a)} all-to-alls (pinned <= "
-            f"{p['train_max_all_to_all_count']}): encoder-reshard traffic "
+            f"{p['train_max_all_to_all_count']} x {steps} step(s)): "
+            f"encoder-reshard traffic "
             f"grew, or something new rides the scan"
         )
 
@@ -351,11 +360,12 @@ def audit_infer(mesh, cfg, h: int, w: int, iters: int = 32,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from raft_tpu.models import build_raft, init_variables
-    from raft_tpu.parallel.mesh import replicated
+    from raft_tpu.parallel.mesh import replicated, traced_under
 
     model = build_raft(cfg)
     variables = init_variables(model)
 
+    @functools.partial(traced_under, mesh)  # the fused kernel shard_maps
     def fwd(variables, im1, im2):
         return model.apply(
             variables, im1, im2, train=False,
@@ -541,17 +551,7 @@ def main():
           f"({1e3/lat/11.8:.1f}x the 3090 Ti with 8 chips; "
           f"{1e3/lat/8/11.8:.2f}x per chip)")
 
-    from raft_tpu.kernels.lookup_xtap import PARTITION_RULE_ACTIVE
-
-    if not PARTITION_RULE_ACTIVE:
-        # without the custom_partitioning rule the fused kernel
-        # replicates under the mesh (q-sized gathers appear by
-        # construction) — an environment limitation, not structure
-        # drift; the same guard skips the pinning tests
-        print("\n# structure cross-check SKIPPED: def_partition lacks "
-              "sharding_rule on this jax — fused lookup runs "
-              "unpartitioned, so the pinned envelope cannot hold here")
-    elif drift:
+    if drift:
         print("\n!! COLLECTIVE STRUCTURE DRIFT — the predictions above "
               "extrapolate from a structure that no longer holds "
               "(tests/test_multichip.py pins the same envelope on the "

@@ -221,10 +221,7 @@ def main():
     ap.add_argument("--device", default=None, choices=[None, "cpu", "tpu"])
     args = ap.parse_args()
     if args.device == "cpu" or args.stage == "score":
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"  # read by the jax import below
 
     import flax.serialization
     import jax
@@ -242,12 +239,16 @@ def main():
         if args.stage == "all":
             # scoring must run on the CPU backend (the one the test uses;
             # the backend choice is process-global, so re-exec) — TPU-scored
-            # expectations would pin bf16-MXU numerics the CPU test can't hit
+            # expectations would pin bf16-MXU numerics the CPU test can't hit.
+            # This parent may hold the chip (one process per chip): the
+            # child is pinned to CPU through its environment so it never
+            # probes the device.
             import subprocess
 
             raise SystemExit(subprocess.call(
                 [sys.executable, os.path.abspath(__file__),
-                 "--stage", "score", "--out", args.out]
+                 "--stage", "score", "--out", args.out],
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
             ))
         return
 

@@ -119,8 +119,12 @@ series. `--autoscale-max N` attaches the signal-driven Autoscaler
 `serve_autoscale` BENCH line — pair it with `--arrival diurnal` for the
 scale-into-the-peak scenario.
 
-Run (TPU/GPU, real model):  python scripts/serve_bench.py --arch raft_small
+Run (TPU, real model):      python scripts/serve_bench.py --arch raft_small
 Run (CPU smoke, tiny net):  python scripts/serve_bench.py --tiny --duration 3
+(without --tiny the bench refuses to run off a TPU; every line it prints
+carries the `device` — platform, kind, count — it ran on. On a TPU the
+`--backend process` arms refuse: this parent holds the chip once it has
+run an in-process arm, and a chip belongs to one process.)
 Boot A/B (CPU smoke):       python scripts/serve_bench.py --tiny \
     --ladder 2,1 --max-batch 2 --pool-capacity 2 --boot-report
 Mixed-iteration A/B (the pool win):
@@ -146,6 +150,14 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
+
+# the device every printed result ran on (platform, kind, count) — set once
+# in main() before anything is measured
+_DEVICE = None
+
+
+def _line(result: dict) -> str:
+    return json.dumps(dict(result, device=_DEVICE))
 
 
 def tiny_config():
@@ -673,10 +685,14 @@ def boot_report(args) -> dict:
     # 1) cold: no cache, no artifact (must run before the cache is wired
     #    — the persistent-cache config is process-global)
     boot_once("boot_cold")
-    # 2) persistent cache: first boot misses + populates, second hits
-    cache_dir = args.compilation_cache_dir or tempfile.mkdtemp(
-        prefix="raft_jax_cache_"
-    )
+    # 2) persistent cache: first boot populates (a miss only while the
+    #    directory holds nothing for these programs — the path is fixed,
+    #    never a temp name, because the path is part of the cache key),
+    #    second hits. JAX_COMPILATION_CACHE_DIR, when set, wins inside
+    #    the engine (runtime.enable_persistent_cache).
+    from raft_tpu.utils.runtime import DEFAULT_CACHE_DIR
+
+    cache_dir = args.compilation_cache_dir or DEFAULT_CACHE_DIR
     boot_once("boot_cache_miss", compilation_cache_dir=cache_dir)
     boot_once("boot_cache_hit", compilation_cache_dir=cache_dir)
     # 3) artifact: build it once (offline cost, reported), then boot
@@ -705,10 +721,10 @@ def boot_report(args) -> dict:
         ("serve_boot_speedup_artifact_vs_cold",
          report["boot_speedup_artifact_vs_cold"], "x"),
     ]:
-        print(json.dumps(
+        print(_line(
             {"metric": metric, "value": value, "unit": unit, "config": config}
         ), flush=True)
-    print(json.dumps({"metric": "serve_boot_report", **report}), flush=True)
+    print(_line({"metric": "serve_boot_report", **report}), flush=True)
     return report
 
 
@@ -941,7 +957,7 @@ def adaptive_ab(args) -> dict:
         "warm_starts_adaptive": adaptive["warm_starts"],
         "config": config,
     }
-    print(json.dumps(report), flush=True)
+    print(_line(report), flush=True)
     return report
 
 
@@ -1179,7 +1195,7 @@ def rollout_bench(args) -> dict:
         "rollback_stage_timeline": bad_snap["stage_history"],
         "config": config,
     }
-    print(json.dumps(report), flush=True)
+    print(_line(report), flush=True)
     return report
 
 
@@ -1416,7 +1432,7 @@ def edge_ab(args) -> dict:
         f"unique_pairs={len(uniq)}, cache={args.edge_cache}, "
         f"coalesce={args.edge_coalesce}, near_dup={args.edge_near_dup}"
     )
-    print(json.dumps(report), flush=True)
+    print(_line(report), flush=True)
     return report
 
 
@@ -1556,7 +1572,7 @@ def tiled_bench(args) -> dict:
             f"overlap={cfg.tile_overlap_px}"
         ),
     }
-    print(json.dumps(report), flush=True)
+    print(_line(report), flush=True)
     return report
 
 
@@ -2126,11 +2142,11 @@ def emit(report: dict, args) -> None:
     ]:
         if value is None:
             continue
-        print(json.dumps(
+        print(_line(
             {"metric": metric, "value": value, "unit": unit, "config": config}
         ), flush=True)
     if report.get("phase_breakdown"):
-        print(json.dumps({
+        print(_line({
             "metric": "serve_phase_breakdown",
             "trace_sample": report["trace_sample"],
             "traces": report["traces_collected"],
@@ -2139,7 +2155,7 @@ def emit(report: dict, args) -> None:
         }), flush=True)
     ledger = report.get("ledger") or {}
     if ledger.get("sampled_dispatches"):
-        print(json.dumps({
+        print(_line({
             "metric": "serve_device_time",
             "sample_every": ledger.get("sample_every"),
             "est_total_device_ms": ledger.get("est_total_device_ms"),
@@ -2155,7 +2171,7 @@ def emit(report: dict, args) -> None:
         }), flush=True)
     conv = report.get("convergence") or {}
     if conv.get("n"):
-        print(json.dumps({
+        print(_line({
             "metric": "serve_convergence",
             "n": conv["n"],
             "final_residual_p50": conv.get("final_residual_p50"),
@@ -2171,7 +2187,7 @@ def emit(report: dict, args) -> None:
         }), flush=True)
     if report.get("autoscale"):
         asc = report["autoscale"]
-        print(json.dumps({
+        print(_line({
             "metric": "serve_autoscale",
             "min_replicas": asc["min_replicas"],
             "max_replicas": asc["max_replicas"],
@@ -2184,7 +2200,7 @@ def emit(report: dict, args) -> None:
         }), flush=True)
     if report.get("edge_slo"):
         fe_snap = report.get("frontend") or {}
-        print(json.dumps({
+        print(_line({
             "metric": "serve_edge_slo",
             "classes": report["edge_slo"],
             "http_requests": fe_snap.get("http_requests"),
@@ -2195,7 +2211,7 @@ def emit(report: dict, args) -> None:
     if report.get("qos"):
         q = report["qos"]
         eng_classes = (q.get("engine") or {}).get("classes") or {}
-        print(json.dumps({
+        print(_line({
             "metric": "serve_qos",
             "tenants": q["tenants"],
             "priority_mix": q["priority_mix"],
@@ -2208,7 +2224,7 @@ def emit(report: dict, args) -> None:
             "config": config,
         }), flush=True)
     if report["classes"]:
-        print(json.dumps({
+        print(_line({
             "metric": "serve_slo_report",
             "arrival": report["arrival"],
             "arrival_rate": report["arrival_rate"],
@@ -2216,7 +2232,7 @@ def emit(report: dict, args) -> None:
             "classes": report["classes"],
             "config": config,
         }), flush=True)
-    print(json.dumps({"metric": "serve_report", **report}), flush=True)
+    print(_line({"metric": "serve_report", **report}), flush=True)
 
 
 def main(argv=None) -> dict:
@@ -2486,17 +2502,27 @@ def main(argv=None) -> dict:
     if args.tiny and args.deadline_ms == 2000.0:
         args.deadline_ms = 30000.0  # CPU compiles ride inside the deadline
     if args.mesh_devices > 1:
-        # must precede the first jax import in the process: CPU hosts
-        # provision the virtual mesh via XLA_FLAGS (real TPU/GPU hosts
-        # already expose their devices)
+        # must precede the first jax import in the process: the --tiny
+        # CPU smoke provisions a virtual mesh via XLA_FLAGS (a TPU host
+        # exposes its own chips; a non---tiny run without one refuses)
         flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags and (
-            args.tiny or os.environ.get("JAX_PLATFORMS", "") == "cpu"
-        ):
+        if "xla_force_host_platform_device_count" not in flags and args.tiny:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.mesh_devices}"
             ).strip()
+    from raft_tpu.utils.runtime import (
+        device_info, enable_persistent_cache, require_tpu,
+    )
+
+    global _DEVICE
+    if args.tiny:
+        # the CPU counts/correctness smoke: named for what it is
+        _DEVICE = device_info()
+    else:
+        # a measurement: needs the chip, never falls back to host devices
+        _DEVICE = require_tpu("serve_bench.py")
+        enable_persistent_cache(args.compilation_cache_dir)
     if args.adaptive_ab:
         return adaptive_ab(args)
     if args.boot_report:
@@ -2559,7 +2585,7 @@ def main(argv=None) -> dict:
                 f"queue_capacity={args.queue_capacity}"
             ),
         }
-        print(json.dumps({"metric": "serve_tcp_ab", **ab}), flush=True)
+        print(_line({"metric": "serve_tcp_ab", **ab}), flush=True)
         report["tcp_ab"] = ab
         return report
     if args.backend == "process" and args.transport == "ab":
@@ -2608,7 +2634,7 @@ def main(argv=None) -> dict:
                 f"queue_capacity={args.queue_capacity}"
             ),
         }
-        print(json.dumps({"metric": "serve_transport", **ab}), flush=True)
+        print(_line({"metric": "serve_transport", **ab}), flush=True)
         report["transport_ab"] = ab
         return report
     if args.backend == "process" and args.replicas > 1:
@@ -2654,7 +2680,7 @@ def main(argv=None) -> dict:
                 f"queue_capacity={args.queue_capacity}"
             ),
         }
-        print(json.dumps({"metric": "serve_process_ab", **ab}), flush=True)
+        print(_line({"metric": "serve_process_ab", **ab}), flush=True)
         report["process_ab"] = ab
         return report
     if args.replicas > 1:
@@ -2683,7 +2709,7 @@ def main(argv=None) -> dict:
             ),
             "router": report.get("router", {}),
         }
-        print(json.dumps({"metric": "serve_replica_ab", **ab}), flush=True)
+        print(_line({"metric": "serve_replica_ab", **ab}), flush=True)
         report["replica_ab"] = ab
         return report
     if args.mesh_devices > 1:
@@ -2695,7 +2721,7 @@ def main(argv=None) -> dict:
         args._mesh_override = None
         report = run_bench(args)
         emit(report, args)
-        print(json.dumps({
+        print(_line({
             "metric": "serve_mesh_ab",
             "mesh_devices": args.mesh_devices,
             "throughput_rps_1dev": base["throughput_rps"],

@@ -23,10 +23,14 @@ through the mesh-sharded step (`parallel.make_sharded_window_step`,
 batches sharded over an N-way `data` axis) and emits `train_mesh_ab`
 BENCH lines — the 1-vs-N A/B for the executed sharded training lane.
 
-Run (TPU/GPU, real model):  python scripts/train_bench.py --arch raft_small
+Run (TPU, real model):      python scripts/train_bench.py --arch raft_small
 Run (CPU smoke, tiny net):  python scripts/train_bench.py --tiny --steps 16
 A/B (the window win):       python scripts/train_bench.py --tiny \\
                                 --window-sizes 1,4 --steps 16
+
+Without ``--tiny`` the bench refuses to run off a TPU (``require_tpu``):
+a CPU run times the CPU backend, not the system. Every line it prints
+carries the ``device`` (platform, kind, count) it ran on.
 """
 
 from __future__ import annotations
@@ -118,6 +122,12 @@ def bench_one(model, variables, args, window_size, mesh_n=1):
     batches = make_batches(steps, args.batch_size, (args.hw, args.hw))
     staging = _WindowStaging(slots=2)
 
+    def put_window(i, *sharding):
+        buf = staging.stack(batches[i: i + k])
+        out = jax.device_put(buf, *sharding)
+        staging.transferred(buf, out)  # zero-copy on CPU: retires the slot
+        return out
+
     def feed(i):
         # the pipeline's staging path: per-step feeds one host batch (jit
         # transfers per leaf); windows stage k batches into a rotating
@@ -127,12 +137,10 @@ def bench_one(model, variables, args, window_size, mesh_n=1):
 
             if k == 1:
                 return shard_batch(batches[i], mesh)
-            return jax.device_put(
-                staging.stack(batches[i: i + k]), window_batch_sharding(mesh)
-            )
+            return put_window(i, window_batch_sharding(mesh))
         if k == 1:
             return batches[i]
-        return jax.device_put(staging.stack(batches[i: i + k]))
+        return put_window(i)
 
     # warmup: compile + first transfer, outside the timed region
     w_state, w_metrics = fn(state, feed(0))
@@ -246,17 +254,30 @@ def main(argv=None):
     if args.tiny and not os.environ.get("JAX_PLATFORMS"):
         os.environ["JAX_PLATFORMS"] = "cpu"
     if args.mesh_devices > 1:
-        # must precede the first jax import: CPU hosts provision the
-        # virtual mesh (real TPU/GPU hosts already expose their devices)
+        # must precede the first jax import: the --tiny CPU smoke
+        # provisions a virtual mesh (a TPU host exposes its own chips; a
+        # non---tiny run without one refuses below)
         flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags and (
-            args.tiny or os.environ.get("JAX_PLATFORMS", "") == "cpu"
-        ):
+        if "xla_force_host_platform_device_count" not in flags and args.tiny:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.mesh_devices}"
             ).strip()
     from raft_tpu.models import build_raft, init_variables
+    from raft_tpu.utils.runtime import (
+        device_info, enable_persistent_cache, require_tpu,
+    )
+
+    # --tiny is the CPU counts/correctness smoke; anything else is a
+    # measurement and needs the chip
+    if args.tiny:
+        device = device_info()
+    else:
+        device = require_tpu("train_bench.py")
+        enable_persistent_cache()
+
+    def emit(line):
+        print(json.dumps(dict(line, device=device)))
 
     if args.tiny:
         from raft_tpu.models.corr import CorrBlock
@@ -302,7 +323,7 @@ def main(argv=None):
             n = next(r for r in results
                      if r["window_size"] == k
                      and r["mesh_devices"] == args.mesh_devices)
-            print(json.dumps({
+            emit({
                 "metric": "train_mesh_ab",
                 "window_size": k,
                 "mesh_devices": args.mesh_devices,
@@ -314,34 +335,34 @@ def main(argv=None):
                 "pairs_per_s_mesh": round(
                     n["steps_per_s"] * args.batch_size, 3
                 ),
-            }))
+            })
     cfg = {"tiny": args.tiny, "batch_size": args.batch_size,
            "hw": args.hw, "iters": args.iters}
     for r in results:
         c = dict(cfg, window_size=r["window_size"],
                  mesh_devices=r["mesh_devices"])
-        print(json.dumps({"metric": "train_steps_per_s",
-                          "value": round(r["steps_per_s"], 3),
-                          "unit": "steps/s", "config": c}))
-        print(json.dumps({"metric": "train_host_syncs_per_step",
-                          "value": round(r["host_syncs_in_window_per_step"], 5),
-                          "unit": "syncs/step (inside windows)",
-                          "config": c}))
-        print(json.dumps({"metric": "train_dispatches_per_step",
-                          "value": round(r["dispatches_per_step"], 5),
-                          "unit": "dispatches/step", "config": c}))
+        emit({"metric": "train_steps_per_s",
+              "value": round(r["steps_per_s"], 3),
+              "unit": "steps/s", "config": c})
+        emit({"metric": "train_host_syncs_per_step",
+              "value": round(r["host_syncs_in_window_per_step"], 5),
+              "unit": "syncs/step (inside windows)",
+              "config": c})
+        emit({"metric": "train_dispatches_per_step",
+              "value": round(r["dispatches_per_step"], 5),
+              "unit": "dispatches/step", "config": c})
         if r.get("window_device_samples"):
             # the window-step ledger line (ISSUE 11): one fused window
             # of device work, in milliseconds — perf_ledger.py gates it
-            print(json.dumps({
+            emit({
                 "metric": "train_device_time",
                 "family": f"train_window_step/{r['window_size']}",
                 "p50_ms": r["window_device_ms_p50"],
                 "mean_ms": r["window_device_ms_mean"],
                 "samples": r["window_device_samples"],
                 "config": c,
-            }))
-    print(json.dumps({"metric": "train_bench_report", "value": report}))
+            })
+    emit({"metric": "train_bench_report", "value": report})
     return report
 
 

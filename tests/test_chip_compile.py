@@ -1,0 +1,177 @@
+"""The main path's kernels compiled for a described TPU v5e — no chip.
+
+The TPU compiler is installed in the sandbox and compiles for a topology
+that is described, not attached (on-chip-measurement guide, section 2
+step 3). Interpret-mode tests cannot see what it refuses — an unaligned
+slice, too much VMEM, a kernel that cannot be partitioned (the
+``custom_partitioning`` form of the fused lookup passed every
+interpret-mode mesh test and was refused here: "Custom emitter for
+CustomSPMDPartitioning not found") — so a few compiles at real widths
+stay among the tests and guard every later PR at no chip time.
+
+Nothing here runs, so nothing is said about results or times. The
+kernels are called directly, ~20 s of Mosaic compile each for the fused
+lookup; whole-program compiles (serve 32-iteration program, train step:
+36-94 s each) live in the builder's scratch rehearsal, not here.
+
+Rules this file keeps (the driver runs the suite under pytest-xdist):
+the topology is described inside a module-scoped fixture, never at
+import, in a ``skipif``, in ``parametrize`` or in ``conftest.py``; the
+fixture is not ``autouse``; the persistent compilation cache is off
+around the compiles (a TPU entry written without a chip cannot be read
+back); no child process compiles; all such tests live in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from raft_tpu.kernels.lookup_xtap import FusedLookupCorrBlock
+
+# raft_large: 4 levels, radius 4, 256-channel feature maps, convcorr1 to 256
+LEVELS, RADIUS, FMAP_C, PROJ_C = 4, 4, 256, 256
+TAPS = LEVELS * (2 * RADIUS + 1) ** 2
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """Compile with the persistent cache off, restored afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _project_args(block, batch, h8, w8):
+    """Abstract operands of one fused lookup+projection call at the
+    (h8, w8) feature grid: the packed pyramid ``build_pyramid`` makes
+    (real level shapes), centroids, and raft_large's convcorr1 weights."""
+    fmap = jax.ShapeDtypeStruct((batch, h8, w8, FMAP_C), jnp.float32)
+    pyramid = jax.eval_shape(block.build_pyramid, fmap, fmap)
+    assert isinstance(pyramid, dict), "geometry not fusable: XLA fallback"
+    return (
+        pyramid,
+        jax.ShapeDtypeStruct((batch, h8, w8, 2), jnp.float32),
+        jax.ShapeDtypeStruct((1, 1, TAPS, PROJ_C), jnp.float32),
+        jax.ShapeDtypeStruct((PROJ_C,), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize(
+    "h8,w8",
+    [
+        (55, 128),  # Sintel 440x1024: pow2 widths, 640-row tiles
+        (47, 156),  # KITTI-pad 376x1248: chunked >128-lane gathers and the
+                    # masked tail tile (7332 rows have no 8-aligned divisor)
+    ],
+    ids=["sintel-440x1024", "kitti-376x1248"],
+)
+def test_lookup_xtap_forward_compiles(one_chip, h8, w8):
+    """The deployment kernel (fused lookup + convcorr1, bf16 storage,
+    y-dot in kernel) at the real level shapes of both eval geometries."""
+    block = FusedLookupCorrBlock(
+        LEVELS, RADIUS, dtype=jnp.bfloat16, interpret=False,
+        ydot_in_kernel=True,
+    )
+    args = _on(one_chip, _project_args(block, 1, h8, w8))
+    compiled = jax.jit(
+        lambda p, c, k, b: block.index_project(p, c, k, b)
+    ).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_lookup_xtap_partitions_over_four_chips(topo, no_persistent_cache):
+    """The same kernel under a 4-device data mesh at the training crop's
+    level shapes (368x768, global batch 8): traced under the mesh it
+    shard_maps over the query rows — one Mosaic call on per-shard shapes,
+    and no all-gather of the volume."""
+    from raft_tpu.parallel import make_mesh, traced_under
+
+    mesh = make_mesh(space=1, devices=topo.devices)
+    assert mesh.devices.shape == (4, 1)
+    row = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    block = FusedLookupCorrBlock(
+        LEVELS, RADIUS, dtype=jnp.bfloat16, interpret=False
+    )
+    pyramid, cents, kernel, bias = _project_args(block, 8, 46, 96)
+    compiled = jax.jit(
+        traced_under(
+            mesh, lambda p, c, k, b: block.index_project(p, c, k, b)
+        ),
+        out_shardings=row,
+    ).lower(
+        _on(row, pyramid), _on(row, cents), _on(rep, kernel), _on(rep, bias)
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "num_partitions=4" in text
+    q = 8 * 46 * 96
+    assert f"bf16[{q // 4},46,96" in text, "no per-shard level-0 block"
+    assert f"bf16[{q},46,96" not in text, "global-q volume: not partitioned"
+
+
+def test_lookup_xtap_unsharded_under_mesh_is_refused(topo, no_persistent_cache):
+    """No quiet replicated kernel on the chip: a multi-device program
+    that reaches the kernel WITHOUT the ambient mesh (not traced under
+    ``traced_under``) is an error, not a silent all-gather."""
+    from raft_tpu.parallel import make_mesh
+
+    mesh = make_mesh(space=1, devices=topo.devices)
+    row = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    block = FusedLookupCorrBlock(
+        LEVELS, RADIUS, dtype=jnp.bfloat16, interpret=False
+    )
+    pyramid, cents, kernel, bias = _project_args(block, 8, 46, 96)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(
+            lambda p, c, k, b: block.index_project(p, c, k, b)
+        ).lower(
+            _on(row, pyramid), _on(row, cents), _on(rep, kernel),
+            _on(rep, bias),
+        )
+
+
+def test_fused_volume_pyramid_compiles(one_chip):
+    """``corr_impl='pallas'``'s volume+pool kernel at raft_large's real
+    widths (256 channels, 128-wide feature rows, 96 MiB VMEM limit).
+    Height is cut to 16 rows: the kernel unrolls over the feature height
+    and the full 55-row Sintel grid takes 92 s to compile (it does
+    compile — builder's rehearsal, PR 23)."""
+    from raft_tpu.kernels.corr_pallas import fused_volume_pyramid
+
+    fmap = _on(one_chip, jax.ShapeDtypeStruct((1, 16, 128, FMAP_C), jnp.float32))
+    compiled = jax.jit(
+        lambda a, b: fused_volume_pyramid(a, b, LEVELS, interpret=False)
+    ).lower(fmap, fmap).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1

@@ -8,7 +8,7 @@ a partitioning-rule regression is caught at test time, not at pod time:
   1. pure-DP training all-reduces exactly the gradient tree (~params
      bytes) — nothing activation-sized;
   2. no q-sized all-gather exists anywhere (the fused kernel's
-     custom_partitioning keeps every query-carrying operand sharded —
+     shard_map keeps every query-carrying operand sharded —
      an all-gather of the correlation volume is THE scaling killer);
   3. spatial sharding exchanges conv halos via collective-permute.
 
@@ -21,18 +21,6 @@ import os
 import sys
 
 import pytest
-
-from raft_tpu.kernels.lookup_xtap import PARTITION_RULE_ACTIVE
-
-# the audited programs run the fused deployment config under a mesh; the
-# structural facts below (sharded kernel operands, no q-sized all-gather)
-# only hold when the custom_partitioning rule can register on this jax
-needs_partition_rule = pytest.mark.skipif(
-    not PARTITION_RULE_ACTIVE,
-    reason="def_partition lacks sharding_rule on this jax; "
-    "fused lookup runs unpartitioned under a mesh",
-)
-
 
 def _load_audit():
     if "collective_audit" in sys.modules:
@@ -47,7 +35,6 @@ def _load_audit():
     return mod
 
 
-@needs_partition_rule
 def test_dp_train_collective_structure():
     audit = _load_audit()
     from raft_tpu.parallel import make_mesh
@@ -71,7 +58,6 @@ def test_dp_train_collective_structure():
     assert sum(colls.get("all-to-all", [])) < 4 * 128 * 128 * 8 * 4, colls
 
 
-@needs_partition_rule
 def test_dp_inference_collectives_bounded_by_encoder_reshard():
     """The DP-inference scaling claim ('per-chip ~flat at any N') rests
     on the forward emitting only the b->2b encoder concat/split
@@ -93,7 +79,6 @@ def test_dp_inference_collectives_bounded_by_encoder_reshard():
     audit.check_infer_structure(colls, pair_bytes)
 
 
-@needs_partition_rule
 def test_space_sharding_emits_halos():
     audit = _load_audit()
     from raft_tpu.parallel import make_mesh

@@ -3,11 +3,12 @@
 VERDICT r3 #1: the benched deployment config (``corr_impl='fused'``) and the
 multi-chip mesh were never exercised together — GSPMD cannot partition an
 opaque TPU custom call, so without a rule the kernel would replicate (or
-fail) under sharding. ``lookup_xtap._partitioned_xtap`` now registers a
-``custom_partitioning`` rule (query axis embarrassingly parallel; weights/
-scales/lane dims replicated). These tests pin, on the 8-device virtual CPU
-mesh (interpret-mode kernels — the same partitioning rule and per-shard
-lowering path a real slice takes):
+fail) under sharding. ``lookup_xtap._partitioned_xtap`` ``shard_map``s the
+kernel over the ambient mesh (query axis embarrassingly parallel; weights/
+scales/lane dims replicated) — sharded programs are traced under
+``parallel.traced_under(mesh, ...)``. These tests pin, on the 8-device
+virtual CPU mesh (interpret-mode kernels — the same shard_map and
+per-shard lowering path a real slice takes):
 
   * the compiled sharded lookup really is partitioned — per-shard (q/n)
     shapes in the HLO, global-q kernel shapes absent;
@@ -28,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raft_tpu.kernels.lookup_xtap import (
-    PARTITION_RULE_ACTIVE,
     FusedLookupCorrBlock,
     lookup_pyramid_fused,
 )
@@ -38,6 +38,7 @@ from raft_tpu.parallel import (
     make_sharded_train_step,
     shard_batch,
     shard_state,
+    traced_under,
 )
 
 
@@ -61,18 +62,7 @@ def _cents(rng, b, h, w, h0, w0):
     return jnp.asarray(c)
 
 
-# the custom_partitioning rule needs the modern def_partition API; without
-# it the kernel runs unwrapped (replicated under a mesh) and the mesh x
-# fused composition below is untestable on this jax
-needs_partition_rule = pytest.mark.skipif(
-    not PARTITION_RULE_ACTIVE,
-    reason="def_partition lacks sharding_rule on this jax; "
-    "fused lookup runs unpartitioned under a mesh",
-)
-
-
 class TestPartitionedLookup:
-    @needs_partition_rule
     @pytest.mark.parametrize(
         "b,h,w,levels",
         [
@@ -101,7 +91,9 @@ class TestPartitionedLookup:
         csh = NamedSharding(mesh, P("data", "space", None, None))
 
         fn = jax.jit(
-            lambda p, c: lookup_pyramid_fused(p, c, radius, interpret=True),
+            traced_under(mesh, lambda p, c: lookup_pyramid_fused(
+                p, c, radius, interpret=True
+            )),
             in_shardings=([qsh] * levels, csh),
             out_shardings=NamedSharding(mesh, P("data", "space", None, None)),
         )
@@ -143,7 +135,6 @@ class TestPartitionedLookup:
         assert _partition_dim0(mesh, "data", 99) is None
         assert _partition_dim0(mesh, None, 99) is None
 
-    @needs_partition_rule
     def test_three_way_mesh_partitions(self, rng):
         """Non-power-of-two shard count (3-way data axis): partitioned
         output must match the unsharded kernel."""
@@ -157,7 +148,9 @@ class TestPartitionedLookup:
         csh = NamedSharding(mesh, P("data", None, None, None))
         qsh = NamedSharding(mesh, P("data", None, None, None))
         fn = jax.jit(
-            lambda p, c: lookup_pyramid_fused(p, c, 2, interpret=True),
+            traced_under(mesh, lambda p, c: lookup_pyramid_fused(
+                p, c, 2, interpret=True
+            )),
             in_shardings=([qsh, qsh], csh),
         )
         got = fn([jax.device_put(v, qsh) for v in pyr], jax.device_put(cents, csh))
@@ -181,14 +174,13 @@ def _tiny_fused_cfg():
         flow_head_hidden=16,
         corr_impl="fused",
         # the DEPLOYMENT storage dtype: keeps the bf16-corr x
-        # custom_partitioning composition exercised under a mesh (the
+        # shard_map composition exercised under a mesh (the
         # dryrun's loss loop runs dense since round 5)
         corr_dtype="bfloat16",
     )
 
 
 class TestFusedTrainStepUnderMesh:
-    @needs_partition_rule
     def test_params_match_single_device(self, rng):
         """Full fused train step under (data=2, space=2) == single device,
         params compared leaf-by-leaf (the bar the DP test sets for the
@@ -254,7 +246,6 @@ class TestFusedTrainStepUnderMesh:
 
 
 class TestInt8ProjectUnderMesh:
-    @needs_partition_rule
     def test_int8_project_partitions(self, rng):
         """The scales-carrying int8 lookup+project variant under the mesh:
         output matches single-device, per-shard shapes in the HLO."""

@@ -1727,17 +1727,23 @@ class TestPerfLedgerScript:
 
         import scripts.perf_ledger as pl
 
-        art = {
-            "n": 99, "cmd": "synthetic", "rc": 0,
-            "tail": _json.dumps({
-                "metric": "raft_large_sintel_fps", "value": 1.0,
-                "unit": "pairs/s",
-            }) + "\n",
-        }
+        def art(n, value):
+            return _json.dumps({
+                "n": n, "cmd": "synthetic", "rc": 0,
+                "tail": _json.dumps({
+                    "metric": "raft_large_sintel_fps", "value": value,
+                    "unit": "pairs/s",
+                }) + "\n",
+            })
+
+        # a history of the test's own (the repo's committed rounds hold
+        # no fps series since the pre-PR-1 chip records were deleted)
+        for n, value in ((1, 23.8), (2, 28.98), (3, 29.01)):
+            (tmp_path / f"BENCH_r{n:02d}.json").write_text(art(n, value))
         path = tmp_path / "regressed.json"
-        path.write_text(_json.dumps(art))
+        path.write_text(art(99, 1.0))
         rc = pl.main([
-            "--check", "--dir", _REPO_ROOT, "--candidate", str(path),
+            "--check", "--dir", str(tmp_path), "--candidate", str(path),
         ])
         assert rc == 2
         err = capsys.readouterr().err

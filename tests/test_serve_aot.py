@@ -40,6 +40,27 @@ from tests.test_serve import _image, _tiny_model
 pytestmark = pytest.mark.chaos
 
 
+@pytest.fixture(scope="module", autouse=True)
+def no_inherited_compile_cache():
+    """This module's claims are about COLD boots and self-contained
+    artifacts, so it runs with the process-global persistent compile
+    cache off — whatever the worker ran before. (xdist's ``loadfile``
+    hands files out largest first, not alphabetically: a serve module
+    whose autouse fixture points the cache at its own tmp dir and leaves
+    it there can come first, and then ``save_artifact`` recompiles
+    instead of reusing — ``compiled == 10`` — and executables read back
+    from that cache die with "Function ... not found".)"""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    cc.reset_cache()
+
+
 @pytest.fixture(scope="module")
 def tiny_model():
     return _tiny_model()
@@ -451,7 +472,7 @@ class TestBuildArtifactScript:
 
 @pytest.mark.slow
 class TestBootReportBench:
-    def test_boot_report_a_b(self):
+    def test_boot_report_a_b(self, tmp_path):
         """The full three-tier boot A/B (cold / persistent-cache /
         artifact) on the tiny CPU config: artifact boot compiles zero
         programs and is >= 2x faster than cold (the ISSUE 7 acceptance
@@ -470,7 +491,11 @@ class TestBootReportBench:
         report = mod.main(
             ["--tiny", "--ladder", "2,1", "--max-batch", "2",
              "--pool-capacity", "2", "--queue-capacity", "8",
-             "--boot-report"]
+             "--boot-report",
+             # a cold directory of the test's own: the bench's default is
+             # the checkout's fixed .jax_cache, which a test must neither
+             # fill nor find warm (its miss boot has to be a miss)
+             "--compilation-cache-dir", str(tmp_path / "jax_cache")]
         )
         assert report["boot_artifact_programs_compiled"] == 0
         assert report["boot_artifact_programs_loaded"] == report["programs"]

@@ -267,8 +267,19 @@ class TestPooledServing:
         for (a, b), n, res in zip(pairs, asks, results):
             assert res.num_flow_updates == n     # honored exactly
             want = _oracle(model, variables, a, b, n)
+            # The absolute tolerance is what fp32 can promise at this
+            # field's magnitude, not a fixed pixel count: the random-init
+            # net emits flows up to ~200 px, and a ONE-ulp nudge of the
+            # input already moves the 3-iteration oracle by up to
+            # 0.014 px (6.8e-5 of the field's max — the GRU iterations
+            # and the 8x convex upsample amplify rounding noise). The
+            # pool (slot-wise steps, batch 3) and the oracle (one scan,
+            # batch 1) are the same fp32 math reassociated, so they are
+            # held to 3x that floor: 2e-4 of the largest flow value
+            # (0.04 px at 200 px, 0.017 px for a 1-iteration 85 px field).
             np.testing.assert_allclose(
-                res.flow, want, rtol=1e-2, atol=1e-2,
+                res.flow, want, rtol=1e-2,
+                atol=2e-4 * float(np.abs(want).max()),
                 err_msg=f"pooled request at {n} iters diverged",
             )
 

@@ -121,8 +121,9 @@ class TestGuardedStep:
         assert int(s2.step) == int(s1.step) + 1  # data position advances
 
     def test_spike_detected_and_skipped(self):
-        """A finite grad-norm spike (images blown out of [-1,1]) is
-        skipped once the EMA is warm; the EMA ignores the spike."""
+        """A finite grad-norm spike (the loss concentrated on one pixel:
+        ``FaultInjector.loss_spike``) is skipped once the EMA is warm;
+        the EMA ignores the spike."""
         from raft_tpu.train import make_train_step
 
         model, tx, state = _tiny_model_and_tx()
@@ -137,7 +138,7 @@ class TestGuardedStep:
             s, m = guarded(s, batch)
         assert int(s.skipped_steps) == 0
         spike = dict(batch)
-        FaultInjector.loss_spike(spike, scale=1e4)
+        FaultInjector.loss_spike(spike)
         spike = {k: jnp.asarray(v) for k, v in spike.items()}
         s2, m2 = guarded(s, spike)
         assert np.isfinite(float(m2["grad_norm"]))

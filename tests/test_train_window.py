@@ -77,10 +77,33 @@ GUARD_KW = dict(
 )
 
 
-def _run_per_step(model, tx, state, batches, **kw):
+def _ulp_nudged(batches):
+    """The same batches with ``image1`` moved ONE fp32 ulp: the smallest
+    input change fp32 can represent. Running the per-step loop on these
+    measures how far this (chaotic, unrolled-GRU) training trajectory
+    amplifies rounding-level noise — i.e. what fp32 can promise for a
+    scan-vs-loop comparison, whose only difference is XLA reassociating
+    the same fp32 math. Tolerances below are multiples of that measured
+    floor instead of hand-picked constants."""
+    out = []
+    for b in batches:
+        nb = dict(b)
+        nb["image1"] = np.nextafter(
+            np.asarray(b["image1"], np.float32), np.float32(np.inf)
+        )
+        out.append(nb)
+    return out
+
+
+def _rel(a, b):
+    return abs(float(a) - float(b)) / max(abs(float(a)), 1e-30)
+
+
+def _run_per_step(model, tx, state, batches, step=None, **kw):
     from raft_tpu.train import make_train_step
 
-    step = make_train_step(model, tx, donate=False, **kw)
+    if step is None:
+        step = make_train_step(model, tx, donate=False, **kw)
     metrics = []
     for b in batches:
         state, m = step(state, b)
@@ -131,15 +154,32 @@ class TestWindowStep:
         tx = optax.sgd(1e-6)
         state0 = TrainState.create({"params": state_a.params}, tx)
         batches = _batches(8)
-        s1, m1 = _run_per_step(model, tx, state0, batches,
-                               num_flow_updates=2)
+        from raft_tpu.train import make_train_step
+
+        step = make_train_step(model, tx, donate=False, num_flow_updates=2)
+        s1, m1 = _run_per_step(model, tx, state0, batches, step=step)
         s2, m2 = _run_windows(model, tx, state0, batches, 4,
                               num_flow_updates=2)
         assert int(s1.step) == int(s2.step) == 8
         _tree_allclose(s1.params, s2.params, rtol=1e-3, atol=1e-5)
         _tree_allclose(s1.opt_state, s2.opt_state, rtol=1e-3, atol=1e-5)
-        for a, b in zip(m1, m2):
-            np.testing.assert_allclose(a["loss"], b["loss"], rtol=1e-4)
+        # Loss trajectory, step for step. The tolerance is what fp32 can
+        # promise at this magnitude, measured: a ONE-ulp nudge of image1
+        # moves the loss (~180) by 3e-7 relative at step 1 and 1.07e-4 at
+        # step 8 (the trajectory amplifies rounding noise ~3x per step,
+        # even at this LR), and scan-vs-loop reassociation lands on the
+        # same curve (0 at step 1, 1.08e-4 at step 8 under jax 0.9's
+        # XLA). So each step is held to 10x the running ulp-noise floor,
+        # never below 1e-6 (a few ulps of a sum this size).
+        _, m3 = _run_per_step(
+            model, tx, state0, _ulp_nudged(batches), step=step
+        )
+        floor = 1e-6
+        for a, b, c in zip(m1, m2, m3):
+            floor = max(floor, _rel(a["loss"], c["loss"]))
+            assert _rel(a["loss"], b["loss"]) <= 10 * floor, (
+                a["loss"], b["loss"], floor
+            )
 
     def test_guard_counters_bitwise_under_faults(self):
         """NaN faults mid-window (step idx 1) AND at a window boundary
@@ -171,14 +211,25 @@ class TestWindowStep:
         in the per-step loop, and the EMA ignores it in both."""
         model, tx, state0 = _tiny_model_and_tx()
         batches = _batches(8)
-        FaultInjector.loss_spike(batches[5], scale=1e4)
-        s1, m1 = _run_per_step(model, tx, state0, batches, **GUARD_KW)
+        from raft_tpu.train import make_train_step
+
+        FaultInjector.loss_spike(batches[5])
+        step = make_train_step(model, tx, donate=False, **GUARD_KW)
+        s1, m1 = _run_per_step(model, tx, state0, batches, step=step)
         s2, m2 = _run_windows(model, tx, state0, batches, 4, **GUARD_KW)
         assert int(s1.skipped_steps) == int(s2.skipped_steps) == 1
         assert [float(m["skipped"]) for m in m2] == [0, 0, 0, 0, 0, 1, 0, 0]
         assert np.isfinite(float(m2[5]["grad_norm"]))
-        np.testing.assert_allclose(
-            float(s1.grad_ema), float(s2.grad_ema), rtol=5e-2
+        # The EMA after 8 AdamW steps at lr=1e-3 sits at the end of a
+        # chaotic trajectory: held to 10x what a ONE-ulp input nudge does
+        # to it (the fp32 noise floor of this comparison, measured in
+        # place), never below the 5e-2 the skip semantics need.
+        s3, _ = _run_per_step(
+            model, tx, state0, _ulp_nudged(batches), step=step
+        )
+        floor = max(5e-3, _rel(s1.grad_ema, s3.grad_ema))
+        assert _rel(s1.grad_ema, s2.grad_ema) <= 10 * floor, (
+            float(s1.grad_ema), float(s2.grad_ema), floor
         )
 
     def test_jaxpr_is_host_callback_free(self):
@@ -311,6 +362,32 @@ class TestWindowPipeline:
                     )
         assert per.step == win.step == 4  # same step bookkeeping
 
+    def test_windows_outlive_the_staging_ring(self):
+        """A window already handed out stays intact while later windows
+        are staged. On the CPU backend ``jax.device_put`` of an aligned
+        numpy array is zero-copy (the device array IS the host buffer),
+        so a ring slot that was transferred there must never be written
+        again — it is retired (``_WindowStaging.transferred``) and the slot
+        allocates anew. (This was the
+        load-dependent windowed-vs-per-step drift: under CPU contention
+        the producer thread rewrote a slot the train step had not read
+        yet.)"""
+        per = self._pipe()
+        it = iter(per)
+        flat = [next(it) for _ in range(12)]
+        it.close()
+        win = self._pipe(window_size=2, prefetch_depth=1)  # ring of 2
+        wit = iter(win)
+        windows = [next(wit) for _ in range(6)]
+        wit.close()
+        for w_idx, window in enumerate(windows):
+            for j in range(2):
+                for key, ref in flat[2 * w_idx + j].items():
+                    np.testing.assert_array_equal(
+                        np.asarray(window[key])[j], ref,
+                        err_msg=f"window {w_idx} row {j} {key} rewritten",
+                    )
+
     def test_staging_rotates_buffers(self):
         from raft_tpu.data.pipeline import _WindowStaging
 
@@ -387,7 +464,17 @@ def _trainer(monkeypatch, **kw):
 class TestTrainerWindow:
     def test_run_parity_with_per_step(self, monkeypatch):
         """A windowed run logs the same boundaries with the same scalars
-        (up to scan-fusion float noise) and lands on the same step."""
+        (up to scan-fusion float noise) and lands on the same step.
+
+        Measured on this installation (8 AdamW steps at the default LR):
+        window-vs-loop boundary losses differ by 4e-4 (step 4) and 3e-3
+        (step 8) relative, a ONE-ulp nudge of the first batch by 4e-5 /
+        5e-4 — same trajectory, fp32 noise amplified ~10x by the scan's
+        reassociation, well inside the 5e-2 below. The 5.5% step-4 gap
+        this test once showed under a loaded xdist run was not float
+        noise: the staging ring rewrote a zero-copy window the step had
+        not read yet (see
+        ``TestWindowPipeline::test_windows_outlive_the_staging_ring``)."""
         runs = {}
         for k in (1, 2):
             tr, _ = _trainer(monkeypatch, window_size=k)
