@@ -16,8 +16,9 @@ discipline: no RNG on the hot path, A/B runs reproducible): execution
 executions still count, so the ledger *extrapolates* each family's total
 device time (``mean sampled ms x executions``) — ``sample_every=1``
 makes the estimate exact at the cost of serializing the dispatch
-pipeline at every seam (the A/B bound in tests/test_observability.py
-pins that cost < 5% on the tiny-CPU smoke).
+pipeline at every seam (what that costs in pairs/s is a chip
+measurement, PERF.md; tests/test_observability.py pins that the off path
+times nothing).
 
 The measured interval is enqueue-to-ready, which includes any device
 work still draining ahead of the timed program. At ``sample_every >= 2``

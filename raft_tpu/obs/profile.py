@@ -17,14 +17,21 @@ manager — the cost is one attribute read and a truth test per dispatch.
 The annotations pair with ``jax.profiler.trace`` / the TensorBoard
 profiler capture (``TrainConfig.profile_port``); nothing here starts a
 profiler by itself.
+
+:func:`phase` is the same region for code that also keeps its own
+timeline (the pool scheduler's loop records, ISSUE 27): given a list it
+appends ``(name, t0, t1)`` from ``time.monotonic()`` on exit, inside the
+profiler region, so one ``with`` names the step on the profiler's clock
+and on the trace records' clock. Given ``None`` it is :func:`annotate`.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import time
 
-__all__ = ["enable", "disable", "enabled", "annotate"]
+__all__ = ["enable", "disable", "enabled", "annotate", "phase"]
 
 _NULL = contextlib.nullcontext()
 _on = os.environ.get("RAFT_OBS_PROFILE", "") not in ("", "0", "false")
@@ -54,3 +61,33 @@ def annotate(name: str):
         return jax.profiler.TraceAnnotation(name)
     except Exception:  # profiler unavailable: degrade to no-op, never raise
         return _NULL
+
+
+class _Phase:
+    """A profiler region (when enabled) around a ``(name, t0, t1)``
+    tuple appended to ``sink`` on exit."""
+
+    __slots__ = ("_name", "_sink", "_region", "_t0")
+
+    def __init__(self, name: str, sink: list):
+        self._name = name
+        self._sink = sink
+
+    def __enter__(self):
+        self._region = annotate(self._name)
+        self._region.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self._sink.append((self._name, self._t0, time.monotonic()))
+        return self._region.__exit__(*exc)
+
+
+def phase(name: str, sink=None):
+    """:func:`annotate`, plus a ``(name, t0, t1)`` tuple appended to the
+    list ``sink`` when one is given. With ``sink=None`` and profiling off
+    this is two truth tests: no clock read, no allocation."""
+    if sink is None:
+        return annotate(name)
+    return _Phase(name, sink)

@@ -28,6 +28,15 @@ Span ``t0_ms`` is relative to the trace start, so a trace reads as a
 timeline without clock arithmetic (docs/observability.md has a worked
 example).
 
+**Loop records** (ISSUE 27): a component whose own loop is worth a
+timeline (the pool scheduler) records one trace per *loop iteration*
+through :meth:`Tracer.start_loop` — sampled at the same rate but on a
+counter of its own, so loop records never change which requests are
+sampled, and finished into the ring only (never to ``on_finish``: the
+flight recorder's last-N ring is for request traces). The ring counts
+what it overwrites (:attr:`Tracer.dropped`), so a reader can tell a
+whole window from its tail.
+
 **Cross-process propagation** (ISSUE 15): a trace born at one component
 (the HTTP front door) can be *joined* by every component a request
 crosses. :class:`TraceContext` carries the edge-chosen ``trace_id`` (and,
@@ -143,19 +152,22 @@ class Trace:
             self.add_span(sp["name"], t0, t0 + sp["dur_ms"] / 1e3, **attrs)
 
     def finish(
-        self, *, ok: bool = True, error: Optional[str] = None, **meta
+        self, *, ok: bool = True, error: Optional[str] = None,
+        t_end: Optional[float] = None, **meta
     ) -> Optional[Dict[str, Any]]:
         """Seal the trace exactly once and push it to the recorder ring.
 
         Later calls are no-ops (worker/caller completion races mirror
         ``Request.finish``). Returns the record, or ``None`` if already
-        finished.
+        finished. ``t_end`` is a monotonic timestamp the caller already
+        holds (a loop record ends where the next loop starts).
         """
         with self._lock:
             if self._done:
                 return None
             self._done = True
-        t_end = time.monotonic()
+        if t_end is None:
+            t_end = time.monotonic()
         self._meta.update(meta)
         t0 = self.t_start
         rec: Dict[str, Any] = {
@@ -266,9 +278,11 @@ class Tracer:
     traced iff ``floor(n*rate) > floor((n-1)*rate)``, i.e. evenly spaced,
     reproducible, RNG-free.
 
-    Completed records go to a bounded ring (``capacity`` most recent) and
-    to any ``on_finish`` callbacks (the flight recorder's last-N-traces
-    ring hangs off one).
+    Completed records go to a bounded ring (``capacity`` most recent;
+    ``dropped`` counts the records it has overwritten) and to any
+    ``on_finish`` callbacks (the flight recorder's last-N-traces ring
+    hangs off one). Loop records (:meth:`start_loop`) go to the ring
+    only.
     """
 
     _ids = itertools.count()  # process-wide: trace ids never collide
@@ -293,10 +307,12 @@ class Tracer:
             maxlen=int(capacity)
         )
         self._counter = itertools.count()
+        self._loop_counter = itertools.count()
         self._on_finish = on_finish
         self._lock = threading.Lock()
         self.started = 0
         self.finished = 0
+        self.dropped = 0
 
     def start(
         self, kind: str, rid: Optional[int] = None,
@@ -315,18 +331,38 @@ class Tracer:
             return Trace(
                 str(trace_id), kind, rid, self._record, t_start=t_start
             )
-        rate = self.sample_rate
-        if rate <= 0.0:
-            return None
-        n = next(self._counter)
-        if rate < 1.0 and int((n + 1) * rate) == int(n * rate):
+        if not self._sampled(self._counter):
             return None
         self.started += 1
         tid = f"{self.prefix}-{next(Tracer._ids):08x}"
         return Trace(tid, kind, rid, self._record, t_start=t_start)
 
+    def start_loop(self, kind: str) -> Optional[Trace]:
+        """Begin the trace of one iteration of the component's own loop
+        (``None`` when unsampled, before any clock reading). Same rate
+        as :meth:`start`, a counter of its own; the finished record goes
+        to the ring only and moves neither ``started`` nor ``finished``,
+        which count requests."""
+        if not self._sampled(self._loop_counter):
+            return None
+        tid = f"{self.prefix}-{next(Tracer._ids):08x}"
+        return Trace(tid, kind, None, self._keep)
+
+    def _sampled(self, counter) -> bool:
+        rate = self.sample_rate
+        if rate <= 0.0:
+            return False
+        n = next(counter)
+        return rate >= 1.0 or int((n + 1) * rate) != int(n * rate)
+
+    def _keep(self, rec: Dict[str, Any]) -> None:
+        ring = self._ring
+        if len(ring) == ring.maxlen:
+            self.dropped += 1
+        ring.append(rec)  # deque(maxlen): bounded, lock-free append
+
     def _record(self, rec: Dict[str, Any]) -> None:
-        self._ring.append(rec)  # deque(maxlen): bounded, lock-free append
+        self._keep(rec)
         self.finished += 1
         if self._on_finish is not None:
             try:

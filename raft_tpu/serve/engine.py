@@ -291,6 +291,30 @@ class _Inflight:
     ] = None
 
 
+# engine trace ring: a 6 s traced window of the busiest cell finishes
+# ~160 request records and ~310 scheduler-loop records
+_TRACE_RING = 4096
+
+
+class _SchedLoop:
+    """One sampled iteration of the pool scheduler's loop: the phases it
+    went through (``(name, t0, t1)``, appended by ``_phase``) and what it
+    did, sealed into a ``"sched"`` trace record when the next iteration
+    starts."""
+
+    __slots__ = (
+        "trace", "cpu0", "t_end", "phases", "ticked", "admitted", "retired",
+    )
+
+    def __init__(self, trace):
+        self.trace = trace
+        self.cpu0 = self.t_end = 0.0  # set by _sched_seal / _sched_turn
+        self.phases: List[Tuple[str, float, float]] = []
+        self.ticked = 0
+        self.admitted: List[int] = []
+        self.retired: List[int] = []
+
+
 class _StagingPool:
     """Rotating preallocated host buffers, keyed by (role, bucket).
 
@@ -513,8 +537,15 @@ class ServeEngine:
         self.tracer = Tracer(
             cfg.trace_sample_rate,
             prefix="srv",
+            # holds a traced window whole: requests plus one "sched"
+            # record per pool-scheduler loop (a deque: memory only as
+            # records arrive); Tracer.dropped counts what it overwrote
+            capacity=_TRACE_RING,
             on_finish=self.recorder.add_trace,
         )
+        # the pool scheduler's current loop record, when that loop is
+        # sampled (see _sched_turn); _phase appends to it
+        self._loop: Optional[_SchedLoop] = None
         if logger is not None:
             # postmortem bundles persist through the logger's structured
             # events file (MetricLogger.log_event)
@@ -1735,6 +1766,7 @@ class ServeEngine:
                 "trace_sample_rate": self.config.trace_sample_rate,
                 "traces_started": self.tracer.started,
                 "traces_finished": self.tracer.finished,
+                "traces_dropped": self.tracer.dropped,
                 "events_recorded": self.recorder.events_recorded,
                 "postmortem_dumps": self.recorder.dumps,
             },
@@ -2563,8 +2595,19 @@ class ServeEngine:
         failure by contract — an admission failure costs that admission
         batch, a tick failure costs the residents of that pool, never the
         worker thread.
+
+        Every step of the loop runs inside one ``_phase`` (flat: never
+        two open at once), so a profiler capture and a sampled loop's
+        ``"sched"`` record both show where an iteration went
+        (docs/observability.md lists the phases).
         """
         while not self._stop.is_set():
+            closed = self._sched_turn()
+            loop = self._loop
+            with self._phase("serve/sched/upkeep"):
+                self._sched_seal(closed, loop)
+                self._log_counters()
+                self._alerts.maybe_observe()
             try:
                 for pool in list(self._pools.values()):
                     self._pool_retire(pool)
@@ -2572,11 +2615,12 @@ class ServeEngine:
                 for pool in list(self._pools.values()):
                     if pool.occupied_count():
                         self._pool_tick(pool)
+                        if loop is not None:
+                            loop.ticked += 1
             except Exception as e:  # isolation: fail residents, not the worker
                 self._count("worker_errors")
                 self._pool_fail_all(ServeError(f"pool tick failed: {e!r}"))
-            self._log_counters()
-            self._alerts.maybe_observe()
+        self._sched_seal(self._sched_turn(last=True), None)
         # shutdown: fail whatever is still resident, then drain the queue
         self._pool_fail_all(EngineStopped("engine stopping"))
         for r in self._queue.close():
@@ -2597,10 +2641,77 @@ class ServeEngine:
                     residents=len(metas), error=repr(err),
                 )
 
+    def _phase(self, name: str):
+        """The one phase helper of the pool scheduler's loop: a profiler
+        region when ``obs.profile`` is on, and ``(name, t0, t1)`` on the
+        current loop's record when that loop is sampled. Both off: two
+        truth tests, no clock read, no allocation."""
+        loop = self._loop
+        return profile.phase(name, None if loop is None else loop.phases)
+
+    def _sched_turn(self, last: bool = False) -> Optional[_SchedLoop]:
+        """Top of a scheduler-loop iteration: close the previous
+        iteration's record, if it was sampled, and open this one's, if it
+        is. One clock reading serves as the end of one ``loop`` span and
+        the start of the next, so sampled loops tile the thread's time.
+        Reads no clock when neither iteration is sampled. Returns the
+        closed record, for ``_sched_seal`` (which the loop calls inside
+        its first phase: the turn itself stays a few microseconds)."""
+        prev = self._loop
+        trace = None if last else self.tracer.start_loop("sched")
+        if prev is None and trace is None:
+            return None
+        if prev is not None:
+            prev.t_end = time.monotonic() if trace is None else trace.t_start
+        self._loop = None if trace is None else _SchedLoop(trace)
+        return prev
+
+    def _sched_seal(
+        self, closed: Optional[_SchedLoop], opened: Optional[_SchedLoop]
+    ) -> None:
+        """Read the thread's CPU clock once, for the loop that ended and
+        the one that began, and finish the ended loop's ``"sched"``
+        record: the ``loop`` span, its phases as children, and what the
+        iteration did. Nothing sampled: nothing read."""
+        if closed is None and opened is None:
+            return
+        cpu = time.thread_time()
+        if opened is not None:
+            opened.cpu0 = cpu
+        if closed is None or not (
+            closed.ticked or closed.admitted or closed.retired
+        ):
+            # nothing ended, or an idle poll of an empty queue did: 20 a
+            # second would wash the request traces out of the ring
+            return
+        tr = closed.trace
+        tr.add_span("loop", tr.t_start, closed.t_end)
+        for name, t0, t1 in closed.phases:
+            tr.add_span(name, t0, t1, parent="loop")
+        pools = list(self._pools.values())
+        tr.finish(
+            t_end=closed.t_end, ticked=closed.ticked,
+            occupied=sum(p.occupied_count() for p in pools),
+            pending=sum(len(p.pending) for p in pools),
+            admitted=len(closed.admitted), retired=len(closed.retired),
+            admitted_rids=closed.admitted, retired_rids=closed.retired,
+            cpu_ms=(cpu - closed.cpu0) * 1e3,
+        )
+
     def _pool_retire(self, pool: BucketPool) -> None:
-        """Free slots whose requests are finished, expired, or due for
-        finalization: target reached OR converged (residual-driven, once
-        past ``pool_min_iters``) OR a deadline-driven early exit.
+        """Scan the slots (``_pool_due``), then finalize what is due."""
+        with self._phase("serve/sched/retire"):
+            due = self._pool_due(pool)
+        if due:
+            self._pool_finalize(pool, due)
+
+    def _pool_due(
+        self, pool: BucketPool
+    ) -> List[Tuple[int, _SlotMeta, str]]:
+        """Free slots whose requests are finished or expired; return
+        the ones due for finalization: target reached OR converged
+        (residual-driven, once past ``pool_min_iters``) OR a
+        deadline-driven early exit.
 
         Precedence per slot, strictest first: a caller-side finish or a
         hard deadline expiry always wins (the slot is dead weight either
@@ -2653,8 +2764,7 @@ class ServeEngine:
                 # the deadline would expire before the remaining
                 # iterations finish: cash in the anytime ladder now
                 due.append((i, meta, "deadline"))
-        if due:
-            self._pool_finalize(pool, due)
+        return due
 
     def _pool_finalize(
         self, pool: BucketPool, due: List[Tuple[int, _SlotMeta, str]]
@@ -2670,41 +2780,52 @@ class ServeEngine:
         while len(due) > self._admit_cap:
             self._pool_finalize(pool, due[: self._admit_cap])
             due = due[self._admit_cap:]
-        rung = self._rung_admit(len(due))
-        idx = np.asarray(
-            [i for i, _, _ in due] + [due[0][0]] * (rung - len(due)),
-            np.int32,
-        )
-        live = [m.req for _, m, _ in due]
-        fetch_c1 = self._warm_start and any(
-            m.req.kind == "stream" for _, m, _ in due
-        )
+        with self._phase("serve/sched/gather"):
+            rung = self._rung_admit(len(due))
+            idx = np.asarray(
+                [i for i, _, _ in due] + [due[0][0]] * (rung - len(due)),
+                np.int32,
+            )
+            live = [m.req for _, m, _ in due]
+            fetch_c1 = self._warm_start and any(
+                m.req.kind == "stream" for _, m, _ in due
+            )
+            t_f = time.monotonic()
+            for _, meta, _ in due:
+                r = meta.req
+                if r.trace is not None:
+                    # the pool's per-iteration refinement window,
+                    # admission insert -> finalize gather
+                    r.trace.add_span(
+                        "refine", meta.admitted_t, t_f, iters=meta.done,
+                    )
 
         def run():
-            c1, hid, res = self._pool_gather(
-                pool.state["coords1"], pool.state["hidden"],
-                pool.state["resid_hist"], idx,
-            )
+            with self._phase("serve/sched/gather"):
+                c1, hid, res = self._pool_gather(
+                    pool.state["coords1"], pool.state["hidden"],
+                    pool.state["resid_hist"], idx,
+                )
+            flow = self._run_pool_final(c1, hid)
             # the residual trajectories (and, with warm start on, the
             # retiring streams' final 1/8-grid coords) ride the fetch
             # the finalize already pays — the flow asarray below is the
-            # sync point, both are computed and resident by then
-            return (
-                np.asarray(self._run_pool_final(c1, hid)),
-                np.asarray(res),
-                np.asarray(c1) if fetch_c1 else None,
-            )
-
-        t_f = time.monotonic()
-        for _, meta, _ in due:
-            r = meta.req
-            if r.trace is not None:
-                # the pool's per-iteration refinement window, admission
-                # insert -> finalize gather
-                r.trace.add_span(
-                    "refine", meta.admitted_t, t_f, iters=meta.done,
+            # sync point, both are computed and resident by then. The
+            # wait on the device has a phase to itself.
+            with self._phase("serve/sched/fetch"):
+                return (
+                    np.asarray(flow),
+                    np.asarray(res),
+                    np.asarray(c1) if fetch_c1 else None,
                 )
+
         out, tripped = self._guarded_dispatch(live, run)
+        with self._phase("serve/sched/complete"):
+            self._pool_complete(pool, due, live, t_f, out, tripped)
+
+    def _pool_complete(self, pool, due, live, t_f, out, tripped) -> None:
+        """Hand the fetched flows to their requests (crop, ``finish``,
+        callbacks) and free the slots."""
         self._trace_span(live, "fetch", t_f)
         with self._lock:
             self._counters["batches"] += 1
@@ -2716,6 +2837,8 @@ class ServeEngine:
                 if meta.req.kind == "stream":
                     self._invalidate_stream(meta.req.stream_id)
             return
+        if self._loop is not None:
+            self._loop.retired.extend(r.rid for r in live)
         flows, resids, c1_rows = out
         for pos, (i, meta, reason) in enumerate(due):
             r = meta.req
@@ -2799,23 +2922,26 @@ class ServeEngine:
             pool = self._pools.get(bucket)
             return self._pool_cap if pool is None else pool.free_count()
 
-        busy = any(
-            p.occupied_count() or p.pending for p in self._pools.values()
-        )
-        batch = self._queue.next_batch(
-            self._admit_cap,
-            0.0,                      # admission never dawdles for stragglers
-            poll=0.0 if busy else 0.05,
-            cap=cap,
-        )
+        with self._phase("serve/sched/poll"):
+            busy = any(
+                p.occupied_count() or p.pending for p in self._pools.values()
+            )
+            batch = self._queue.next_batch(
+                self._admit_cap,
+                0.0,                  # admission never dawdles for stragglers
+                poll=0.0 if busy else 0.05,
+                cap=cap,
+            )
         if not batch:
             return
         live: List[Request] = []
         try:
-            live = self._filter_live(batch)
+            with self._phase("serve/sched/poll"):
+                live = self._filter_live(batch)
+                if live:
+                    pool = self._pool_for(live[0].bucket)
+                    ctrl_iters, level = self._observe(live)
             if live:
-                pool = self._pool_for(live[0].bucket)
-                ctrl_iters, level = self._observe(live)
                 if live[0].kind == "stream":
                     self._pool_admit_stream(pool, live, ctrl_iters, level)
                 else:
@@ -2847,19 +2973,7 @@ class ServeEngine:
             if not plain:
                 return
             live = plain
-        bh, bw = pool.bucket
-        rung = self._rung_admit(len(live))
-        shape = (self._admit_cap, bh, bw, 3)
-        t_form = time.monotonic()
-        self._trace_queue_wait(live, t_form)
-        p1 = self._staging.fill(
-            ("pool_p1", pool.bucket), shape, [r.p1 for r in live], rung
-        )
-        p2 = self._staging.fill(
-            ("pool_p2", pool.bucket), shape, [r.p2 for r in live], rung
-        )
-        t0 = time.monotonic()
-        self._trace_span(live, "batch_form", t_form, t0, rung=rung)
+        p1, p2, rung, t0 = self._pool_stage_pairs(pool, live)
         rows, tripped = self._guarded_dispatch(
             live, lambda: self._run_pool_begin(p1, p2)
         )
@@ -2867,6 +2981,26 @@ class ServeEngine:
             return
         self._trace_span(live, "dispatch", t0, rung=rung)
         self._pool_insert_live(pool, rows, live, ctrl_iters, level)
+
+    def _pool_stage_pairs(self, pool: BucketPool, live: List[Request]):
+        """Copy the cohort's frames into the rotating staging buffers
+        (the ``batch_form`` span). Returns ``(p1, p2, rung, t)`` with
+        ``t`` the moment staging ended and the dispatch begins."""
+        with self._phase("serve/sched/stage"):
+            bh, bw = pool.bucket
+            rung = self._rung_admit(len(live))
+            shape = (self._admit_cap, bh, bw, 3)
+            t_form = time.monotonic()
+            self._trace_queue_wait(live, t_form)
+            p1 = self._staging.fill(
+                ("pool_p1", pool.bucket), shape, [r.p1 for r in live], rung
+            )
+            p2 = self._staging.fill(
+                ("pool_p2", pool.bucket), shape, [r.p2 for r in live], rung
+            )
+            t0 = time.monotonic()
+            self._trace_span(live, "batch_form", t_form, t0, rung=rung)
+        return p1, p2, rung, t0
 
     def _pool_admit_pairs_seeded(
         self, pool: BucketPool, live: List[Request], ctrl_iters: int,
@@ -2884,19 +3018,7 @@ class ServeEngine:
         carry encode(0) garbage that the insert mask discards, exactly
         like the stream path's.
         """
-        bh, bw = pool.bucket
-        rung = self._rung_admit(len(live))
-        shape = (self._admit_cap, bh, bw, 3)
-        t_form = time.monotonic()
-        self._trace_queue_wait(live, t_form)
-        p1 = self._staging.fill(
-            ("pool_p1", pool.bucket), shape, [r.p1 for r in live], rung
-        )
-        p2 = self._staging.fill(
-            ("pool_p2", pool.bucket), shape, [r.p2 for r in live], rung
-        )
-        t_e = time.monotonic()
-        self._trace_span(live, "batch_form", t_form, t_e, rung=rung)
+        p1, p2, rung, t_e = self._pool_stage_pairs(pool, live)
         out, tripped = self._guarded_dispatch(
             live, lambda: (self._run_encode(p1), self._run_encode(p2))
         )
@@ -2904,12 +3026,13 @@ class ServeEngine:
             return
         (f1, c1), (f2, _c2) = out
         self._trace_span(live, "encode", t_e, rung=rung)
-        ishape = (self._admit_cap,) + tuple(f1.shape[1:3]) + (2,)
-        ifl = self._staging.fill(
-            ("pool_init", pool.bucket), ishape, [r.init8 for r in live],
-            rung,
-        )
-        t0 = time.monotonic()
+        with self._phase("serve/sched/stage"):
+            ishape = (self._admit_cap,) + tuple(f1.shape[1:3]) + (2,)
+            ifl = self._staging.fill(
+                ("pool_init", pool.bucket), ishape,
+                [r.init8 for r in live], rung,
+            )
+            t0 = time.monotonic()
         rows, tripped = self._guarded_dispatch(
             live, lambda: self._run_pool_begin_features(f1, f2, c1, ifl)
         )
@@ -2922,46 +3045,55 @@ class ServeEngine:
         self, pool: BucketPool, live: List[Request], ctrl_iters: int,
         level: int,
     ) -> None:
-        bh, bw = pool.bucket
-        rung = self._rung_admit(len(live))
-        shape = (self._admit_cap, bh, bw, 3)
-        t_form = time.monotonic()
-        self._trace_queue_wait(live, t_form)
-        frames = self._staging.fill(
-            ("pool_frames", pool.bucket), shape, [r.p2 for r in live], rung
-        )
+        with self._phase("serve/sched/stage"):
+            bh, bw = pool.bucket
+            rung = self._rung_admit(len(live))
+            shape = (self._admit_cap, bh, bw, 3)
+            t_form = time.monotonic()
+            self._trace_queue_wait(live, t_form)
+            frames = self._staging.fill(
+                ("pool_frames", pool.bucket), shape,
+                [r.p2 for r in live], rung,
+            )
+            t_e = time.monotonic()
 
         def run_encode():
             fm, cx = self._run_encode(frames)
-            return np.asarray(fm), np.asarray(cx)
+            # the admit group's wait on the device, a phase to itself
+            with self._phase("serve/sched/encode_fetch"):
+                return np.asarray(fm), np.asarray(cx)
 
-        t_e = time.monotonic()
         (fmap_np, ctx_np), tripped = self._guarded_dispatch(live, run_encode)
         if tripped:
             return
         self._trace_span(live, "encode", t_e, rung=rung)
-        flow_reqs, rows = self._stream_transact(
-            live, fmap_np, ctx_np, ctrl_iters, level
-        )
-        if not flow_reqs:
-            return
-        rung2 = self._rung_admit(len(flow_reqs))
-        fshape = (self._admit_cap,) + fmap_np.shape[1:]
-        cshape = (self._admit_cap,) + ctx_np.shape[1:]
-        ishape = (self._admit_cap,) + fmap_np.shape[1:3] + (2,)
-        f1 = self._staging.fill(
-            ("pool_f1", pool.bucket), fshape, [rr[0] for rr in rows], rung2
-        )
-        f2 = self._staging.fill(
-            ("pool_f2", pool.bucket), fshape, [rr[1] for rr in rows], rung2
-        )
-        cx = self._staging.fill(
-            ("pool_ctx", pool.bucket), cshape, [rr[2] for rr in rows], rung2
-        )
-        ifl = self._staging.fill(
-            ("pool_init", pool.bucket), ishape, [rr[3] for rr in rows], rung2
-        )
-        t0 = time.monotonic()
+        with self._phase("serve/sched/stage"):
+            flow_reqs, rows = self._stream_transact(
+                live, fmap_np, ctx_np, ctrl_iters, level
+            )
+            if not flow_reqs:
+                return
+            rung2 = self._rung_admit(len(flow_reqs))
+            fshape = (self._admit_cap,) + fmap_np.shape[1:]
+            cshape = (self._admit_cap,) + ctx_np.shape[1:]
+            ishape = (self._admit_cap,) + fmap_np.shape[1:3] + (2,)
+            f1 = self._staging.fill(
+                ("pool_f1", pool.bucket), fshape,
+                [rr[0] for rr in rows], rung2,
+            )
+            f2 = self._staging.fill(
+                ("pool_f2", pool.bucket), fshape,
+                [rr[1] for rr in rows], rung2,
+            )
+            cx = self._staging.fill(
+                ("pool_ctx", pool.bucket), cshape,
+                [rr[2] for rr in rows], rung2,
+            )
+            ifl = self._staging.fill(
+                ("pool_init", pool.bucket), ishape,
+                [rr[3] for rr in rows], rung2,
+            )
+            t0 = time.monotonic()
         state_rows, tripped = self._guarded_dispatch(
             flow_reqs,
             lambda: self._run_pool_begin_features(f1, f2, cx, ifl),
@@ -2986,6 +3118,10 @@ class ServeEngine:
         through ONE insert dispatch (rows beyond ``len(live)`` are
         padding lanes, masked out).
         """
+        with self._phase("serve/sched/insert"):
+            self._pool_insert_slots(pool, rows, live, ctrl_iters, level)
+
+    def _pool_insert_slots(self, pool, rows, live, ctrl_iters, level) -> None:
         now = time.monotonic()
         rung = int(rows["coords1"].shape[0])
         slots = [pool.alloc() for _ in live]
@@ -2996,6 +3132,8 @@ class ServeEngine:
             [True] * len(slots) + [False] * (rung - len(slots)), bool
         )
         pool.state = self._pool_insert(pool.state, rows, idx, mask)
+        if self._loop is not None:
+            self._loop.admitted.extend(r.rid for r in live)
         qos_on = self.config.qos_enabled
         ladder = self._controller.ladder
         for i, r in zip(slots, live):
@@ -3029,6 +3167,24 @@ class ServeEngine:
         converged mask of its tick — one ``np.asarray`` in place of the
         old ``block_until_ready``, so convergence costs zero new host
         syncs (tripwire-asserted in tests)."""
+        with self._phase("serve/pool_step"):
+            live = self._pool_tick_dispatch(pool)
+        while (
+            live is not None
+            and len(pool.pending) > self.config.pipeline_depth
+        ):
+            # the wait on the device (the oldest tick's pacing token)
+            # has a phase to itself, apart from the dispatch above
+            with self._phase("serve/sched/drain"):
+                if not self._pool_tick_drain(pool, live):
+                    return
+
+    def _pool_tick_dispatch(
+        self, pool: BucketPool
+    ) -> Optional[List[Request]]:
+        """Dispatch one ``pool_step`` and do the tick's bookkeeping;
+        returns the residents it advanced (``None`` after a watchdog
+        trip: the pool was reset)."""
         occupied = pool.occupied()
         live = [m.req for _, m in occupied]
         frozen_n = sum(1 for _, m in occupied if m.converged)
@@ -3037,17 +3193,8 @@ class ServeEngine:
         )
         if tripped:
             # residents already failed by the watchdog callback
-            cleared = pool.clear()
-            for m in cleared:
-                if m.req.kind == "stream":
-                    self._invalidate_stream(m.req.stream_id)
-            with self._lock:
-                self._counters["pool_resets"] += 1
-            self.recorder.record(
-                "pool_reset", bucket=f"{pool.bucket[0]}x{pool.bucket[1]}",
-                residents=len(cleared), error="watchdog trip",
-            )
-            return
+            self._pool_reset_tripped(pool, "watchdog trip")
+            return None
         coords1, hidden, resid_hist, converged, token = out
         pool.state = {
             **pool.state, "coords1": coords1, "hidden": hidden,
@@ -3075,31 +3222,37 @@ class ServeEngine:
                 self._counters["inflight_peak"], len(pool.pending) + 1
             )
         pool.pending.append((time.monotonic(), token, occupants))
-        while len(pool.pending) > self.config.pipeline_depth:
-            _, tok, occ = pool.pending.popleft()
-            mask, tripped = self._guarded_dispatch(
-                live, lambda: np.asarray(tok)
+        return live
+
+    def _pool_tick_drain(self, pool: BucketPool, live: List[Request]) -> bool:
+        """Fetch the oldest pending tick's pacing token (blocks until
+        that tick is done on the device) and believe its converged mask.
+        False after a watchdog trip: the pool was reset."""
+        _, tok, occ = pool.pending.popleft()
+        mask, tripped = self._guarded_dispatch(live, lambda: np.asarray(tok))
+        now = time.monotonic()
+        pool.note_drain(now)
+        with self._lock:
+            self._batch_ms_ewma += 0.2 * (
+                pool.tick_ewma_ms - self._batch_ms_ewma
             )
-            now = time.monotonic()
-            pool.note_drain(now)
-            with self._lock:
-                self._batch_ms_ewma += 0.2 * (
-                    pool.tick_ewma_ms - self._batch_ms_ewma
-                )
-            if tripped:
-                cleared = pool.clear()
-                for m in cleared:
-                    if m.req.kind == "stream":
-                        self._invalidate_stream(m.req.stream_id)
-                with self._lock:
-                    self._counters["pool_resets"] += 1
-                self.recorder.record(
-                    "pool_reset",
-                    bucket=f"{pool.bucket[0]}x{pool.bucket[1]}",
-                    residents=len(cleared), error="watchdog trip (drain)",
-                )
-                return
-            self._apply_converged_mask(pool, mask, occ)
+        if tripped:
+            self._pool_reset_tripped(pool, "watchdog trip (drain)")
+            return False
+        self._apply_converged_mask(pool, mask, occ)
+        return True
+
+    def _pool_reset_tripped(self, pool: BucketPool, error: str) -> None:
+        cleared = pool.clear()
+        for m in cleared:
+            if m.req.kind == "stream":
+                self._invalidate_stream(m.req.stream_id)
+        with self._lock:
+            self._counters["pool_resets"] += 1
+        self.recorder.record(
+            "pool_reset", bucket=f"{pool.bucket[0]}x{pool.bucket[1]}",
+            residents=len(cleared), error=error,
+        )
 
     def _apply_converged_mask(self, pool: BucketPool, mask, occupants) -> None:
         """Mark slots the fetched pacing token reports converged.
@@ -3134,7 +3287,7 @@ class ServeEngine:
         """Dispatch one pool admission (pair encode + state init); seam."""
         key = ("pool_begin_pair", p1.shape[0], p1.shape[1], p1.shape[2])
         ex = self._aot_execs.get(key)
-        with profile.annotate("serve/pool_begin"):
+        with self._phase("serve/pool_begin"):
             if ex is not None:
                 return self.ledger.run(key, lambda: ex(self._dev_vars, p1, p2))
             return self.ledger.run(
@@ -3147,7 +3300,7 @@ class ServeEngine:
         the traced warm-start seed, zeros for a cold start); seam."""
         key = ("pool_begin_features", f1.shape[0], f1.shape[1], f1.shape[2])
         ex = self._aot_execs.get(key)
-        with profile.annotate("serve/pool_begin_features"):
+        with self._phase("serve/pool_begin_features"):
             if ex is not None:
                 return self.ledger.run(
                     key, lambda: ex(self._dev_vars, f1, f2, ctx, init_flow)
@@ -3168,17 +3321,16 @@ class ServeEngine:
         key = ("pool_step", c.shape[0], c.shape[1], c.shape[2])
         ex = self._aot_execs.get(key)
         th, sk, mi = self._conv_thresh, self._conv_streak, self._conv_min
-        with profile.annotate("serve/pool_step"):
-            if ex is not None:
-                return self.ledger.run(
-                    key, lambda: ex(self._dev_vars, state, th, sk, mi)
-                )
+        # no region of its own: _pool_tick opens "serve/pool_step" around
+        # this call and the tick's bookkeeping
+        if ex is not None:
             return self.ledger.run(
-                key,
-                lambda: self._pool_progs.step(
-                    self._dev_vars, state, th, sk, mi
-                ),
+                key, lambda: ex(self._dev_vars, state, th, sk, mi)
             )
+        return self.ledger.run(
+            key,
+            lambda: self._pool_progs.step(self._dev_vars, state, th, sk, mi),
+        )
 
     def _run_pool_final(self, coords1, hidden):
         """Dispatch the final-upsample stage for retiring slots; seam."""
@@ -3187,7 +3339,7 @@ class ServeEngine:
             coords1.shape[2],
         )
         ex = self._aot_execs.get(key)
-        with profile.annotate("serve/pool_final"):
+        with self._phase("serve/pool_final"):
             if ex is not None:
                 return self.ledger.run(
                     key, lambda: ex(self._dev_vars, coords1, hidden)
@@ -3422,7 +3574,7 @@ class ServeEngine:
         """Dispatch one frame-encode batch (stream path); seam."""
         key = ("encode", frames.shape[0], frames.shape[1], frames.shape[2])
         ex = self._aot_execs.get(key)
-        with profile.annotate("serve/encode"):
+        with self._phase("serve/encode"):
             if ex is not None:
                 return self.ledger.run(key, lambda: ex(self._dev_vars, frames))
             return self.ledger.run(

@@ -1083,8 +1083,13 @@ class ServeRouter:
             if eng is None:
                 continue
             try:
-                # the replicas' latest traces join the bundle's trace ring
-                for rec in eng.tracer.snapshot()[-16:]:
+                # the replicas' latest request traces join the bundle's
+                # trace ring (not their scheduler-loop records)
+                recs = [
+                    rec for rec in eng.tracer.snapshot()
+                    if rec.get("kind") != "sched"
+                ]
+                for rec in recs[-16:]:
                     self.recorder.add_trace(rec)
                 engines_extra[rep.replica_id] = {
                     "events": eng.recorder.events()[-32:],

@@ -541,7 +541,7 @@ ENGINE_POOL_KEYS = frozenset({
 })
 ENGINE_OBS_KEYS = frozenset({
     "events_recorded", "postmortem_dumps", "trace_sample_rate",
-    "traces_finished", "traces_started",
+    "traces_dropped", "traces_finished", "traces_started",
 })
 ENGINE_HEALTH_KEYS = frozenset({
     "draining", "healthy", "level", "num_flow_updates", "quarantined",
@@ -1574,64 +1574,53 @@ class TestDeviceTimeLedgerEngine:
 
 @pytest.mark.chaos
 class TestLedgerOverhead:
-    def _throughput(self, tiny_model, artifact, k, seconds, clients=4):
-        rng = np.random.default_rng(0)
-        im1, im2 = _image(rng), _image(rng)
-        done = [0] * clients
-        stop = threading.Event()
-        with _engine(
-            tiny_model, artifact=artifact, ledger_sample_every=k,
-            queue_capacity=32,
-        ) as eng:
-
-            def worker(i):
-                while not stop.is_set():
-                    try:
-                        eng.submit(im1, im2, deadline_ms=60000.0)
-                        done[i] += 1
-                    except ServeError:
-                        pass
-
-            threads = [
-                threading.Thread(target=worker, args=(i,), daemon=True)
-                for i in range(clients)
-            ]
-            t0 = time.monotonic()
-            for t in threads:
-                t.start()
-            time.sleep(seconds)
-            stop.set()
-            for t in threads:
-                t.join(timeout=30.0)
-            elapsed = time.monotonic() - t0
-        return sum(done) / elapsed
-
-    def test_ledger_on_overhead_under_5_percent(
-        self, tiny_model, shared_artifact
+    def test_ledger_off_times_no_dispatch(
+        self, tiny_model, shared_artifact, rng, monkeypatch
     ):
-        """A/B: closed-loop throughput with the ledger off vs K=1 (every
-        dispatch timed + blocked). Interleaved rounds, best-per-arm
-        (mirrors the tracing-overhead A/B); the timed arm must stay
-        within 5% of the untimed one."""
-        seconds = 1.2
-        best = {"off": 0.0, "on": 0.0}
-        ratio = 0.0
-        for _ in range(3):  # A B, A B, A B — early exit once in bound
-            best["off"] = max(
-                best["off"],
-                self._throughput(tiny_model, shared_artifact, 0, seconds),
-            )
-            best["on"] = max(
-                best["on"],
-                self._throughput(tiny_model, shared_artifact, 1, seconds),
-            )
-            ratio = best["on"] / max(best["off"], 1e-9)
-            if ratio >= 0.95:
-                break
-        assert best["off"] > 0 and best["on"] > 0
-        assert ratio >= 0.95, (
-            f"ledger-on throughput regressed {100 * (1 - ratio):.1f}% "
-            f"(off={best['off']:.1f} rps, on={best['on']:.1f} rps)"
+        """The ledger's promise, structurally (its cost in pairs/s is a
+        chip number now, PERF.md; the wall-clock A/B this replaces raced
+        five xdist workers): off, no dispatch is timed or blocked on —
+        the ledger reads no clock, calls no ``block_until_ready`` and
+        registers no family; at K=1 every dispatch is, exactly once."""
+        import types
+
+        import jax
+
+        from raft_tpu.obs import ledger as ledger_mod
+
+        clock_reads, blocks = [], []
+        monkeypatch.setattr(ledger_mod, "time", types.SimpleNamespace(
+            perf_counter=lambda: clock_reads.append(1) or time.perf_counter()
+        ))
+        real_block = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: blocks.append(1) or real_block(x),
+        )
+        im1, im2 = _image(rng), _image(rng)
+        seen = {}
+        for k in (0, 1):
+            del clock_reads[:], blocks[:]
+            with _engine(
+                tiny_model, artifact=shared_artifact, ledger_sample_every=k,
+            ) as eng:
+                for _ in range(4):
+                    eng.submit(im1, im2, deadline_ms=60000.0)
+                seen[k] = (
+                    len(clock_reads), len(blocks),
+                    eng.device_time_breakdown(), eng.stats()["batches"],
+                )
+        reads, blocked, bd, _ = seen[0]
+        assert (reads, blocked) == (0, 0)
+        assert bd["families"] == 0 and bd["sampled_dispatches"] == 0
+        reads, blocked, bd, batches = seen[1]
+        assert batches >= 4
+        # boot's smoke runs are dispatches too
+        assert bd["sampled_dispatches"] >= batches
+        assert reads == 2 * bd["sampled_dispatches"]
+        assert blocked >= bd["sampled_dispatches"]
+        assert all(
+            f["sampled"] == f["executions"] for f in bd["by_family"].values()
         )
 
 

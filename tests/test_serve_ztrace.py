@@ -26,9 +26,10 @@ Layers of coverage:
   handshake; the ``trace_propagation=False`` arm speaks exactly that
   wire) still serves against the new parent: spans degrade to the
   parent-side transport view, nothing raises.
-* **overhead** — the tracing A/B re-run THROUGH the front door with
-  propagation on: end-to-end overhead < 5% at rate 1.0 (interleaved
-  best-of-rounds).
+* **overhead** — structural: at rate 0 a request THROUGH the front
+  door builds no ``Trace`` anywhere; at rate 1.0 exactly the edge trace
+  and the engine's joined one (what tracing costs in pairs/s is measured
+  on the chip, PERF.md).
 
 This module is named to sort AFTER tests/test_serve_xport.py: tier-1's
 870s truncation and the process-global compile-cache order dependency
@@ -39,7 +40,6 @@ test_serve_worker fixture pattern).
 """
 
 import json
-import threading
 import time
 
 import numpy as np
@@ -49,7 +49,6 @@ from raft_tpu.obs import TraceContext, Tracer, dedupe_traces
 from raft_tpu.serve import (
     RouterConfig,
     ServeEngine,
-    ServeError,
     ServeFrontend,
     ServeRouter,
     FrontendClient,
@@ -516,80 +515,74 @@ class TestBackCompatPR14Wire:
 
 
 # ---------------------------------------------------------------------------
-# overhead: the tracing A/B through the front door, propagation on
+# overhead: the off path through the front door builds no Trace
 # ---------------------------------------------------------------------------
 
 
 class TestEdgeTracingOverhead:
-    def _throughput(self, tiny_model, artifact, rate, seconds, clients=4):
+    def test_tracing_off_builds_no_trace_through_the_front_door(
+        self, tiny_model, shared_artifact, monkeypatch
+    ):
+        """The propagation machinery's promise, structurally (what
+        tracing costs in pairs/s is a chip number now, PERF.md; the
+        wall-clock A/B this replaces raced five xdist workers): at rate
+        0 a request THROUGH the HTTP front door builds no ``Trace``
+        anywhere — frontend, engine, scheduler loop — and no record; at
+        rate 1.0 each request builds its edge trace and the engine's
+        joined one, stitched under ONE id."""
+        from raft_tpu.obs import trace as trace_mod
+
+        built = []
+        real_init = trace_mod.Trace.__init__
+
+        def counting_init(self, trace_id, kind, *a, **kw):
+            built.append(kind)
+            real_init(self, trace_id, kind, *a, **kw)
+
+        monkeypatch.setattr(trace_mod.Trace, "__init__", counting_init)
         model, variables = tiny_model
         rng = np.random.default_rng(0)
         im1, im2 = _image(rng), _image(rng)
-        done = [0] * clients
-        stop = threading.Event()
-        eng = ServeEngine(
-            model, variables,
-            _config(warmup=True, warmup_artifact=artifact,
-                    trace_sample_rate=rate, queue_capacity=32),
-        )
-        eng.start()
-        fe = ServeFrontend(eng, trace_sample_rate=rate).start()
-        try:
-            def worker(i):
+        n = 4
+        for rate in (0.0, 1.0):
+            del built[:]
+            eng = ServeEngine(
+                model, variables,
+                _config(warmup=True, warmup_artifact=shared_artifact,
+                        trace_sample_rate=rate, queue_capacity=32),
+            )
+            eng.start()
+            fe = ServeFrontend(eng, trace_sample_rate=rate).start()
+            try:
                 fc = FrontendClient(fe.address)
-                while not stop.is_set():
-                    try:
-                        fc.submit(im1, im2, deadline_ms=60000.0)
-                        done[i] += 1
-                    except ServeError:
-                        pass
+                metas = [
+                    fc.submit(im1, im2, deadline_ms=60000.0) for _ in range(n)
+                ]
                 fc.close_connection()
-
-            threads = [
-                threading.Thread(target=worker, args=(i,), daemon=True)
-                for i in range(clients)
-            ]
-            t0 = time.monotonic()
-            for t in threads:
-                t.start()
-            time.sleep(seconds)
-            stop.set()
-            for t in threads:
-                t.join(timeout=30.0)
-            elapsed = time.monotonic() - t0
-        finally:
-            fe.close()
-            eng.stop()
-        return sum(done) / elapsed
-
-    def test_propagated_tracing_overhead_under_5_percent(
-        self, tiny_model, shared_artifact
-    ):
-        """End-to-end A/B THROUGH the HTTP front door: rate 0 (no edge
-        trace, no propagation) vs rate 1.0 (every request stitched
-        across frontend + engine). Interleaved rounds, best-per-arm,
-        early exit once the 5% bound holds — the TestTracingOverhead
-        protocol, now covering the whole propagation machinery."""
-        seconds = 1.2
-        best = {"off": 0.0, "on": 0.0}
-        ratio = 0.0
-        for _ in range(3):
-            best["off"] = max(
-                best["off"],
-                self._throughput(tiny_model, shared_artifact, 0.0, seconds),
-            )
-            best["on"] = max(
-                best["on"],
-                self._throughput(tiny_model, shared_artifact, 1.0, seconds),
-            )
-            ratio = best["on"] / max(best["off"], 1e-9)
-            if ratio >= 0.95:
-                break
-        assert best["off"] > 0 and best["on"] > 0
-        assert ratio >= 0.95, (
-            f"edge tracing + propagation cost {(1 - ratio) * 100:.1f}% "
-            f"(> 5%): off={best['off']:.1f} on={best['on']:.1f} req/s"
-        )
+                deadline = time.monotonic() + 5.0
+                while (  # an edge trace seals after its response went out
+                    len(fe.tracer.snapshot()) < n * rate
+                    and time.monotonic() < deadline
+                ):
+                    time.sleep(0.01)
+                edge, inner = fe.tracer.snapshot(), eng.tracer.snapshot()
+            finally:
+                fe.close()
+                eng.stop()
+            if rate == 0.0:
+                assert built == []
+                assert edge == [] and inner == []
+                assert all(m.get("edge_trace_id") is None for m in metas)
+                assert eng.stats()["obs"]["traces_started"] == 0
+            else:
+                requests = [k for k in built if k != "sched"]
+                assert len(requests) == 2 * n  # edge + engine, per request
+                ids = [m["edge_trace_id"] for m in metas]
+                assert len(set(ids)) == n
+                assert sorted(r["trace_id"] for r in edge) == sorted(ids)
+                for rec in edge:  # the engine's spans joined the edge trace
+                    names = {s["name"] for s in rec["spans"]}
+                    assert {"http_read", "dispatch", "http_write"} <= names
 
 
 # ---------------------------------------------------------------------------
