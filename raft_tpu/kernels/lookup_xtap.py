@@ -1217,6 +1217,36 @@ class FusedLookupCorrBlock(CorrBlock):
             return pyramid["levels"], pyramid["flats"], pyramid.get("scales")
         return pyramid, (), None
 
+    def resident_pyramid(self, pyramid):
+        """The packed ``pyramid`` in the shapes to HOLD it in across many
+        lookups (the serve pool's slot state): each level the kernel
+        takes as a raw ``(q, hl, wl)`` volume block — the y-dot levels,
+        contracted in the kernel — zero-padded to whole ``(8, 128)``
+        tiles. Zero data past the grid is exactly an out-of-range tap,
+        so the lookup is unchanged; what changes is how the array lies
+        in memory. The Mosaic call wants those operands row-major,
+        tiled over ``(hl, wl)``. For a resident ``(slots, Q, 55, 128,
+        1)`` leaf the TPU's default layout tiles over ``Q`` instead
+        (55 rows would pad to 56; a 64-lane level gets ``Q`` on the
+        lanes), and every lookup then begins with a relayout ``copy``
+        of the whole level. A shape with no tile padding left to save
+        gets the row-major default — the operand's own layout — and
+        the buffers go to the kernel as they are
+        (``tests/test_chip_compile.py`` holds the compiler to that).
+        It costs the padding the operand carried anyway: ``[55, 128]``
+        -> ``[56, 128]``, ``[27, 64]`` -> ``[32, 128]``. The small
+        levels' raw copies (they reach the kernel as ``flats``) stay
+        as built, and so does everything when XLA contracts the levels
+        (``ydot_in_kernel=False``)."""
+        levels = list(pyramid["levels"])
+        if self.ydot_in_kernel:
+            for l in _split_levels(levels, 2 * self.radius + 1)[0]:
+                hl, wl = levels[l].shape[1:3]
+                pads = [(0, 0)] * levels[l].ndim
+                pads[1:3] = (0, -hl % 8), (0, -wl % MAX_LANES)
+                levels[l] = jnp.pad(levels[l], pads)
+        return dict(pyramid, levels=levels)
+
     def _lookup_dtype(self, scales):
         # int8 pyramids emit bf16 rows/taps; the block dtype otherwise
         return jnp.bfloat16 if scales is not None else self.dtype

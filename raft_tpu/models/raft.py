@@ -327,6 +327,10 @@ class RAFT(nn.Module):
                 "slot pool (per-build dequant scales); serve int8 with "
                 "pool_capacity=0"
             )
+        if packed:
+            # held across every iterate_step: in the block's resident
+            # shapes, so that no step re-lays a level before reading it
+            pyramid = self.corr_block.resident_pyramid(pyramid)
         # every leaf (levels, and the packed form's flat rows) is q-major
         pyramid = jax.tree.map(
             lambda lvl: lvl.reshape((b, h8 * w8) + lvl.shape[1:]),

@@ -61,6 +61,24 @@ in-class note for the mesh exception) so slot writes are in-place
 scatters, never a pool-sized copy; ``step`` returns only the recurrent
 carry (coords + hidden) plus a scalar pacing token, so the pyramid is
 never copied per tick.
+
+That last clause also needs the state to hold the pyramid the way the
+lookup kernel reads it: a ``step`` lowered against a level whose
+default layout is not the kernel operand's begins with a relayout
+``copy`` of the whole level — 7.6 of raft_large's 16.0 ms tick at 16
+slots, until PR 29. It is kept true by shape, at admission:
+``RAFT.begin_refinement`` holds the fused block's packed pyramid in
+``FusedLookupCorrBlock.resident_pyramid``'s shapes (raw-volume levels
+zero-padded to whole ``(8, 128)`` tiles, for which the TPU's default
+layout is row-major, the operand's), ``state_spec`` / ``zero_state``
+derive the pool's leaves from that row, and ``insert`` writes like into
+like. No layout is named anywhere, so the programs, their specs, the
+compile cache and the warm-up artifact carry nothing new (a pinned
+``jax.experimental.layout.Format`` did the same on the chip until a
+program came back from the persistent cache expecting the default
+layout again: PERF.md, PR 29). ``tests/test_chip_compile.py`` compiles
+the lookup from ``state_spec``'s leaves for a described v5e and fails
+on a ``copy`` of a level.
 """
 
 from __future__ import annotations

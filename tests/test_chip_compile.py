@@ -22,6 +22,8 @@ around the compiles (a TPU entry written without a chip cannot be read
 back); no child process compiles; all such tests live in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -107,6 +109,82 @@ def test_lookup_xtap_forward_compiles(one_chip, h8, w8):
         lambda p, c, k, b: block.index_project(p, c, k, b)
     ).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize(
+    "arch,bucket,held",
+    [
+        # radius 4: levels 0-1 raw volumes (whole tiles), 2-3 flats
+        ("raft_large", (440, 1024), [(56, 128), (32, 128), (13, 32), (6, 16)]),
+        # radius 3: level 0 raw, 1-3 flats
+        ("raft_small", (440, 1024), [(56, 128), (27, 64), (13, 32), (6, 16)]),
+        # KITTI-pad: 47x156 grid, level 0 lane-padded to 256 by the build
+        ("raft_large", (376, 1248), [(48, 256), (24, 128), (11, 39), (5, 19)]),
+    ],
+    ids=["raft_large-sintel", "raft_small-sintel", "raft_large-kitti"],
+)
+def test_lookup_xtap_reads_pool_state_in_place(one_chip, arch, bucket, held):
+    """The lookup + projection lowered from operands as the slot pool
+    holds them (2 slots; leaf shapes from ``state_spec`` of the serving
+    model, layouts the compiler's defaults): the state's buffers go to
+    the Mosaic call as they are — no relayout ``copy`` of a level, no
+    level-sized temporary. (Held in the pooled shapes, ``[.., 55, 128,
+    1]`` and ``[.., 27, 64, 1]``, the default layout tiles over Q, and
+    every pool tick began with a copy of each raw level: half of
+    raft_large's tick, PERF.md, PR 29.)"""
+    from raft_tpu.models import build_raft, zoo
+    from raft_tpu.serve import ServeConfig
+    from raft_tpu.serve.pool import state_spec
+
+    slots = 2
+    h8, w8 = bucket[0] // 8, bucket[1] // 8
+    cfg = zoo.CONFIGS[arch].replace(
+        **ServeConfig.preset("throughput").model_overrides()
+    )
+    block = FusedLookupCorrBlock(
+        cfg.corr_levels, cfg.corr_radius, dtype=jnp.bfloat16, interpret=False
+    )
+    model = build_raft(cfg, corr_block=block)
+    image = jax.ShapeDtypeStruct((1,) + bucket + (3,), jnp.float32)
+    variables = jax.eval_shape(
+        lambda x: model.init(
+            jax.random.PRNGKey(0), x, x, train=False, num_flow_updates=1
+        ),
+        image,
+    )
+    pyramid = state_spec(model, variables, slots, bucket)["pyramid"]
+    q = h8 * w8
+    assert [v.shape for v in pyramid["levels"]] == [
+        (slots, q, hl, wl, 1) for hl, wl in held
+    ]
+
+    def lookup(pyramid, cents, kernel, bias):
+        # iterate_step's view of the state: slots folded into the rows
+        pyramid = jax.tree.map(
+            lambda v: v.reshape((v.shape[0] * v.shape[1],) + v.shape[2:]),
+            pyramid,
+        )
+        return block.index_project(pyramid, cents, kernel, bias)
+
+    taps = cfg.corr_levels * (2 * cfg.corr_radius + 1) ** 2
+    compiled = jax.jit(lookup).lower(*_on(one_chip, (
+        pyramid,
+        jax.ShapeDtypeStruct((slots, h8, w8, 2), jnp.float32),
+        jax.ShapeDtypeStruct((1, 1, taps, PROJ_C), jnp.float32),
+        jax.ShapeDtypeStruct((PROJ_C,), jnp.float32),
+    ))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # a level, whole or with the slots folded in (not the 3-D flats,
+    # which XLA may prefetch as they are)
+    level = re.compile(rf" = bf16\[({slots},{q}|{slots * q}),\d+,\d+[,\]]")
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if " copy(" in line and level.search(line)
+    ]
+    assert not copies, copies
+    one_slot_level0 = q * h8 * w8 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_slot_level0
 
 
 def test_lookup_xtap_partitions_over_four_chips(topo, no_persistent_cache):

@@ -46,7 +46,7 @@ from raft_tpu.utils.faults import FaultInjector
 pytestmark = pytest.mark.chaos
 
 
-def _tiny_model():
+def _tiny_model(corr_block=None):
     from raft_tpu.models import RAFT_SMALL, build_raft, init_variables
     from raft_tpu.models.corr import CorrBlock
 
@@ -60,7 +60,9 @@ def _tiny_model():
         flow_head_hidden=16,
         corr_levels=2,
     )
-    model = build_raft(cfg, corr_block=CorrBlock(num_levels=2, radius=3))
+    model = build_raft(
+        cfg, corr_block=corr_block or CorrBlock(num_levels=2, radius=3)
+    )
     return model, init_variables(model)
 
 
@@ -173,32 +175,38 @@ class TestPoolConfig:
 # ---------------------------------------------------------------------------
 
 
+def _assert_stepwise_matches_scan(model, variables, rng):
+    """N ``iterate_step`` calls on ``begin_pair``'s state reproduce the
+    N-step scan; returns the state (for what it holds)."""
+    im1 = (rng.random((2, 48, 64, 3)).astype(np.float32)) * 2 - 1
+    im2 = (rng.random((2, 48, 64, 3)).astype(np.float32)) * 2 - 1
+    state = model.apply(variables, im1, im2, train=False,
+                        method="begin_pair")
+    for n in (1, 2, 3):
+        state = model.apply(variables, state, train=False,
+                            method="iterate_step")
+        got = np.asarray(
+            model.apply(
+                variables, state["coords1"], state["hidden"],
+                train=False, method="finalize_flow",
+            )
+        )
+        want = np.asarray(
+            model.apply(
+                variables, im1, im2, train=False, num_flow_updates=n,
+                emit_all=False,
+            )
+        )
+        np.testing.assert_allclose(
+            got, want, rtol=1e-2, atol=1e-2,
+            err_msg=f"iterate_step diverged from the scan at N={n}",
+        )
+    return state
+
+
 class TestIterateStepParity:
     def test_stepwise_matches_scanned_iterate(self, tiny_model, rng):
-        model, variables = tiny_model
-        im1 = (rng.random((2, 48, 64, 3)).astype(np.float32)) * 2 - 1
-        im2 = (rng.random((2, 48, 64, 3)).astype(np.float32)) * 2 - 1
-        state = model.apply(variables, im1, im2, train=False,
-                            method="begin_pair")
-        for n in (1, 2, 3):
-            state = model.apply(variables, state, train=False,
-                                method="iterate_step")
-            got = np.asarray(
-                model.apply(
-                    variables, state["coords1"], state["hidden"],
-                    train=False, method="finalize_flow",
-                )
-            )
-            want = np.asarray(
-                model.apply(
-                    variables, im1, im2, train=False, num_flow_updates=n,
-                    emit_all=False,
-                )
-            )
-            np.testing.assert_allclose(
-                got, want, rtol=1e-2, atol=1e-2,
-                err_msg=f"iterate_step diverged from the scan at N={n}",
-            )
+        _assert_stepwise_matches_scan(*tiny_model, rng)
 
     def test_begin_refinement_matches_begin_pair(self, tiny_model, rng):
         """The stream-admission path (cached per-frame features) builds
@@ -230,6 +238,38 @@ class TestIterateStepParity:
 # ---------------------------------------------------------------------------
 
 
+def _assert_mixed_iters_match_oracle(engine, model, variables, rng):
+    """Requests with different iteration targets co-resident in the
+    pool each get flow allclose to the whole-batch ``iterate`` at
+    exactly their own target."""
+    asks = [3, 2, 1, 3, 2, 1]
+    pairs = [(_image(rng), _image(rng)) for _ in asks]
+    with ThreadPoolExecutor(len(asks)) as pool:
+        futs = [
+            pool.submit(engine.submit, a, b, num_flow_updates=n)
+            for (a, b), n in zip(pairs, asks)
+        ]
+        results = [f.result() for f in futs]
+    for (a, b), n, res in zip(pairs, asks, results):
+        assert res.num_flow_updates == n     # honored exactly
+        want = _oracle(model, variables, a, b, n)
+        # The absolute tolerance is what fp32 can promise at this
+        # field's magnitude, not a fixed pixel count: the random-init
+        # net emits flows up to ~200 px, and a ONE-ulp nudge of the
+        # input already moves the 3-iteration oracle by up to
+        # 0.014 px (6.8e-5 of the field's max — the GRU iterations
+        # and the 8x convex upsample amplify rounding noise). The
+        # pool (slot-wise steps, batch 3) and the oracle (one scan,
+        # batch 1) are the same fp32 math reassociated, so they are
+        # held to 3x that floor: 2e-4 of the largest flow value
+        # (0.04 px at 200 px, 0.017 px for a 1-iteration 85 px field).
+        np.testing.assert_allclose(
+            res.flow, want, rtol=1e-2,
+            atol=2e-4 * float(np.abs(want).max()),
+            err_msg=f"pooled request at {n} iters diverged",
+        )
+
+
 class TestPooledServing:
     def test_serves_finite_flow_with_pool_stats(self, engine, rng):
         res = engine.submit(_image(rng), _image(rng))
@@ -255,33 +295,7 @@ class TestPooledServing:
         """The acceptance golden: requests with different iteration
         targets co-resident in the pool each get flow allclose to the
         whole-batch ``iterate`` at exactly their own target."""
-        model, variables = tiny_model
-        asks = [3, 2, 1, 3, 2, 1]
-        pairs = [(_image(rng), _image(rng)) for _ in asks]
-        with ThreadPoolExecutor(len(asks)) as pool:
-            futs = [
-                pool.submit(engine.submit, a, b, num_flow_updates=n)
-                for (a, b), n in zip(pairs, asks)
-            ]
-            results = [f.result() for f in futs]
-        for (a, b), n, res in zip(pairs, asks, results):
-            assert res.num_flow_updates == n     # honored exactly
-            want = _oracle(model, variables, a, b, n)
-            # The absolute tolerance is what fp32 can promise at this
-            # field's magnitude, not a fixed pixel count: the random-init
-            # net emits flows up to ~200 px, and a ONE-ulp nudge of the
-            # input already moves the 3-iteration oracle by up to
-            # 0.014 px (6.8e-5 of the field's max — the GRU iterations
-            # and the 8x convex upsample amplify rounding noise). The
-            # pool (slot-wise steps, batch 3) and the oracle (one scan,
-            # batch 1) are the same fp32 math reassociated, so they are
-            # held to 3x that floor: 2e-4 of the largest flow value
-            # (0.04 px at 200 px, 0.017 px for a 1-iteration 85 px field).
-            np.testing.assert_allclose(
-                res.flow, want, rtol=1e-2,
-                atol=2e-4 * float(np.abs(want).max()),
-                err_msg=f"pooled request at {n} iters diverged",
-            )
+        _assert_mixed_iters_match_oracle(engine, *tiny_model, rng)
 
     def test_stream_session_golden_parity(self, engine, tiny_model, rng):
         """A stream request refining from CACHED frame features through
@@ -579,6 +593,152 @@ class TestPoolWarmup:
             assert eng.program_counts() == warm, (
                 "traffic after warmup compiled a new program"
             )
+
+
+# ---------------------------------------------------------------------------
+# The fused block's packed pyramid: the pool holds its raw-volume levels in
+# whole (8, 128) tiles — the shapes whose default TPU layout the lookup
+# kernel reads in place — whoever made the state, so that no program
+# re-lays a level and none compiles twice
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def fused_model():
+    """The tiny net on the fused lookup block (interpret mode on the
+    CPU): ``begin_pair`` returns the packed ``levels`` / ``flats``
+    pyramid, level 0 a raw volume, level 1 a flat."""
+    from raft_tpu.kernels.lookup_xtap import FusedLookupCorrBlock
+
+    return _tiny_model(FusedLookupCorrBlock(num_levels=2, radius=3))
+
+
+@pytest.fixture(scope="module")
+def fused_engine(fused_model):
+    model, variables = fused_model
+    eng = ServeEngine(model, variables, _config(warmup=True))
+    with eng:
+        yield eng
+
+
+class TestPackedPyramidPool:
+    BUCKET, CAP, R = (48, 64), 3, 3
+    # the 6x8 grid's levels as the pool holds them: level 0 (a raw
+    # volume) in whole tiles, level 1 (it travels as a flat) as built
+    HELD = [(8, 128, 1), (3, 4, 1)]
+
+    def test_spec_zero_state_rows_and_insert_agree(self, fused_model, rng):
+        """``state_spec``, ``zero_state``, ``begin_pair``'s row and what
+        ``insert`` returns agree on every leaf's shape and dtype, the
+        raw-volume level in whole tiles; ``step`` after ``insert`` after
+        ``zero_state`` (twice over) is ONE compiled program each: nobody
+        hands on a differently shaped state."""
+        import jax
+
+        from raft_tpu.serve.pool import PoolPrograms, state_spec, zero_state
+
+        model, variables = fused_model
+        progs = PoolPrograms(model, resid_len=self.R)
+        spec = state_spec(
+            model, variables, self.CAP, self.BUCKET, resid_len=self.R
+        )
+        assert [v.shape for v in spec["pyramid"]["levels"]] == [
+            (self.CAP, 48) + tail for tail in self.HELD
+        ]
+        # 6x8 and 3x4 cells in lane-dense rows, as the kernel's flat
+        # path has always taken them
+        assert [v.shape for v in spec["pyramid"]["flats"]] == [
+            (self.CAP, 48, 128)
+        ]
+
+        def same(tree, lead):
+            got = jax.tree_util.tree_map(lambda v: (v.shape, v.dtype), tree)
+            want = jax.tree_util.tree_map(
+                lambda v: ((lead,) + v.shape[1:], v.dtype), spec
+            )
+            return got == want
+
+        state = zero_state(
+            model, variables, self.CAP, self.BUCKET, resid_len=self.R
+        )
+        im = (rng.random((1, 48, 64, 3)).astype(np.float32)) * 2 - 1
+        th, sk, mi = np.float32(0.0), np.int32(1), np.int32(1)
+        for slot in (0, 2):
+            assert same(state, self.CAP)
+            rows = progs.begin_pair(variables, im, im)
+            assert same(rows, 1)
+            state = progs.insert(
+                state, rows, np.asarray([slot], np.int32),
+                np.asarray([True]),
+            )
+            assert same(state, self.CAP)
+            c1, hid, hist, conv, _ = progs.step(variables, state, th, sk, mi)
+            state = {**state, "coords1": c1, "hidden": hid,
+                     "resid_hist": hist, "converged": conv}
+        counts = progs.counts()
+        assert counts["pool_step"] == 1
+        assert counts["pool_insert"] == 1
+        assert counts["pool_begin_pair"] == 1
+
+    def test_padding_is_zero_and_the_volume_is_unchanged(
+        self, fused_model, rng
+    ):
+        """What the pool holds is the scan's own pyramid with zeros past
+        the grid (an out-of-range tap reads zero either way)."""
+        model, variables = fused_model
+        im1 = (rng.random((1, 48, 64, 3)).astype(np.float32)) * 2 - 1
+        im2 = (rng.random((1, 48, 64, 3)).astype(np.float32)) * 2 - 1
+        f1, ctx = model.apply(variables, im1, train=False,
+                              method="encode_frame")
+        f2, _ = model.apply(variables, im2, train=False,
+                            method="encode_frame")
+        held = model.apply(variables, f1, f2, ctx, train=False,
+                           method="begin_refinement")["pyramid"]
+        built = model.corr_block.build_pyramid(f1, f2)
+        lvl0 = np.asarray(held["levels"][0], np.float32)[0]
+        np.testing.assert_array_equal(
+            lvl0[:, :6, :8], np.asarray(built["levels"][0], np.float32)
+        )
+        assert not lvl0[:, 6:].any() and not lvl0[:, :, 8:].any()
+        np.testing.assert_array_equal(
+            np.asarray(held["levels"][1], np.float32)[0],
+            np.asarray(built["levels"][1], np.float32),
+        )
+
+    def test_dense_pyramid_keeps_its_shapes(self, tiny_model):
+        """The rule reads the pyramid's form: the dense block's tuple of
+        levels is held as built."""
+        from raft_tpu.serve.pool import state_spec
+
+        model, variables = tiny_model
+        spec = state_spec(model, variables, self.CAP, self.BUCKET)
+        assert isinstance(spec["pyramid"], tuple)
+        assert [v.shape for v in spec["pyramid"]] == [
+            (self.CAP, 48, 6, 8, 1), (self.CAP, 48, 3, 4, 1)
+        ]
+
+    def test_stepwise_matches_scanned_iterate(self, fused_model, rng):
+        """``TestIterateStepParity``'s decomposition, on the packed
+        pyramid as the pool holds it (the scan reads it as built)."""
+        state = _assert_stepwise_matches_scan(*fused_model, rng)
+        assert [v.shape for v in state["pyramid"]["levels"]] == [
+            (2, 48) + tail for tail in self.HELD
+        ]
+
+    def test_mixed_iters_golden_parity_closed_program_set(
+        self, fused_engine, fused_model, rng
+    ):
+        """``TestPooledServing``'s golden through the warmed engine on the
+        packed pyramid (AOT programs lowered from ``state_spec``, state
+        from ``zero_state``): every request's flow matches the scanned
+        ``iterate`` (which reads the unpadded pyramid) at its own target,
+        and serving compiles nothing."""
+        warm = fused_engine.program_counts()
+        assert warm["pool_step"] == 1
+        _assert_mixed_iters_match_oracle(fused_engine, *fused_model, rng)
+        assert fused_engine.program_counts() == warm
+        held = fused_engine._pools[self.BUCKET].state["pyramid"]["levels"]
+        assert [v.shape[2:] for v in held] == self.HELD
 
 
 # ---------------------------------------------------------------------------
