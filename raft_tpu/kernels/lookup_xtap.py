@@ -116,9 +116,66 @@ def _pad_width(vol: jax.Array) -> jax.Array:
     pads[2] = (0, wp - wl)
     return jnp.pad(vol, pads)
 
-# queries per kernel grid step; swept on-chip (640 > 880 > 440 by ~1% at
-# Sintel scale; >=1760 fails VMEM) — _pick_tile rounds to a divisor of Q
+# most queries per kernel grid step; swept on-chip (640 > 880 > 440 by ~1%
+# at Sintel scale; >=1760 fails VMEM). _plan_tile lowers it where a tile's
+# level blocks would not fit VMEM; _pick_tile rounds to a divisor of Q
 DEFAULT_QUERY_TILE = 640
+
+# VMEM the kernel body takes beside its double-buffered blocks, per query
+# row and per lane of the widest blocked level: the in-kernel y-dot's
+# result rows (S -> 16 sublanes: fp32, the storage dtype, fp32 again for
+# the gathers), the tap windows, the output block. Read off compiles for
+# a described v5e with the tile forced until VMEM ran out (PR 30): 141 B
+# at Sintel (18.0 KB a row, 128 lanes), 113 B at 1088x1920 (29.0 KB, 256
+# lanes), 145 B at KITTI (37.2 KB, 256 lanes, masked tail).
+_SCRATCH_LANE_BYTES = 160
+
+
+def _vmem_limit(ydot_in_kernel: bool) -> int:
+    """Scoped-VMEM limit of the call: double-buffered row blocks exceed
+    the 16 MB default; the ydot-in-kernel variant additionally stages raw
+    volume blocks + the batched dot's padded operands (measured 65.5 MB
+    at batch 8), so it gets 100 MB of the chip's 128."""
+    return (100 if ydot_in_kernel else 64) << 20
+
+
+def _row_bytes(operands) -> int:
+    """VMEM bytes ONE query row of the blocked operands takes: a
+    ``(q, a, b)`` operand's ``(a, b)`` slab in whole (8, 128) tiles, a
+    ``(q, n)`` flat row in whole lanes."""
+    total = 0
+    for x in operands:
+        lanes = -(-x.shape[-1] // MAX_LANES) * MAX_LANES
+        rows = -(-x.shape[1] // 8) * 8 if len(x.shape) == 3 else 1
+        total += rows * lanes * jnp.dtype(x.dtype).itemsize
+    return total
+
+
+def _plan_tile(q: int, query_tile: int, operands, limit: int):
+    """``(tile, blocked)`` for ``q`` query rows of the blocked
+    ``operands`` (arrays or shape specs), from shapes alone. One rule:
+    what a tile needs — its level blocks twice (double-buffered), the
+    body's scratch (``_SCRATCH_LANE_BYTES``), and the coordinate operand
+    where it lies whole in VMEM (512 B a row, lane-padded) — fits the
+    call's VMEM limit.
+
+    The tile is the largest :func:`_pick_tile` gives under ``query_tile``
+    that fits with the coordinates blocked: 640 at every Sintel, KITTI
+    and training shape (a row of levels 0-1 is 23 KB at 440x1024), 408
+    at 1088x1920, where a row of levels 0-2 is 97 KB (640 of them, twice,
+    are 121 MiB; 480 miss the limit by 4.2 MiB, compiled). ``blocked``
+    says whether the coordinate operand is blocked by tile like the
+    levels: only where whole it would not fit beside them — a 16-slot
+    Sintel pool keeps it whole (55 MiB beside 29 + 12.5), a 32-slot one
+    (110 MiB) or any 1088x1920 pool does not."""
+    lanes = max(
+        [-(-x.shape[-1] // MAX_LANES) * MAX_LANES for x in operands
+         if len(x.shape) == 3] or [MAX_LANES]
+    )
+    per_row = 2 * _row_bytes(operands) + _SCRATCH_LANE_BYTES * lanes
+    tq = _pick_tile(q, max(8, min(query_tile, limit // per_row)))
+    cents_bytes = -(-q // tq) * tq * MAX_LANES * 4
+    return tq, tq * per_row + cents_bytes > limit
 
 
 def _corner_gather(src, idx_a, coef_a, coef_b):
@@ -139,7 +196,7 @@ def _write_taps(
     cents_ref, scales_ref, t_refs, flat_refs, dst_ref, *,
     radius: int, ydot_levels, widths, flat_levels, flat_dims,
     ydot_offsets, flat_offsets, tq: int, ydot_in_kernel: bool = False,
-    heights=(),
+    heights=(), cents_blocked: bool = False,
 ):
     """Write one query tile of taps into ``dst_ref`` (the out ref, or the
     fp32 scratch of the projecting kernel), at the per-level column offsets
@@ -168,9 +225,14 @@ def _write_taps(
     # VMEM->HBM round trip of the coords carry every iteration, ~13 us of
     # pure latency on the critical path); slice this tile's rows here. The
     # tile size is 8-aligned so the dynamic start is provably aligned.
-    row0 = pl.program_id(0) * tq
-    cx = cents_ref[pl.dslice(row0, tq), 0]  # (T,) f32 level-0 x
-    cy = cents_ref[pl.dslice(row0, tq), 1]  # (T,) f32 level-0 y
+    # Where whole they would not fit beside the level blocks (_plan_tile)
+    # the ref IS this tile's rows, blocked like the levels.
+    rows = (
+        slice(None) if cents_blocked
+        else pl.dslice(pl.program_id(0) * tq, tq)
+    )
+    cx = cents_ref[rows, 0]  # (T,) f32 level-0 x
+    cy = cents_ref[rows, 1]  # (T,) f32 level-0 y
 
     for idx_l, (level, t_ref, wl, off) in enumerate(
         zip(ydot_levels, t_refs, widths, ydot_offsets)
@@ -362,7 +424,7 @@ def _write_taps(
 def _xtap_kernel(
     cents_ref, *refs, radius: int, ydot_levels, widths, flat_levels, flat_dims,
     ydot_offsets, flat_offsets, has_scales: bool = False,
-    ydot_in_kernel: bool = False, heights=(),
+    ydot_in_kernel: bool = False, heights=(), cents_blocked: bool = False,
 ):
     """One query tile of taps.
 
@@ -383,6 +445,7 @@ def _xtap_kernel(
         flat_levels=flat_levels, flat_dims=flat_dims,
         ydot_offsets=ydot_offsets, flat_offsets=flat_offsets,
         tq=out_ref.shape[0], ydot_in_kernel=ydot_in_kernel, heights=heights,
+        cents_blocked=cents_blocked,
     )
 
 
@@ -390,7 +453,7 @@ def _xtap_project_kernel(
     cents_ref, w_ref, b_ref, *refs,
     radius: int, ydot_levels, widths, flat_levels, flat_dims,
     ydot_offsets, flat_offsets, mxu_dtype, has_scales: bool = False,
-    ydot_in_kernel: bool = False, heights=(),
+    ydot_in_kernel: bool = False, heights=(), cents_blocked: bool = False,
 ):
     """x-tap + ``convcorr1`` projection in one pass: the j-major taps land
     in an fp32 VMEM scratch, one (T, L*S*S) @ (L*S*S, C_out) MXU matmul +
@@ -411,6 +474,7 @@ def _xtap_project_kernel(
         flat_levels=flat_levels, flat_dims=flat_dims,
         ydot_offsets=ydot_offsets, flat_offsets=flat_offsets,
         tq=out_ref.shape[0], ydot_in_kernel=ydot_in_kernel, heights=heights,
+        cents_blocked=cents_blocked,
     )
     taps = acc_ref[...].astype(mxu_dtype)
     w = w_ref[...].astype(mxu_dtype)
@@ -473,7 +537,8 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
 
     q = cents.shape[0]
     s = 2 * st.radius + 1
-    tq = _pick_tile(q, st.query_tile)
+    limit = _vmem_limit(st.ydot_in_kernel)
+    tq, cents_blocked = _plan_tile(q, st.query_tile, [*ts, *flats], limit)
     grid = -(-q // tq)
     if grid * tq != q:
         # non-divisible q (no 8-aligned divisor <= the tile): the last
@@ -490,7 +555,11 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
         flat_levels=st.flat_levels, flat_dims=st.flat_dims,
         ydot_offsets=st.ydot_offsets, flat_offsets=st.flat_offsets,
         has_scales=st.has_scales, ydot_in_kernel=st.ydot_in_kernel,
-        heights=st.heights,
+        heights=st.heights, cents_blocked=cents_blocked,
+    )
+    cents_spec = (
+        pl.BlockSpec((tq, 2), lambda i: (i, 0)) if cents_blocked
+        else pl.BlockSpec(memory_space=pltpu.VMEM)
     )
     scale_specs = (
         [pl.BlockSpec(memory_space=pltpu.VMEM)] if st.has_scales else []
@@ -502,22 +571,14 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
         for t in ts
     ] + [pl.BlockSpec((tq, f.shape[1]), lambda i: (i, 0)) for f in flats]
     out_dtype = jnp.dtype(st.out_dtype) if st.out_dtype else jnp.float32
-    params = pltpu.CompilerParams(
-        # double-buffered row blocks exceed the 16 MB default; the
-        # ydot-in-kernel variant additionally stages raw volume blocks +
-        # the batched dot's padded operands (measured 65.5 MB at batch 8),
-        # so it gets 100 MB of the chip's 128
-        vmem_limit_bytes=(100 if st.ydot_in_kernel else 64) * 1024 * 1024,
-    )
+    params = pltpu.CompilerParams(vmem_limit_bytes=limit)
     if not st.project:
         kernel = functools.partial(_xtap_kernel, **static)
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((q, st.c_scratch), out_dtype),
             grid=(grid,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
-            + scale_specs
-            + operand_specs,
+            in_specs=[cents_spec] + scale_specs + operand_specs,
             out_specs=pl.BlockSpec((tq, st.c_scratch), lambda i: (i, 0)),
             interpret=st.interpret,
             compiler_params=params,
@@ -533,7 +594,7 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
         out_shape=jax.ShapeDtypeStruct((q, st.c_out), out_dtype),
         grid=(grid,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # cents, unblocked
+            cents_spec,  # whole where it fits, else blocked by tile
             pl.BlockSpec(memory_space=pltpu.VMEM),  # w_mat, unblocked
             pl.BlockSpec(memory_space=pltpu.VMEM),  # bias, unblocked
         ]
@@ -1246,6 +1307,41 @@ class FusedLookupCorrBlock(CorrBlock):
                 pads[1:3] = (0, -hl % 8), (0, -wl % MAX_LANES)
                 levels[l] = jnp.pad(levels[l], pads)
         return dict(pyramid, levels=levels)
+
+    def kernel_rows(self, pyramid):
+        """Shape specs of the kernel's blocked operands for the packed
+        ``pyramid`` (arrays or specs, query rows leading), as
+        :class:`_FusedPrep` hands them over: the y-dot levels' raw
+        ``(q, hl, wl)`` volumes (``(q, S, wl)`` rows when XLA contracts
+        them), lane-padded, then the flats."""
+        levels, flats, _ = self._unwrap(pyramid)
+        s = 2 * self.radius + 1
+        rows = [
+            jax.ShapeDtypeStruct(
+                (
+                    levels[l].shape[0],
+                    levels[l].shape[1] if self.ydot_in_kernel else s,
+                    _pad_width_to_lanes(levels[l].shape[2]),
+                ),
+                self.dtype or levels[l].dtype,
+            )
+            for l in _split_levels(levels, s)[0]
+        ]
+        return [*rows, *flats]
+
+    def lookup_plan(self, pyramid):
+        """``(query tile, coordinates blocked?)`` the kernel picks for one
+        lookup of the packed ``pyramid``: :func:`_plan_tile` on
+        :meth:`kernel_rows`, which :func:`_invoke_xtap` calls on the
+        operands themselves (held equal in ``tests/test_hd_frames.py``).
+        Under a mesh the kernel runs per shard: hand this the rows one
+        device holds (``serve.pool.state_layout`` does, for
+        ``ServeEngine.stats()``)."""
+        rows = self.kernel_rows(pyramid)
+        return _plan_tile(
+            rows[0].shape[0], DEFAULT_QUERY_TILE, rows,
+            _vmem_limit(self.ydot_in_kernel),
+        )
 
     def _lookup_dtype(self, scales):
         # int8 pyramids emit bf16 rows/taps; the block dtype otherwise

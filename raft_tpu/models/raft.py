@@ -327,6 +327,15 @@ class RAFT(nn.Module):
                 "slot pool (per-build dequant scales); serve int8 with "
                 "pool_capacity=0"
             )
+        if packed and "levels" not in pyramid:
+            # the on-the-fly block's 'pyramid' is feature maps, not one
+            # row a query: nothing to hold by slot. Held as built it ran
+            # the 1080p cell once at 46% of fused's rate (PR 30): no cell
+            raise ValueError(
+                "corr_impl='onthefly' cannot live in the resident slot "
+                "pool (its pyramid is feature maps, not per-query rows); "
+                "serve it with pool_capacity=0"
+            )
         if packed:
             # held across every iterate_step: in the block's resident
             # shapes, so that no step re-lays a level before reading it

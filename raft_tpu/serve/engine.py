@@ -135,6 +135,7 @@ from raft_tpu.serve.pool import (
     BucketPool,
     PoolPrograms,
     _SlotMeta,
+    state_layout,
     zero_state,
 )
 from raft_tpu.serve.qos import (
@@ -560,6 +561,10 @@ class ServeEngine:
                 "encode_cache_hits", "encode_cache_misses", "stream_primes",
                 "stream_invalidations", "stream_evictions", "inflight_peak",
                 "pool_ticks", "pool_admitted", "pool_resets",
+                # host bytes of what retirements fetched (flow, residual
+                # history, warm-start coords): beside `completed`, the
+                # size of the blocking fetch a completion pays
+                "fetched_bytes",
                 "idle_slot_iters", "dispatched_slot_iters",
                 "early_exit_iters_saved", "early_exits_deadline",
                 "early_exits_converged", "early_exit_iters_saved_deadline",
@@ -1747,6 +1752,12 @@ class ServeEngine:
                 if self._pools
                 else None
             ),
+            # per live bucket: what its slot state holds on the device
+            # and how the lookup kernel reads it (pool.state_layout)
+            "buckets": {
+                f"{bh}x{bw}": dict(p.layout)
+                for (bh, bw), p in self._pools.items()
+            },
         }
         with self._lock:
             r_sum = self._resid_iter_sum.copy()
@@ -2564,13 +2575,13 @@ class ServeEngine:
     def _pool_for(self, bucket: Tuple[int, int]) -> BucketPool:
         pool = self._pools.get(bucket)
         if pool is None:
+            state = zero_state(
+                self.model, self._dev_vars, self._pool_cap, bucket,
+                sharding=self._row_sharding, resid_len=self._resid_len,
+            )
             pool = BucketPool(
-                bucket,
-                self._pool_cap,
-                zero_state(
-                    self.model, self._dev_vars, self._pool_cap, bucket,
-                    sharding=self._row_sharding, resid_len=self._resid_len,
-                ),
+                bucket, self._pool_cap, state,
+                layout=state_layout(self.model, state),
             )
             self._pools[bucket] = pool
         return pool
@@ -2829,6 +2840,10 @@ class ServeEngine:
         self._trace_span(live, "fetch", t_f)
         with self._lock:
             self._counters["batches"] += 1
+            if not tripped:
+                self._counters["fetched_bytes"] += sum(
+                    a.nbytes for a in out if a is not None
+                )
         if tripped:
             # requests already failed by the watchdog callback; their
             # slots are dead weight now — free them

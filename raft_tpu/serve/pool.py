@@ -92,7 +92,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "PoolPrograms", "BucketPool", "state_spec", "zero_state",
+    "PoolPrograms", "BucketPool", "state_spec", "zero_state", "state_layout",
     "RESID_HISTORY", "RESID_SENTINEL", "unpack_converged",
 ]
 
@@ -270,29 +270,39 @@ class PoolPrograms:
             )
             return rows
 
-        self.begin_pair = jax.jit(
-            lambda variables, image1, image2: _with_hist(
+        # Each program is a function of this instance with a name of its
+        # own: the name is the program's in a device trace (``jit_<name>``;
+        # lambdas all read ``jit__lambda``), and a fresh function object
+        # per instance keeps jax's compiled-program cache — keyed on the
+        # FUNCTION OBJECT — per engine, which ``program_counts()`` counts.
+        # ``_step`` keeps its name: the benchmark reads ``jit__step``.
+        def pool_begin_pair(variables, image1, image2):
+            return _with_hist(
                 apply(
                     variables, image1, image2, train=False,
                     method="begin_pair",
                 )
-            ),
-            **sh(("rep", "row", "row"), "row"),
+            )
+
+        self.begin_pair = jax.jit(
+            pool_begin_pair, **sh(("rep", "row", "row"), "row")
         )
         # Stream admission takes the warm-start initial flow as a TRACED
         # input (ISSUE 12): zeros reproduce the cold start bitwise, a
         # forward-warped previous-pair flow seeds coords1 near the fixed
         # point — one compiled program either way.
-        self.begin_features = jax.jit(
-            lambda variables, fmap1, fmap2, context_out, init_flow: (
-                _with_hist(
-                    apply(
-                        variables, fmap1, fmap2, context_out,
-                        init_flow=init_flow, train=False,
-                        method="begin_refinement",
-                    )
+        def pool_begin_features(variables, fmap1, fmap2, context_out,
+                                init_flow):
+            return _with_hist(
+                apply(
+                    variables, fmap1, fmap2, context_out,
+                    init_flow=init_flow, train=False,
+                    method="begin_refinement",
                 )
-            ),
+            )
+
+        self.begin_features = jax.jit(
+            pool_begin_features,
             **sh(("rep", "row", "row", "row", "row"), "row"),
         )
 
@@ -363,18 +373,14 @@ class PoolPrograms:
                 ("row", "row", "row", "row", "rep"),
             ),
         )
-        self.final = jax.jit(
-            partial(apply, train=False, method="finalize_flow"),
-            **sh(("rep", "row", "row"), "row"),
-        )
-        # The module-level bodies are wrapped in per-instance lambdas
-        # before jitting: jax keys its compiled-program cache on the
-        # FUNCTION OBJECT, so jitting the shared module function would
-        # pool every engine's insert/gather signatures into one global
-        # count and break the per-engine `program_counts()` accounting
-        # (every other pool program already gets a fresh identity from
-        # its `partial(apply, ...)` / closure).
-        #
+        def pool_final(variables, coords1, hidden):
+            return apply(
+                variables, coords1, hidden, train=False,
+                method="finalize_flow",
+            )
+
+        self.final = jax.jit(pool_final, **sh(("rep", "row", "row"), "row"))
+
         # Donation is single-device only: deserializing an SPMD
         # executable that carries input-output aliasing segfaults on
         # this jaxlib (serialize_executable + donate_argnums +
@@ -384,10 +390,11 @@ class PoolPrograms:
         # insert pipeline (jit fallback, AOT warmup, artifact) stays one
         # consistent non-donating program. Revisit on a jaxlib where
         # aliased deserialization holds, and on real-TPU bringup.
+        def pool_insert(state, rows, idx, mask):
+            return _insert_rows(state, rows, idx, mask)
+
         self.insert = jax.jit(
-            lambda state, rows, idx, mask: _insert_rows(
-                state, rows, idx, mask
-            ),
+            pool_insert,
             **({"donate_argnums": (0,)} if mesh is None else {}),
             **sh(("row", "row", "rep", "rep"), "row"),
         )
@@ -395,10 +402,11 @@ class PoolPrograms:
         # must see which (sharded) slots the gather pulls. Since ISSUE 11
         # the gather also pulls the retiring slots' residual histories —
         # the trajectories ride the fetch the finalize already pays.
+        def pool_gather(coords1, hidden, resid_hist, idx):
+            return _gather_carry(coords1, hidden, resid_hist, idx)
+
         self.gather = jax.jit(
-            lambda coords1, hidden, resid_hist, idx: _gather_carry(
-                coords1, hidden, resid_hist, idx
-            ),
+            pool_gather,
             **sh(("row", "row", "row", "rep"), ("row", "row", "row")),
         )
 
@@ -470,13 +478,46 @@ def zero_state(model, variables, capacity: int, bucket: Tuple[int, int],
     )
 
 
+def state_layout(model, state) -> Dict[str, Any]:
+    """What a pool's ``state`` costs and how the lookup kernel reads it,
+    from shapes alone: bytes resident (``state_bytes``; ``slot_bytes`` a
+    slot) and, where the state holds the fused block's packed pyramid,
+    the kernel's query tile and whether its coordinate operand is blocked
+    by tile (``None`` otherwise). The kernel plans from the rows ONE
+    device holds (under a mesh it runs per shard), so the plan is asked
+    for each leaf's shard shape."""
+    leaves = jax.tree_util.tree_leaves(state)
+    capacity = int(leaves[0].shape[0])
+    state_bytes = sum(
+        int(x.size) * jnp.dtype(x.dtype).itemsize for x in leaves
+    )
+    tile = blocked = None
+    plan = getattr(getattr(model, "corr_block", None), "lookup_plan", None)
+    if plan is not None and isinstance(state["pyramid"], dict):
+        def rows(v):
+            shape = v.sharding.shard_shape(v.shape)
+            return jax.ShapeDtypeStruct(
+                (shape[0] * shape[1],) + shape[2:], v.dtype
+            )
+
+        tile, blocked = plan(jax.tree_util.tree_map(rows, state["pyramid"]))
+    return {
+        "state_bytes": state_bytes,
+        "slot_bytes": state_bytes // capacity,
+        "query_tile": tile,
+        "coords_blocked": blocked,
+    }
+
+
 class BucketPool:
     """One bucket's resident slot array + host-side slot table."""
 
-    def __init__(self, bucket: Tuple[int, int], capacity: int, state):
+    def __init__(self, bucket: Tuple[int, int], capacity: int, state,
+                 layout: Optional[Dict[str, Any]] = None):
         self.bucket = bucket
         self.capacity = int(capacity)
         self.state = state                     # device pytree, lead dim = capacity
+        self.layout = layout or {}             # state_layout(), for stats()
         self.slots: List[Optional[_SlotMeta]] = [None] * self.capacity
         self._free: List[int] = list(range(self.capacity - 1, -1, -1))
         # dispatched-but-unfetched tick tokens (the pacing window):

@@ -94,8 +94,10 @@ def _project_args(block, batch, h8, w8):
         (55, 128),  # Sintel 440x1024: pow2 widths, 640-row tiles
         (47, 156),  # KITTI-pad 376x1248: chunked >128-lane gathers and the
                     # masked tail tile (7332 rows have no 8-aligned divisor)
+        (136, 240),  # 1080p 1088x1920: three raw levels, 97 KB of blocks a
+                     # row, so 408-row tiles (640 of them: 170 MiB of VMEM)
     ],
-    ids=["sintel-440x1024", "kitti-376x1248"],
+    ids=["sintel-440x1024", "kitti-376x1248", "hd1080-1088x1920"],
 )
 def test_lookup_xtap_forward_compiles(one_chip, h8, w8):
     """The deployment kernel (fused lookup + convcorr1, bf16 storage,
@@ -120,8 +122,13 @@ def test_lookup_xtap_forward_compiles(one_chip, h8, w8):
         ("raft_small", (440, 1024), [(56, 128), (27, 64), (13, 32), (6, 16)]),
         # KITTI-pad: 47x156 grid, level 0 lane-padded to 256 by the build
         ("raft_large", (376, 1248), [(48, 256), (24, 128), (11, 39), (5, 19)]),
+        # 1080p: levels 0-2 raw (level 2's 34x60 is 16 packed rows, over
+        # the 4 a flat level may have), level 3 flat; 65,280 rows of
+        # coordinates do not fit VMEM beside the blocks: blocked by tile
+        ("raft_large", (1088, 1920), [(136, 256), (72, 128), (40, 128), (17, 30)]),
     ],
-    ids=["raft_large-sintel", "raft_small-sintel", "raft_large-kitti"],
+    ids=["raft_large-sintel", "raft_small-sintel", "raft_large-kitti",
+         "raft_large-hd1080"],
 )
 def test_lookup_xtap_reads_pool_state_in_place(one_chip, arch, bucket, held):
     """The lookup + projection lowered from operands as the slot pool
@@ -185,6 +192,55 @@ def test_lookup_xtap_reads_pool_state_in_place(one_chip, arch, bucket, held):
     assert not copies, copies
     one_slot_level0 = q * h8 * w8 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_slot_level0
+
+
+def test_hd1080_admission_fits_beside_the_pool(one_chip):
+    """The 1080p cell's admission at its capacity (2 slots, one pair at a
+    time): ``pool_begin_pair``'s row and temporaries beside the resident
+    state, and the insert that follows, stay inside one v5e's 16 GB —
+    with the room ``pool_final`` (0.8 GB of temporaries) and the frames
+    need. ``insert`` writes into the donated state: its output is the
+    state's own buffers, and its only temporaries are row prefetches.
+    A third slot would not fit (9.9 + 3.3 + 2.3 GB before anything
+    else): PERF.md, PR 30."""
+    from raft_tpu.models import build_raft, zoo
+    from raft_tpu.serve import ServeConfig
+    from raft_tpu.serve.pool import PoolPrograms, state_spec
+
+    slots, bucket = 2, (1088, 1920)
+    cfg = zoo.CONFIGS["raft_large"].replace(
+        **ServeConfig.preset("throughput").model_overrides()
+    )
+    block = FusedLookupCorrBlock(
+        cfg.corr_levels, cfg.corr_radius, dtype=jnp.bfloat16, interpret=False
+    )
+    model = build_raft(cfg, corr_block=block)
+    image = jax.ShapeDtypeStruct((1,) + bucket + (3,), jnp.float32)
+    variables = jax.eval_shape(
+        lambda x: model.init(
+            jax.random.PRNGKey(0), x, x, train=False, num_flow_updates=1
+        ),
+        image,
+    )
+    progs = PoolPrograms(model, resid_len=20)
+    rows = jax.eval_shape(progs.begin_pair, variables, image, image)
+    state = state_spec(model, variables, slots, bucket, resid_len=20)
+    begin = progs.begin_pair.lower(
+        *_on(one_chip, (variables, image, image))
+    ).compile().memory_analysis()
+    insert = progs.insert.lower(*_on(one_chip, (
+        state, rows,
+        jax.ShapeDtypeStruct((1,), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.bool_),
+    ))).compile().memory_analysis()
+    gb = 1e9
+    state_bytes = insert.output_size_in_bytes
+    assert 6.4 * gb < state_bytes < 6.8 * gb           # 3.3 GB a slot
+    assert insert.alias_size_in_bytes >= state_bytes - 4096  # in place
+    assert insert.temp_size_in_bytes < 0.1 * gb
+    at_begin = (state_bytes + begin.output_size_in_bytes
+                + begin.temp_size_in_bytes)
+    assert at_begin < 13.5 * gb, at_begin  # of 16: 2.5 GB left for the rest
 
 
 def test_lookup_xtap_partitions_over_four_chips(topo, no_persistent_cache):

@@ -122,3 +122,30 @@ def test_window_lookup_matches_gather_oracle(rng, radius):
     got = lookup_pyramid_window(pyr, cent, radius)
     want = lookup_pyramid_gather(pyr, cent, radius)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_onthefly_refuses_the_slot_pool(rng):
+    """The slot pool's entry point on the on-the-fly block: its 'pyramid'
+    is feature maps, not one row a query, so there is nothing to hold by
+    slot. ``begin_pair`` says so with a typed error (as for int8 storage)
+    and not an ``AttributeError`` from inside the fold; the scan serves
+    it (``pool_capacity=0``). Held as built it ran the 1080p cell once at
+    46% of the fused block's rate (PERF.md, PR 30) and got no cell."""
+    cfg = RAFT_SMALL.replace(
+        feature_encoder_widths=(8, 8, 12, 16, 24),
+        context_encoder_widths=(8, 8, 12, 16, 40),
+        motion_corr_widths=(16,),
+        motion_flow_widths=(16, 8),
+        motion_out_channels=20,
+        gru_hidden=24,
+        flow_head_hidden=16,
+        corr_impl="onthefly",
+    )
+    model = build_raft(cfg)
+    variables = init_variables(model)
+    im = jnp.asarray(rng.uniform(-1, 1, (1, 128, 160, 3)).astype(np.float32))
+    flow = model.apply(variables, im, im, train=False, num_flow_updates=1,
+                       emit_all=False)
+    assert flow.shape == (1, 128, 160, 2)
+    with pytest.raises(ValueError, match="onthefly.*pool_capacity=0"):
+        model.apply(variables, im, im, train=False, method="begin_pair")

@@ -491,6 +491,8 @@ ENGINE_STATS_KEYS = frozenset({
     "early_exit_iters_saved_deadline", "early_exits_converged",
     "early_exits_deadline", "encode_cache_hits",
     "encode_cache_misses", "encoder_cache_hit_rate", "expired",
+    # PR 30: host bytes the pool's retirements fetched (beside completed)
+    "fetched_bytes",
     "idle_slot_iters", "inflight_peak", "invalid", "latency", "ledger",
     "mesh_devices", "nonfinite_batches", "obs", "padded_rows",
     "padding_waste", "pool", "pool_admitted", "pool_resets", "pool_ticks",
@@ -536,7 +538,9 @@ ENGINE_BOOT_KEYS = frozenset({
     "source",
 })
 ENGINE_POOL_KEYS = frozenset({
-    "capacity", "mesh_devices", "occupancy", "occupied",
+    # PR 30: "buckets" = per live bucket slot_bytes / state_bytes /
+    # query_tile / coords_blocked (pool.state_layout)
+    "buckets", "capacity", "mesh_devices", "occupancy", "occupied",
     "per_device_occupancy", "tick_ms_ewma", "ticks", "ttfd_p50_ms",
 })
 ENGINE_OBS_KEYS = frozenset({
