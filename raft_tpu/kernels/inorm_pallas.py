@@ -17,6 +17,19 @@ overhead varies enough between processes to fake a 2x gap on a sub-ms op;
 only same-program, same-session comparisons are trustworthy (see
 ``docs/perf_notes.md``).
 
+**That 0.74 ms was the norm alone, a program of its own; inside
+``pool_begin_pair`` the same expression was not the same code.** There
+the TPU compiler computes the two frames' 64-channel convs with the width
+split into the batch, and the norm over ``(B, H, W, C)`` made it leave
+that split and come back: per half-resolution norm four relayout copies
+of the fp32 activation and two materialised broadcasts: 2.37 GB written
+in a program of 17.5 ms a pair at 440x1024, 15.7 GB in one of 132.6 ms
+at 1088x1920 (PERF.md, PR 31). Since PR 31 the feature encoder runs fewer
+than 8 frames a device on a depth axis of batch-1 convs (``layers.frames_conv``)
+and the statistics leave the conv's own fusion as ``(B, C)``: the program
+takes 8.9 and 64.1 ms a pair, no kernel needed. A Pallas pass would read and write the unsplit layout,
+i.e. bring those copies back.
+
 Kept as a tested negative result: the two-phase streaming-stats pattern
 (grid = (B, 2, H-tiles); TPU grids are sequential, so for each image every
 phase-0 accumulate step runs before any phase-1 normalize step, with fp32

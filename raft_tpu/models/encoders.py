@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple, Type
 
 import flax.linen as nn
+import jax
 
 from raft_tpu.models.layers import ConvNormAct, ResidualBlock, conv
 
@@ -41,6 +42,22 @@ class EncoderStage(nn.Module):
         return x
 
 
+def _depth_form(x) -> bool:
+    """Whether ``x``'s frames go on a depth axis of batch-1 convs
+    (``layers.frames_conv``) for an instance-normed encoder: where one
+    device computes fewer than 8 of them. The TPU compiler computes a
+    conv of fewer than 8 frames with its width split into the batch, and
+    a per-frame statistic stays in that split only if no frame axis is
+    left in the batch; from 8 frames on the batch fills the tiles itself,
+    nothing is split, and the batch form is the cheaper one (PERF.md,
+    PR 31: the shapes both sides were read at). Under a mesh
+    (``parallel.mesh.traced_under``: the sharded train step, the serve
+    mesh) the batch is sharded over ``data`` and a device computes its
+    share."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return x.shape[0] // dict(mesh.shape).get("data", 1) < 8
+
+
 class FeatureEncoder(nn.Module):
     """RAFT encoder. ``widths`` = (stem, stage1, stage2, stage3, out)."""
 
@@ -54,6 +71,9 @@ class FeatureEncoder(nn.Module):
     @nn.compact
     def __call__(self, x, *, train: bool = False):
         stem, w1, w2, w3, out = self.widths
+        frames_on_depth = self.norm == "instance" and _depth_form(x)
+        if frames_on_depth:
+            x = x[None]
         x = ConvNormAct(
             stem, 7, 2, self.norm, use_bias=True,
             axis_name=self.axis_name, dtype=self.dtype, s2d=self.s2d_stem,
@@ -63,4 +83,4 @@ class FeatureEncoder(nn.Module):
         x = EncoderStage(self.block, w2, 2, self.norm, self.axis_name, self.dtype, name="layer2")(x, train=train)
         x = EncoderStage(self.block, w3, 2, self.norm, self.axis_name, self.dtype, name="layer3")(x, train=train)
         x = conv(out, 1, dtype=self.dtype, name="conv")(x)
-        return x
+        return x[0] if frames_on_depth else x

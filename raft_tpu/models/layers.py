@@ -26,6 +26,8 @@ __all__ = [
     "conv",
     "make_norm",
     "instance_norm",
+    "frames_conv",
+    "Conv",
     "ConvNormAct",
     "ResidualBlock",
     "BottleneckBlock",
@@ -35,17 +37,26 @@ __all__ = [
 def instance_norm(x, eps: float = 1e-5, relu: bool = False):
     """Parameter-free instance norm (+ optional relu) as one tight chain.
 
-    Exactly ``nn.InstanceNorm(use_bias=False, use_scale=False)`` numerics
+    ``x`` is ``(B, H, W, C)``, or ``(1, B, H, W, C)`` with the frames on
+    a depth axis (:func:`frames_conv`); either way the statistics are
+    per frame and channel, over ``(H, W)``. Exactly
+    ``nn.InstanceNorm(use_bias=False, use_scale=False)`` numerics
     (one-pass stats: ``var = max(0, E[x^2] - E[x]^2)``, fp32), written as a
-    single expression so XLA emits two passes over the activation (one
-    fused dual-reduce for the stats, one fused normalize+relu) instead of
-    the separate square / reduce / sub / mul / relu kernels plus layout
-    copies the module form produced — those measured ~1 ms per full-res
-    norm on the encoder stack (docs/perf_notes.md).
+    single expression so XLA emits one fused dual-reduce for the stats and
+    one fused normalize+relu.
+
+    In the depth form the unit batch axis is reduced with ``(H, W)``: the
+    TPU compiler computes a small-batch conv with the width split into the
+    batch, and keeps a reduction in that split only if it takes the batch
+    and the split axis together (or neither) — then the sums leave the
+    conv's own fusion as ``(B, C)`` and the normalisation joins the next
+    one, with no relayout of the activation (PERF.md, PR 31).
     """
+    frames, chan = x.ndim - 4, x.ndim - 1
+    axes = tuple(a for a in range(x.ndim) if a not in (frames, chan))
     xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=(1, 2), keepdims=True)
-    m2 = jnp.mean(xf * xf, axis=(1, 2), keepdims=True)
+    mu = jnp.mean(xf, axis=axes, keepdims=True)
+    m2 = jnp.mean(xf * xf, axis=axes, keepdims=True)
     var = jnp.maximum(m2 - mu * mu, 0.0)
     y = (xf - mu) * jax.lax.rsqrt(var + eps)
     if relu:
@@ -64,6 +75,51 @@ def _pair(k: KernelT) -> Tuple[int, int]:
     return (k, k) if isinstance(k, int) else tuple(k)
 
 
+def frames_conv(x, kernel, strides, padding):
+    """2-D convolution by an HWIO ``kernel`` of ``(B, H, W, C)``, or of
+    ``(1, B, H, W, C)``: frames on a depth axis that the kernel does not
+    span, batch 1 — the same sums, frame by frame. Which of the two a
+    caller wants is :class:`~raft_tpu.models.encoders.FeatureEncoder`'s
+    to say, and why (PERF.md, PR 31); here the rank of ``x`` decides."""
+    if x.ndim == 5:
+        return jax.lax.conv_general_dilated(
+            x, kernel[None], (1,) + tuple(strides), ((0, 0),) + tuple(padding),
+            dimension_numbers=("NDHWC", "DHWIO", "NDHWC"),
+        )
+    return jax.lax.conv_general_dilated(
+        x, kernel, tuple(strides), tuple(padding),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+
+
+class Conv(nn.Module):
+    """``nn.Conv``'s 2-D convolution — the same ``kernel`` / ``bias``
+    parameters, dtype promotion and sums — for both of
+    :func:`frames_conv`'s forms (``nn.Conv`` itself folds every leading
+    axis back into one batch)."""
+
+    features: int
+    kernel_size: Tuple[int, int]
+    strides: Tuple[int, int]
+    padding: Tuple[int, int]
+    use_bias: bool = True
+    dtype: Optional[Dtype] = None  # computation dtype; params stay fp32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", kaiming_normal_init,
+            tuple(self.kernel_size) + (x.shape[-1], self.features),
+        )
+        bias = (
+            self.param("bias", nn.initializers.zeros, (self.features,))
+            if self.use_bias else None
+        )
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias, dtype=self.dtype)
+        y = frames_conv(x, kernel, self.strides, [(p, p) for p in self.padding])
+        return y if bias is None else y + bias
+
+
 def conv(
     features: int,
     kernel: KernelT = 3,
@@ -72,8 +128,8 @@ def conv(
     use_bias: bool = True,
     dtype: Optional[Dtype] = None,
     name: Optional[str] = None,
-) -> nn.Conv:
-    """``nn.Conv`` with kaiming-normal init and torch-style default padding.
+) -> Conv:
+    """:class:`Conv` with kaiming-normal init and torch-style default padding.
 
     Default padding is ``(k-1)//2`` per spatial dim (symmetric), matching
     ``torch.nn.Conv2d(padding=k//2)`` for the odd kernels RAFT uses.
@@ -81,15 +137,9 @@ def conv(
     kernel = _pair(kernel)
     if padding is None:
         padding = tuple((k - 1) // 2 for k in kernel)
-    return nn.Conv(
-        features,
-        kernel_size=kernel,
-        strides=_pair(stride),
-        padding=padding,
-        use_bias=use_bias,
-        kernel_init=kaiming_normal_init,
-        dtype=dtype,  # computation dtype; params stay fp32 (param_dtype)
-        name=name,
+    return Conv(
+        features, kernel, _pair(stride), _pair(padding),
+        use_bias=use_bias, dtype=dtype, name=name,
     )
 
 
@@ -139,7 +189,7 @@ class _S2DConv7x2(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        b, h, w, c = x.shape
+        *lead, h, w, c = x.shape  # (B,) or (1, B): frames_conv's two forms
         if h % 2 or w % 2:
             raise ValueError("space-to-depth stem needs even H and W")
         kernel = self.param(
@@ -151,8 +201,8 @@ class _S2DConv7x2(nn.Module):
             else None
         )
         # x2[p, q, (du, dv, c)] = x[2p+du, 2q+dv, c]
-        x2 = x.reshape(b, h // 2, 2, w // 2, 2, c)
-        x2 = x2.transpose(0, 1, 3, 2, 4, 5).reshape(b, h // 2, w // 2, 4 * c)
+        x2 = x.reshape(-1, h // 2, 2, w // 2, 2, c)
+        x2 = x2.transpose(0, 1, 3, 2, 4, 5).reshape(*lead, h // 2, w // 2, 4 * c)
         # y[i,j] = sum_k W[k,l] x[2i+k-3, 2j+l-3]; with k = 2t+du-1 the
         # phase decomposition is W2[t, tj, (du, dv, c)] = Wp[2t+du, 2tj+dv]
         # over the zero-padded Wp[1:8] = W
@@ -162,10 +212,7 @@ class _S2DConv7x2(nn.Module):
         if self.dtype is not None:
             x2 = x2.astype(self.dtype)
             k2 = k2.astype(self.dtype)
-        y = jax.lax.conv_general_dilated(
-            x2, k2, (1, 1), ((2, 1), (2, 1)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
+        y = frames_conv(x2, k2, (1, 1), ((2, 1), (2, 1)))
         if bias is not None:
             y = y + (bias.astype(self.dtype) if self.dtype is not None else bias)
         return y

@@ -73,6 +73,29 @@ def _on(sharding, tree):
     )
 
 
+def _serving_model(arch, bucket):
+    """The model ``ServeConfig.preset("throughput")`` serves, on the
+    deployment kernel, with its variables and one frame as shapes."""
+    from raft_tpu.models import build_raft, zoo
+    from raft_tpu.serve import ServeConfig
+
+    cfg = zoo.CONFIGS[arch].replace(
+        **ServeConfig.preset("throughput").model_overrides()
+    )
+    block = FusedLookupCorrBlock(
+        cfg.corr_levels, cfg.corr_radius, dtype=jnp.bfloat16, interpret=False
+    )
+    model = build_raft(cfg, corr_block=block)
+    image = jax.ShapeDtypeStruct((1,) + bucket + (3,), jnp.float32)
+    variables = jax.eval_shape(
+        lambda x: model.init(
+            jax.random.PRNGKey(0), x, x, train=False, num_flow_updates=1
+        ),
+        image,
+    )
+    return cfg, block, model, variables, image
+
+
 def _project_args(block, batch, h8, w8):
     """Abstract operands of one fused lookup+projection call at the
     (h8, w8) feature grid: the packed pyramid ``build_pyramid`` makes
@@ -139,26 +162,11 @@ def test_lookup_xtap_reads_pool_state_in_place(one_chip, arch, bucket, held):
     1]`` and ``[.., 27, 64, 1]``, the default layout tiles over Q, and
     every pool tick began with a copy of each raw level: half of
     raft_large's tick, PERF.md, PR 29.)"""
-    from raft_tpu.models import build_raft, zoo
-    from raft_tpu.serve import ServeConfig
     from raft_tpu.serve.pool import state_spec
 
     slots = 2
     h8, w8 = bucket[0] // 8, bucket[1] // 8
-    cfg = zoo.CONFIGS[arch].replace(
-        **ServeConfig.preset("throughput").model_overrides()
-    )
-    block = FusedLookupCorrBlock(
-        cfg.corr_levels, cfg.corr_radius, dtype=jnp.bfloat16, interpret=False
-    )
-    model = build_raft(cfg, corr_block=block)
-    image = jax.ShapeDtypeStruct((1,) + bucket + (3,), jnp.float32)
-    variables = jax.eval_shape(
-        lambda x: model.init(
-            jax.random.PRNGKey(0), x, x, train=False, num_flow_updates=1
-        ),
-        image,
-    )
+    cfg, block, model, variables, _ = _serving_model(arch, bucket)
     pyramid = state_spec(model, variables, slots, bucket)["pyramid"]
     q = h8 * w8
     assert [v.shape for v in pyramid["levels"]] == [
@@ -203,25 +211,10 @@ def test_hd1080_admission_fits_beside_the_pool(one_chip):
     state's own buffers, and its only temporaries are row prefetches.
     A third slot would not fit (9.9 + 3.3 + 2.3 GB before anything
     else): PERF.md, PR 30."""
-    from raft_tpu.models import build_raft, zoo
-    from raft_tpu.serve import ServeConfig
     from raft_tpu.serve.pool import PoolPrograms, state_spec
 
     slots, bucket = 2, (1088, 1920)
-    cfg = zoo.CONFIGS["raft_large"].replace(
-        **ServeConfig.preset("throughput").model_overrides()
-    )
-    block = FusedLookupCorrBlock(
-        cfg.corr_levels, cfg.corr_radius, dtype=jnp.bfloat16, interpret=False
-    )
-    model = build_raft(cfg, corr_block=block)
-    image = jax.ShapeDtypeStruct((1,) + bucket + (3,), jnp.float32)
-    variables = jax.eval_shape(
-        lambda x: model.init(
-            jax.random.PRNGKey(0), x, x, train=False, num_flow_updates=1
-        ),
-        image,
-    )
+    _, _, model, variables, image = _serving_model("raft_large", bucket)
     progs = PoolPrograms(model, resid_len=20)
     rows = jax.eval_shape(progs.begin_pair, variables, image, image)
     state = state_spec(model, variables, slots, bucket, resid_len=20)
@@ -241,6 +234,100 @@ def test_hd1080_admission_fits_beside_the_pool(one_chip):
     at_begin = (state_bytes + begin.output_size_in_bytes
                 + begin.temp_size_in_bytes)
     assert at_begin < 13.5 * gb, at_begin  # of 16: 2.5 GB left for the rest
+
+
+_MOVES = ("copy", "reshape", "pad", "broadcast")
+
+
+def _itemsize(hlo_dtype):
+    """Bytes an element of an HLO type takes (``bf16``, ``s32``, ``pred``,
+    ``f8e4m3fn``, ...), by way of the numpy dtype of the same meaning."""
+    kind, bits = re.fullmatch(r"([a-z]+?)(\d.*)?", hlo_dtype).groups()
+    name = {"pred": "bool", "bf": "bfloat", "f": "float", "s": "int",
+            "u": "uint", "c": "complex"}.get(kind, kind) + (bits or "")
+    try:
+        return jnp.dtype(name.replace("float8", "float8_")).itemsize
+    except TypeError:
+        raise AssertionError(f"no element size known for HLO type {hlo_dtype!r}")
+
+
+def _entry_bytes_written(hlo_text, scope):
+    """Bytes the entry computation's ``_MOVES`` instructions write, for
+    those whose ``op_name`` lies under ``scope``: what a compiled program
+    spends on moving an activation it already has. Instructions inside a
+    fusion are not counted — they write nothing of their own; each of
+    these operations has one array as its result."""
+    body = hlo_text[hlo_text.index("\nENTRY "):]
+    body = body[:body.index("\n}")]
+    instr = re.compile(r"^\s*(?:ROOT )?\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+    total = 0
+    for line in body.splitlines():
+        m = instr.match(line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not m or m[3] not in _MOVES or not name or scope not in name[1]:
+            continue
+        n = _itemsize(m[1])
+        for d in filter(None, m[2].split(",")):
+            n *= int(d)
+        total += n
+    return total
+
+
+@pytest.mark.parametrize(
+    "arch,bucket,pairs,chips,ceiling,parent,parent_temp",
+    [
+        # reached / the parent's, bytes (PERF.md, PR 31)
+        ("raft_large", (440, 1024), 1, 1, 0.126e9, 2.374e9, 358_275_072),
+        ("raft_small", (440, 1024), 1, 1, 0.072e9, 0.701e9, 154_359_808),
+        ("raft_large", (1088, 1920), 1, 1, 1.023e9, 15.709e9, 2_279_497_728),
+        # the other side of FeatureEncoder's rule: 8 frames, batch form
+        ("raft_large", (440, 1024), 4, 1, 0, 0, 639_968_768),
+        # and the same 8 frames on a serve mesh of two: 4 a device
+        ("raft_large", (440, 1024), 4, 2, 0.272e9, 4.748e9, 944_809_472),
+    ],
+    ids=["raft_large-sintel", "raft_small-sintel", "raft_large-hd1080",
+         "raft_large-sintel-rung4", "raft_large-sintel-rung4-mesh2"],
+)
+def test_admission_moves_no_feature_encoder_activation(
+    topo, no_persistent_cache, arch, bucket, pairs, chips, ceiling, parent,
+    parent_temp,
+):
+    """``pool_begin_pair``: the feature encoder's instance norms run in
+    the split the TPU compiler computes their convs in. Written over
+    ``(B, H, W, C)`` with one pair's two frames as the batch, each
+    half-resolution norm stood between four relayout copies of the whole
+    fp32 activation and two materialised broadcasts of its statistics
+    (at 1080p two reshapes more): half of all the program wrote. With the
+    frames on a depth axis of batch-1 convs the sums leave the conv's own
+    fusion as ``(B, C)`` and the normalisation joins the next one; what
+    is left is the frames' way in and the feature maps' way out. From 8
+    frames on (rung 4) the compiler splits nothing, the batch form moves
+    nothing and is kept (the depth form there writes 0.44 GB of movement
+    and 1.3x the bytes in all) — 8 frames *a device*: on a serve mesh of
+    two, rung 4 is 4 frames each and the depth form again (bytes of one
+    device's program). A ceiling is what PR 31 reached plus 10%,
+    far under half the parent's; the program's temporaries may not grow
+    either. Read on jax 0.9.0 / libtpu 0.0.34: another compiler may split
+    at other sizes, and these numbers are then to be read again, with
+    ``FeatureEncoder``'s rule."""
+    from raft_tpu.parallel.serve_shard import make_serve_mesh
+    from raft_tpu.serve.pool import PoolPrograms
+
+    _, _, model, variables, image = _serving_model(arch, bucket)
+    images = jax.ShapeDtypeStruct((pairs,) + image.shape[1:], image.dtype)
+    if chips == 1:
+        mesh = None
+        rep = rows = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = make_serve_mesh(chips, devices=topo.devices)
+        rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    compiled = PoolPrograms(model, mesh=mesh, resid_len=20).begin_pair.lower(
+        _on(rep, variables), _on(rows, images), _on(rows, images)
+    ).compile()
+    moved = _entry_bytes_written(compiled.as_text(), "feature_encoder")
+    assert moved <= ceiling and (moved > 0) == (parent > 0), moved
+    assert ceiling <= parent / 2
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
 
 
 def test_lookup_xtap_partitions_over_four_chips(topo, no_persistent_cache):
