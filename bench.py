@@ -9,17 +9,15 @@ Benched configuration (per-model TPU deployment tuning, all measured in
 docs/perf_notes.md): ``corr_impl="fused"`` (the Pallas lookup+projection
 kernel with the in-kernel batched-MXU y-dot, output-exact to the dense
 reference semantics — oracle-tested) with ``corr_dtype="bfloat16"``
-(bf16 pyramid storage feeding the in-kernel dot natively; under the
-round-4 kernel bf16 beats int8 at every batch size, so the r1-r3 int8
-deployment config is retired to an alternative). raft_small additionally
+(bf16 pyramid storage feeding the in-kernel dot natively). raft_small additionally
 runs its conv stack in bf16 (``compute_dtype``; its C=32 convs are
 layout-bound) while raft_large keeps fp32 convs (bf16 measured slower
 there). Flow/coordinate arithmetic, norm statistics, and params stay
 fp32 in every config. On trained weights the storage rounding is
 absorbed by the contractive refinement: on a converged toy at full
-acceptance scale, bf16 flows match fp32 to ~5e-3 px max (int8 0.021 px
-mean / 0.16 px max; PARITY.md, reproducible via scripts/parity_report.py
---evidence-only). The library default config stays pure fp32 dense.
+acceptance scale, bf16 flows match fp32 to ~5e-3 px max (PARITY.md,
+reproducible via scripts/parity_report.py --evidence-only). The library
+default config stays pure fp32 dense.
 Override with --corr/--corr-dtype/--dtype to bench other variants.
 
 Measurement: JAX dispatch is asynchronous, so the clock stops only after
@@ -78,11 +76,9 @@ def resolve_bench_config(arch: str, corr=None, corr_dtype=None, dtype=None):
     """Resolve CLI overrides to a concrete (impl, corr_dtype, compute_dtype).
 
     Defaults are each impl's best MEASURED storage dtype (perf_notes.md):
-    fused benches the bf16-corr deployment config (under the round-4
-    ydot-in-kernel kernel, bf16 beats int8 at EVERY batch size — the
-    in-kernel dequant that justified int8 is gone, and bf16 feeds the
-    batched MXU dot natively: b=1 large 28.1 vs 26.9, small 43.0 vs
-    40.6); every other impl benches fp32 storage (dense+bf16 measured
+    fused benches the bf16-corr deployment config (bf16 feeds the
+    kernel's batched MXU dot natively); every other impl benches fp32
+    storage (dense+bf16 measured
     ~2 pairs/s SLOWER than dense+fp32, so defaulting non-fused impls to
     bf16 would inflate A/B gaps). The bf16 conv stack is part of
     raft_small's fused DEPLOYMENT config only — when --corr overrides
@@ -100,7 +96,7 @@ def resolve_bench_config(arch: str, corr=None, corr_dtype=None, dtype=None):
 def describe_config(impl: str, corr_dtype: str, compute_dtype: str, batch: int = 1) -> str:
     """Human/machine-readable config label for metric lines, so a metric
     value is never separated from the precision/impl it was measured at."""
-    short = {"float32": "fp32", "bfloat16": "bf16", "int8": "int8"}
+    short = {"float32": "fp32", "bfloat16": "bf16"}
     s = f"corr={impl}+{short.get(corr_dtype, corr_dtype)}, conv={short.get(compute_dtype, compute_dtype)}"
     if batch != 1:
         s += f", batch={batch}"
@@ -108,8 +104,8 @@ def describe_config(impl: str, corr_dtype: str, compute_dtype: str, batch: int =
 
 
 def bench_model(arch: str, *, n_pairs: int = N_PAIRS, profile_dir=None,
-                dtype=None, corr=None, corr_dtype=None, batch: int = 1,
-                ydot_in_kernel: bool = True) -> float:
+                dtype=None, corr=None, corr_dtype=None,
+                batch: int = 1) -> float:
     """``batch`` > 1 amortizes per-pair overheads across a batched forward
     (measured: raft_large b=8 reaches ~29 pairs/s vs ~22 at b=1 on one
     v5e). The published protocol is batch 1, so the driver's headline
@@ -123,7 +119,6 @@ def bench_model(arch: str, *, n_pairs: int = N_PAIRS, profile_dir=None,
         corr_impl=impl,
         corr_dtype=corr_dtype,
         compute_dtype=dtype,
-        corr_ydot_in_kernel=ydot_in_kernel,
     )
     if batch < 1:
         raise ValueError(f"batch must be >= 1, got {batch}")
@@ -179,7 +174,7 @@ def bench_model(arch: str, *, n_pairs: int = N_PAIRS, profile_dir=None,
 def bench_train(arch: str, *, steps: int = 20, batch: int = 6,
                 crop=(368, 768), iters: int = 12, corr=None,
                 corr_dtype=None, dtype=None, remat_policy=None,
-                profile_dir=None, ydot_in_kernel: bool = True):
+                profile_dir=None):
     """Training throughput (pairs/s) on synthetic batches at the Sintel
     fine-tune stage shape — proves the full jitted train step (forward +
     backward + AdamW update, donated state) on real hardware. Dispatches
@@ -195,15 +190,9 @@ def bench_train(arch: str, *, steps: int = 20, batch: int = 6,
     # Training benches the library-default dense fp32 correlation unless
     # overridden (the fused path trains through its custom_vjp, but its
     # backward IS the XLA path, so dense is the representative default).
-    cfg = CONFIGS[arch].replace(
-        remat=True, remat_policy=remat_policy,
-        corr_ydot_in_kernel=ydot_in_kernel,
-    )
+    cfg = CONFIGS[arch].replace(remat=True, remat_policy=remat_policy)
     if corr is not None:
         cfg = cfg.replace(corr_impl=corr)
-    if corr_dtype == "int8":
-        # the quantized lookup has no autodiff path (lookup_xtap)
-        raise ValueError("corr_dtype='int8' is inference-only; use bfloat16")
     if corr_dtype is not None:
         cfg = cfg.replace(corr_dtype=corr_dtype)
     if dtype is not None:
@@ -246,9 +235,9 @@ def main():
     ap.add_argument("--profile", default=None, metavar="DIR")
     ap.add_argument("--dtype", default=None, choices=["float32", "bfloat16"])
     ap.add_argument("--corr", default=None,
-                    choices=["dense", "onthefly", "pallas", "fused"])
+                    choices=["dense", "onthefly", "fused"])
     ap.add_argument("--corr-dtype", default=None,
-                    choices=["float32", "bfloat16", "int8"])
+                    choices=["float32", "bfloat16"])
     ap.add_argument("--batch", type=int, default=1,
                     help="batched-inference variant (protocol label added; "
                          "the published protocol and driver headline are "
@@ -267,14 +256,6 @@ def main():
                          "reduced-precision deployment headline: _exact "
                          "(fp32 storage and convs) and raft_small's "
                          "_native (only corr at bf16)")
-    ap.add_argument("--ydot-in-kernel", dest="ydot_in_kernel",
-                    action="store_true", default=True,
-                    help="run the y-contraction inside the Pallas kernel "
-                         "(the round-4 deployment kernel; default)")
-    ap.add_argument("--no-ydot-in-kernel", dest="ydot_in_kernel",
-                    action="store_false",
-                    help="reproduce the round-3 kernel (XLA einsum y-dot "
-                         "feeding the kernel) for the documented A/B")
     args = ap.parse_args()
 
     from raft_tpu.utils.runtime import enable_persistent_cache, require_tpu
@@ -293,13 +274,10 @@ def main():
                 arch, corr=args.corr, corr_dtype=args.corr_dtype,
                 dtype=args.dtype, remat_policy=args.remat_policy,
                 profile_dir=args.profile,
-                ydot_in_kernel=args.ydot_in_kernel,
             )
             if args.remat_policy:
                 protocol += f", remat_policy={args.remat_policy}"
             config = describe_config(t_impl, t_cdt, t_dt)
-            if not args.ydot_in_kernel and t_impl == "fused":
-                config += ", ydot=xla (round-3 kernel)"
             print(
                 json.dumps(
                     {
@@ -323,7 +301,7 @@ def main():
             args.corr is None and args.corr_dtype is None and args.dtype is None
         )
         runs = []
-        if (cdt in ("int8", "bfloat16") and args.corr_dtype is None
+        if (cdt == "bfloat16" and args.corr_dtype is None
                 and not args.no_exact):
             # The deployment config approximates the correlation storage;
             # also report the exact-semantics fused number — fp32 storage
@@ -369,7 +347,6 @@ def main():
                 corr=r_impl,
                 corr_dtype=r_cdt,
                 batch=r_batch,
-                ydot_in_kernel=args.ydot_in_kernel,
             )
             line = {
                 "metric": f"{arch}_sintel_fps{suffix}",
@@ -379,8 +356,6 @@ def main():
                 "config": describe_config(r_impl, r_cdt, r_dt, r_batch),
                 "device": device,
             }
-            if not args.ydot_in_kernel and r_impl == "fused":
-                line["config"] += ", ydot=xla (round-3 kernel)"
             if r_batch != 1:
                 line["metric"] += f"_b{r_batch}"
                 line["protocol"] = f"batch {r_batch} (published protocol is b=1)"

@@ -91,7 +91,7 @@ class FlowEstimator:
     ) -> "FlowEstimator":
         """Build an estimator at a named deployment precision preset.
 
-        The presets (``'quality'`` / ``'throughput'`` / ``'edge'``) are
+        The presets (``'quality'`` / ``'throughput'``) are
         the golden-EPE-gated precision configs of
         :meth:`raft_tpu.serve.ServeConfig.preset` — ``'throughput'``
         (bf16 convs + bf16 correlation storage, the fastest validated

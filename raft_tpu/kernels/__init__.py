@@ -1,11 +1,8 @@
 """Pallas TPU kernels for the hot correlation path."""
 
-from raft_tpu.kernels.corr_pallas import PallasCorrBlock, fused_volume_pyramid
 from raft_tpu.kernels.lookup_xtap import FusedLookupCorrBlock, lookup_pyramid_fused
 
 __all__ = [
     "FusedLookupCorrBlock",
-    "PallasCorrBlock",
-    "fused_volume_pyramid",
     "lookup_pyramid_fused",
 ]

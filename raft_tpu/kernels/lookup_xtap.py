@@ -1,15 +1,19 @@
-"""Pallas TPU kernel: gather-based x-tap of the multi-scale correlation lookup.
+"""Pallas TPU kernel: the multi-scale correlation lookup and its projection.
 
-The lookup (reference semantics ``jax_raft/model.py:448-470``) runs 32x per
-pair and was 54% of raft_large inference (r2 on-chip profile): the XLA
-separable form pays a 9x VMEM re-read in its x-contraction plus layout
-copies between the two contractions. This module splits the lookup where
-the hardware wants it split:
+The lookup (reference semantics ``jax_raft/model.py:448-470``) runs once
+per refinement step and pair: (2r+1)^2 bilinear taps around each query's
+current match, on every level of the pooled all-pairs volume, then the
+motion encoder's ``convcorr1`` 1x1 projection of those taps. In the
+serve pool's step program it is one Mosaic call, and the largest part of
+the tick: of 8.25 / 5.39 / 12.36 ms a tick (raft_large and raft_small at
+440x1024 on 16 slots, raft_large at 1088x1920 on 2; ledger, PR 29-31)
+the call takes 4.05 / 3.15 / 8.68 ms (builder's traces, PR 29-30), which
+is 4.46 / 3.07 / 1.20% of the bytes-bound least time for the cells its
+taps touch (``lookup_xtap_roofline.offline``; ledger, PR 31).
 
-  * y-contraction: stays in XLA as the dense bilinear-weight dot
-    (``einsum('qjy,qyx->qjx')``) — profiled AT the HBM roofline (904 GB/s
-    reading the pooled volume), nothing to win there.
-  * x-contraction: the bilinear weight matrix has shift structure
+How the work is split, and why:
+
+  * x-contraction. The bilinear weight matrix has shift structure
     ``wx[q, i, x] = f_q(x - i)`` with ``f_q`` 2-sparse (the two bilinear
     corners), so the whole contraction collapses to
 
@@ -19,7 +23,22 @@ the hardware wants it split:
     Mosaic supports exactly one scattered primitive that vectorizes over
     queries: the lane-dim gather (``take_along_axis`` axis=-1, index shape
     == source shape). Per (level, j) the kernel issues one gather per
-    bilinear corner over the whole query tile — no per-query loop anywhere.
+    query tile; the second corner is a static roll of the first.
+  * y-contraction of the large levels (``ydot_levels``: level 0, and
+    whichever others are too big to pack). The kernel takes the RAW
+    ``(q, hl, wl)`` volume as double-buffered blocks and contracts it
+    against bilinear y-weights built from iotas, as one batched MXU
+    ``dot_general``: the ``(q, S, wl)`` rows never exist in HBM, and the
+    volume is read once a step.
+  * the small pooled levels (``flat_levels``) skip the y-dot entirely:
+    their whole volumes are packed at build time into lane-dense rows
+    and both bilinear axes run as 4-corner lane gathers. A separate
+    contraction of a lane-padded ``(q, hl, wl <= 64)`` level reads mostly
+    padding.
+  * the projection (+bias+relu) runs in the same call
+    (``lookup_project_fused``): the ``(Q, L*S*S)`` tap tensor lives only
+    in a VMEM scratch and one MXU matmul emits the motion features, so
+    the taps are never laid out in reference channel order.
 
 Out-of-range taps: the y side is exact by construction (dense weights
 vanish outside the grid); the x side masks each corner by its in-range
@@ -27,41 +46,11 @@ predicate, folded into the corner coefficients, reproducing torch
 ``padding_mode='zeros'`` (tested against the gather oracle in
 ``tests/test_pallas.py``).
 
-Three rounds of measured evolution on top of that split (full history in
-``docs/perf_notes.md``):
-
-  * the motion encoder's ``convcorr1`` 1x1 projection (+bias+relu) runs
-    inside the kernel (``lookup_project_fused``): the (Q, L*S*S) tap
-    tensor lives only in a VMEM scratch, one MXU matmul emits the
-    motion features directly — the tap relayout at the custom-call
-    boundary was what previously cancelled the kernel's isolated win;
-  * the small pooled levels skip the XLA y-dot entirely: their whole
-    volumes are packed (at build time — XLA's loop-ICM refuses
-    size-increasing pads) into lane-dense rows and both bilinear axes run
-    as 4-corner in-kernel lane gathers. Their separate y-dots were 4-5x
-    over their HBM floor on lane-padded (Q, hl, wl<=64) layouts;
-  * ``ydot_in_kernel`` (round 4): the remaining y-dot levels' contraction
-    moves into the kernel too, as a batched MXU ``dot_general`` over
-    double-buffered raw volume blocks, with the bilinear y-weights built
-    from iotas in-kernel. Bit-exact vs the XLA einsum form for the
-    fp32/bf16 paths (probed on-chip; the int8 branch keeps its dequanted
-    t rows fp32 where the XLA form rounds them to bf16 — strictly MORE
-    precise, differing within quantization noise); kills the
-    per-iteration HBM t rows, their custom-call
-    staging copies, and the int8 path's standalone int32->bf16 dequant
-    convert in one stroke: +14% raft_large int8 headline (23.5 -> 26.9),
-    +15% raft_large exact (20.7 -> 23.9), +9% raft_small exact
-    (29.5 -> 32.4) — the round-3 verdict's "one structural lever not yet
-    attempted", measured. Now the deployment default.
-
-With ``corr_dtype='bfloat16'`` (rounding-only storage, trained-weight
-perturbation ~5e-3 px max — see PARITY.md) this is the benched deployment
-path (``corr_impl='fused'``): ~29.0 pairs/s raft_large (2.46x the
-3090 Ti) at the Sintel b=1 protocol on one v5e chip, ~40 at b=8, vs the
-dense fp32 path's ~15. Under the round-4 kernel bf16 beats the previous
-int8 config at every batch size (the standalone dequant int8 paid for is
-gone); int8 remains available with its own evidence. Full history of
-reworks and sweeps: docs/perf_notes.md.
+The pyramid is stored in the block's dtype (``corr_dtype``: fp32, or
+bf16 rounding-only storage, which every benchmark cell runs; PARITY.md
+bounds what the rounding does to trained weights). The kernel is the
+forward pass only: gradients are those of ``models/corr.py``'s XLA form
+(``lookup_fused_diff`` / ``project_fused_diff``).
 """
 
 from __future__ import annotations
@@ -104,10 +93,10 @@ def _pad_width_to_lanes(wl: int) -> int:
 
 
 def _pad_width(vol: jax.Array) -> jax.Array:
-    """Zero-pad a ``(..., hl, wl[, 1])`` level volume (or ``(q, S, wl)`` t
-    rows) on its width axis 2 to :func:`_pad_width_to_lanes`. No-op at
-    wl <= MAX_LANES. Call once per pyramid build where possible — inside
-    the update scan XLA refuses to hoist size-increasing ops."""
+    """Zero-pad a ``(q, hl, wl[, 1])`` level volume on its width axis 2
+    to :func:`_pad_width_to_lanes`. No-op at wl <= MAX_LANES. Call once
+    per pyramid build where possible — inside the update scan XLA refuses
+    to hoist size-increasing ops."""
     wl = vol.shape[2]
     wp = _pad_width_to_lanes(wl)
     if wp == wl:
@@ -116,9 +105,10 @@ def _pad_width(vol: jax.Array) -> jax.Array:
     pads[2] = (0, wp - wl)
     return jnp.pad(vol, pads)
 
-# most queries per kernel grid step; swept on-chip (640 > 880 > 440 by ~1%
-# at Sintel scale; >=1760 fails VMEM). _plan_tile lowers it where a tile's
-# level blocks would not fit VMEM; _pick_tile rounds to a divisor of Q
+# most queries per kernel grid step. _plan_tile lowers it where a tile's
+# level blocks would not fit VMEM (408 rows at 1088x1920, where tiles
+# 320 / 384 / 408 timed within 0.15% on the chip: builder's, PR 30);
+# _pick_tile rounds to a divisor of Q. 640 itself predates the ledger
 DEFAULT_QUERY_TILE = 640
 
 # VMEM the kernel body takes beside its double-buffered blocks, per query
@@ -131,12 +121,12 @@ DEFAULT_QUERY_TILE = 640
 _SCRATCH_LANE_BYTES = 160
 
 
-def _vmem_limit(ydot_in_kernel: bool) -> int:
-    """Scoped-VMEM limit of the call: double-buffered row blocks exceed
-    the 16 MB default; the ydot-in-kernel variant additionally stages raw
-    volume blocks + the batched dot's padded operands (measured 65.5 MB
-    at batch 8), so it gets 100 MB of the chip's 128."""
-    return (100 if ydot_in_kernel else 64) << 20
+# Scoped-VMEM limit of the call, of the chip's 128 MiB. The default is
+# 16 MiB; a tile's raw level blocks (double-buffered), the batched
+# y-dot's operands and the coordinates take 29 + 12.5 + 55 MiB in the
+# 16-slot Sintel cells (compiled for a described v5e, PR 30). _plan_tile
+# fits the tile to this.
+_VMEM_LIMIT = 100 << 20
 
 
 def _row_bytes(operands) -> int:
@@ -151,13 +141,13 @@ def _row_bytes(operands) -> int:
     return total
 
 
-def _plan_tile(q: int, query_tile: int, operands, limit: int):
+def _plan_tile(q: int, query_tile: int, operands):
     """``(tile, blocked)`` for ``q`` query rows of the blocked
     ``operands`` (arrays or shape specs), from shapes alone. One rule:
     what a tile needs — its level blocks twice (double-buffered), the
     body's scratch (``_SCRATCH_LANE_BYTES``), and the coordinate operand
     where it lies whole in VMEM (512 B a row, lane-padded) — fits the
-    call's VMEM limit.
+    call's VMEM limit (``_VMEM_LIMIT``).
 
     The tile is the largest :func:`_pick_tile` gives under ``query_tile``
     that fits with the coordinates blocked: 640 at every Sintel, KITTI
@@ -173,9 +163,9 @@ def _plan_tile(q: int, query_tile: int, operands, limit: int):
          if len(x.shape) == 3] or [MAX_LANES]
     )
     per_row = 2 * _row_bytes(operands) + _SCRATCH_LANE_BYTES * lanes
-    tq = _pick_tile(q, max(8, min(query_tile, limit // per_row)))
+    tq = _pick_tile(q, max(8, min(query_tile, _VMEM_LIMIT // per_row)))
     cents_bytes = -(-q // tq) * tq * MAX_LANES * 4
-    return tq, tq * per_row + cents_bytes > limit
+    return tq, tq * per_row + cents_bytes > _VMEM_LIMIT
 
 
 def _corner_gather(src, idx_a, coef_a, coef_b):
@@ -193,10 +183,9 @@ def _corner_gather(src, idx_a, coef_a, coef_b):
 
 
 def _write_taps(
-    cents_ref, scales_ref, t_refs, flat_refs, dst_ref, *,
+    cents_ref, vol_refs, flat_refs, dst_ref, *,
     radius: int, ydot_levels, widths, flat_levels, flat_dims,
-    ydot_offsets, flat_offsets, tq: int, ydot_in_kernel: bool = False,
-    heights=(), cents_blocked: bool = False,
+    ydot_offsets, flat_offsets, tq: int, cents_blocked: bool = False,
 ):
     """Write one query tile of taps into ``dst_ref`` (the out ref, or the
     fp32 scratch of the projecting kernel), at the per-level column offsets
@@ -204,14 +193,16 @@ def _write_taps(
 
     Two in-kernel paths, chosen per pyramid level by the wrapper:
 
-      * y-dot levels (``t_refs``, typically level 0): the XLA y-contraction
-        already happened; this does the 2-tap x-combine via lane gathers.
+      * y-dot levels (``vol_refs``, the large levels): the block is the
+        RAW ``(T, hl, wl)`` volume; the y-contraction runs here as one
+        batched MXU dot against bilinear y-weights built from iotas, then
+        the 2-tap x-combine via lane gathers.
         Block layout: j-major, ``off + j*S + i``.
       * flat levels (``flat_refs``, the small pooled levels): the level's
         whole (hl, wl) volume is packed as dense 128-lane rows and BOTH
-        bilinear axes run here as lane gathers — no XLA y-dot at all (the
-        small levels' y-dots were 4-5x over their HBM floor on lane-padded
-        layouts). Taps are laid out in RUNS of ``S+1`` lanes
+        bilinear axes run here as lane gathers — no y-dot at all (a
+        lane-padded ``(hl, wl <= 64)`` level's y-dot reads mostly
+        padding). Taps are laid out in RUNS of ``S+1`` lanes
         (``off + j*(S+1) + i``, lane ``i == S`` dead): within a run the
         flat volume index is affine in the lane, so the x+1 bilinear
         corner is a static left-roll of the x corner's gather instead of a
@@ -221,9 +212,9 @@ def _write_taps(
         version of this kernel issued 4.
     """
     s = 2 * radius + 1
-    # cents stay resident in VMEM unblocked (a blocked operand forced a
-    # VMEM->HBM round trip of the coords carry every iteration, ~13 us of
-    # pure latency on the critical path); slice this tile's rows here. The
+    # cents stay resident in VMEM unblocked where they fit (a blocked
+    # operand is a VMEM->HBM round trip of the coords carry every
+    # iteration, on the critical path); slice this tile's rows here. The
     # tile size is 8-aligned so the dynamic start is provably aligned.
     # Where whole they would not fit beside the level blocks (_plan_tile)
     # the ref IS this tile's rows, blocked like the levels.
@@ -234,8 +225,8 @@ def _write_taps(
     cx = cents_ref[rows, 0]  # (T,) f32 level-0 x
     cy = cents_ref[rows, 1]  # (T,) f32 level-0 y
 
-    for idx_l, (level, t_ref, wl, off) in enumerate(
-        zip(ydot_levels, t_refs, widths, ydot_offsets)
+    for level, vol_ref, wl, off in zip(
+        ydot_levels, vol_refs, widths, ydot_offsets
     ):
         cxl = cx * (1.0 / (2.0**level))
         x0 = jnp.floor(cxl)
@@ -247,11 +238,8 @@ def _write_taps(
         # (corner a) / u0+i+1 (corner b); only lanes < S are consumed.
         # Widths > MAX_LANES run the chunked path: the gather shape is one
         # 128-lane register row and the tap window (S+1 wide) is summed
-        # over per-chunk hit masks, the same scheme as the flat path below.
-        # COVERAGE: this path is verified only under interpret=True on the
-        # CPU-only dev host (tests/test_pallas.py chunked cases); real
-        # Mosaic lowering of the per-chunk dynamic gathers is unproven —
-        # see docs/perf_notes.md "First run on real TPU: checklist".
+        # over per-chunk hit masks, the same scheme as the flat path below
+        # (the 1080p cell's level 0, 240 wide, runs it on the chip).
         chunked = wl > MAX_LANES
         nl = MAX_LANES if chunked else wl
         lane = jax.lax.broadcasted_iota(jnp.int32, (tq, nl), 1)
@@ -279,57 +267,33 @@ def _write_taps(
                 for c in range(wl // MAX_LANES)
             ]
 
-        if ydot_in_kernel:
-            # t_ref is the RAW (T, hl, wl) volume block; run the y-dot
-            # here as one batched MXU contraction (VERDICT r3 #3: the
-            # XLA y-dot's HBM t round-trip, its custom-call staging
-            # copies, and the int8 path's standalone int32->dequant
-            # convert all collapse into this kernel). Bit-exact vs the
-            # XLA einsum form for fp32/bf16 (probed on-chip); the int8
-            # branch keeps its t rows fp32 where the XLA form rounds to
-            # bf16 — more precise, not bitwise-matching that path.
-            hl = heights[idx_l]
-            cyl = (cy * (1.0 / (2.0**level))).astype(jnp.float32)
-            jj = jax.lax.broadcasted_iota(
-                jnp.int32, (tq, s, hl), 1
-            ).astype(jnp.float32)
-            yy = jax.lax.broadcasted_iota(
-                jnp.int32, (tq, s, hl), 2
-            ).astype(jnp.float32)
-            wy = jnp.maximum(
-                1.0 - jnp.abs(cyl[:, None, None] + (jj - radius) - yy), 0.0
-            )
-            vol = t_ref[...]
-            if scales_ref is not None:
-                # int8 path: quantize the bilinear weights at 1/127 (the
-                # same scheme as _ydots) -> int8 x int8 -> int32 dot,
-                # dequantized right here instead of in a separate XLA op
-                wq = jnp.round(wy * 127.0).astype(jnp.int8)
-                t32 = jax.lax.dot_general(
-                    wq, vol,
-                    dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.int32,
-                )
-                t = t32.astype(jnp.float32) * (
-                    scales_ref[0, level] * (1.0 / 127.0)
-                )
-            else:
-                t = jax.lax.dot_general(
-                    wy.astype(vol.dtype), vol,
-                    dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )
-                # match the XLA _ydots rounding exactly: its bf16 einsum
-                # accumulates fp32 on the MXU then emits bf16 rows
-                t = t.astype(vol.dtype)
-            get_row = lambda j, t=t: t[:, j, :].astype(jnp.float32)
-        else:
-            get_row = lambda j: t_ref[:, j, :].astype(jnp.float32)
+        # the y-contraction, as one batched MXU dot over the RAW
+        # (T, hl, wl) block: no (q, S, wl) rows in HBM. The weights are
+        # rounded to the storage dtype and the fp32 accumulation is
+        # rounded back to it, as the XLA form (corr.lookup_pyramid with
+        # this weight_dtype) rounds its rows
+        hl = vol_ref.shape[1]
+        cyl = (cy * (1.0 / (2.0**level))).astype(jnp.float32)
+        jj = jax.lax.broadcasted_iota(
+            jnp.int32, (tq, s, hl), 1
+        ).astype(jnp.float32)
+        yy = jax.lax.broadcasted_iota(
+            jnp.int32, (tq, s, hl), 2
+        ).astype(jnp.float32)
+        wy = jnp.maximum(
+            1.0 - jnp.abs(cyl[:, None, None] + (jj - radius) - yy), 0.0
+        )
+        vol = vol_ref[...]
+        t = jax.lax.dot_general(
+            wy.astype(vol.dtype), vol,
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ).astype(vol.dtype)
 
         for j in range(s):
             # fp32 before the gather (Mosaic's tpu.dynamic_gather has no
             # bf16 lowering here)
-            src = get_row(j)  # (T, wl) fp32
+            src = t[:, j, :].astype(jnp.float32)  # (T, wl)
             if not chunked:
                 taps = _corner_gather(src, idx_a, coef_a, coef_b)
             else:
@@ -415,66 +379,52 @@ def _write_taps(
         if dual:
             # fold the dy=1 half (lanes 64+) onto the dy=0 half
             acc = acc + jnp.roll(acc, -64, axis=1)
-        if scales_ref is not None:
-            # int8 path: one dequantization multiply per level block
-            acc = acc * scales_ref[0, level]
         dst_ref[:, off : off + nlanes] = acc[:, :nlanes].astype(dst_ref.dtype)
 
 
 def _xtap_kernel(
     cents_ref, *refs, radius: int, ydot_levels, widths, flat_levels, flat_dims,
-    ydot_offsets, flat_offsets, has_scales: bool = False,
-    ydot_in_kernel: bool = False, heights=(), cents_blocked: bool = False,
+    ydot_offsets, flat_offsets, cents_blocked: bool = False,
 ):
     """One query tile of taps.
 
-    refs = ([scales,] t_*, flat_*, out): t_l is (T, S, wl) y-contracted
-    rows for the y-dot levels — or the RAW (T, hl, wl) volume block when
-    ``ydot_in_kernel`` (the y-contraction then runs here as a batched MXU
-    dot); flat_l is (T, rows*128) packed volume for the flat levels (int8
-    when ``has_scales``, with per-level dequant factors in ``scales``);
-    out is (T, c_scratch) taps in the :func:`_scratch_layout` column
-    order.
+    refs = (vol_*, flat_*, out): vol_l is the RAW (T, hl, wl) volume block
+    of a y-dot level (the y-contraction runs here as a batched MXU dot);
+    flat_l is (T, rows*128) packed volume for the flat levels; out is
+    (T, c_scratch) taps in the :func:`_scratch_layout` column order.
     """
-    scales_ref, refs = (refs[0], refs[1:]) if has_scales else (None, refs)
     out_ref = refs[-1]
-    nt = len(widths)
+    nv = len(widths)
     _write_taps(
-        cents_ref, scales_ref, refs[:nt], refs[nt:-1], out_ref,
+        cents_ref, refs[:nv], refs[nv:-1], out_ref,
         radius=radius, ydot_levels=ydot_levels, widths=widths,
         flat_levels=flat_levels, flat_dims=flat_dims,
         ydot_offsets=ydot_offsets, flat_offsets=flat_offsets,
-        tq=out_ref.shape[0], ydot_in_kernel=ydot_in_kernel, heights=heights,
-        cents_blocked=cents_blocked,
+        tq=out_ref.shape[0], cents_blocked=cents_blocked,
     )
 
 
 def _xtap_project_kernel(
     cents_ref, w_ref, b_ref, *refs,
     radius: int, ydot_levels, widths, flat_levels, flat_dims,
-    ydot_offsets, flat_offsets, mxu_dtype, has_scales: bool = False,
-    ydot_in_kernel: bool = False, heights=(), cents_blocked: bool = False,
+    ydot_offsets, flat_offsets, mxu_dtype, cents_blocked: bool = False,
 ):
     """x-tap + ``convcorr1`` projection in one pass: the j-major taps land
     in an fp32 VMEM scratch, one (T, L*S*S) @ (L*S*S, C_out) MXU matmul +
     bias + relu emits the motion-encoder input directly — the tap tensor
-    never reaches HBM in reference layout (its relayout cost was what
-    cancelled the bare kernel's win; see module docstring).
+    never reaches HBM in reference layout.
 
-    refs = ([scales,] t_*, flat_*, out, acc): ``w_ref`` is the
-    row-permuted (j-major) projection matrix, ``b_ref`` the (1, C_out)
-    bias; ``scales`` leads when ``has_scales`` (the int8 path).
+    refs = (vol_*, flat_*, out, acc): ``w_ref`` is the row-permuted
+    (j-major) projection matrix, ``b_ref`` the (1, C_out) bias.
     """
-    scales_ref, refs = (refs[0], refs[1:]) if has_scales else (None, refs)
     out_ref, acc_ref = refs[-2], refs[-1]
-    nt = len(widths)
+    nv = len(widths)
     _write_taps(
-        cents_ref, scales_ref, refs[:nt], refs[nt:-2], acc_ref,
+        cents_ref, refs[:nv], refs[nv:-2], acc_ref,
         radius=radius, ydot_levels=ydot_levels, widths=widths,
         flat_levels=flat_levels, flat_dims=flat_dims,
         ydot_offsets=ydot_offsets, flat_offsets=flat_offsets,
-        tq=out_ref.shape[0], ydot_in_kernel=ydot_in_kernel, heights=heights,
-        cents_blocked=cents_blocked,
+        tq=out_ref.shape[0], cents_blocked=cents_blocked,
     )
     taps = acc_ref[...].astype(mxu_dtype)
     w = w_ref[...].astype(mxu_dtype)
@@ -492,8 +442,8 @@ class _XtapStatic(NamedTuple):
     kernel needs besides the operand arrays themselves. One instance keys
     one :func:`_partitioned_xtap` mesh-aware call (lru-cached), and
     :func:`_invoke_xtap` rebuilds the pallas_call from it at ANY query
-    count — the global q in a single-device trace, the per-shard q when
-    GSPMD partitions the op over a mesh."""
+    count — the global q in a single-device trace, the per-shard q under
+    a mesh."""
 
     radius: int
     ydot_levels: tuple
@@ -502,7 +452,6 @@ class _XtapStatic(NamedTuple):
     flat_dims: tuple
     ydot_offsets: tuple
     flat_offsets: tuple
-    has_scales: bool
     c_scratch: int
     out_dtype: Optional[str]  # dtype *name* (dtype objects don't hash stably)
     query_tile: int
@@ -510,79 +459,60 @@ class _XtapStatic(NamedTuple):
     project: bool = False
     c_out: int = 0
     mxu_dtype: Optional[str] = None
-    # y-dot levels' operands are raw (q, hl, wl) volumes and the
-    # y-contraction runs in-kernel (batched MXU dot); `heights` carries
-    # each y-dot level's hl
-    ydot_in_kernel: bool = False
-    heights: tuple = ()
 
 
 def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
     """Build and run the x-tap pallas_call for this operand set's q.
 
-    ``arrays`` order: ``cents, [w_mat, bias (project),] [scales,] *ts,
-    *flats``. Shape-polymorphic in q only: the query tile, grid, and block
-    specs are derived here so the same static config serves both the
-    global trace and GSPMD's per-shard lowering (the partitioner calls
-    this with q/n-row operands)."""
+    ``arrays`` order: ``cents, [w_mat, bias (project),] *vols, *flats``.
+    Shape-polymorphic in q only: the query tile, grid, and block specs
+    are derived here so the same static config serves both the global
+    trace and the per-shard call under a mesh (``_partitioned_xtap``
+    hands this q/n-row operands)."""
     cents = arrays[0]
     i = 1
     if st.project:
         w_mat, bias = arrays[1], arrays[2]
         i = 3
-    scale_args = list(arrays[i : i + 1]) if st.has_scales else []
-    i += int(st.has_scales)
-    nt = len(st.widths)
-    ts, flats = arrays[i : i + nt], arrays[i + nt :]
+    nv = len(st.widths)
+    vols, flats = arrays[i : i + nv], arrays[i + nv :]
 
     q = cents.shape[0]
-    s = 2 * st.radius + 1
-    limit = _vmem_limit(st.ydot_in_kernel)
-    tq, cents_blocked = _plan_tile(q, st.query_tile, [*ts, *flats], limit)
+    tq, cents_blocked = _plan_tile(q, st.query_tile, [*vols, *flats])
     grid = -(-q // tq)
     if grid * tq != q:
         # non-divisible q (no 8-aligned divisor <= the tile): the last
         # block is masked by Pallas (OOB stores dropped, OOB operand rows
-        # padded); only cents needs real rows, its tile is sliced manually.
-        # COVERAGE: the masked-tail cdiv grid is verified only under
-        # interpret=True on the CPU-only dev host (tests/test_pallas.py
-        # nonpow2 cases); Mosaic's handling of the OOB-masked last block
-        # is unproven on hardware — see docs/perf_notes.md "First run on
-        # real TPU: checklist".
+        # padded); only cents needs real rows, its tile is sliced manually
         cents = jnp.pad(cents, ((0, grid * tq - q), (0, 0)))
     static = dict(
         radius=st.radius, ydot_levels=st.ydot_levels, widths=st.widths,
         flat_levels=st.flat_levels, flat_dims=st.flat_dims,
         ydot_offsets=st.ydot_offsets, flat_offsets=st.flat_offsets,
-        has_scales=st.has_scales, ydot_in_kernel=st.ydot_in_kernel,
-        heights=st.heights, cents_blocked=cents_blocked,
+        cents_blocked=cents_blocked,
     )
     cents_spec = (
         pl.BlockSpec((tq, 2), lambda i: (i, 0)) if cents_blocked
         else pl.BlockSpec(memory_space=pltpu.VMEM)
     )
-    scale_specs = (
-        [pl.BlockSpec(memory_space=pltpu.VMEM)] if st.has_scales else []
-    )
-    # t operands are (q, S, wl) y-contracted rows, or (q, hl, wl) raw
-    # volume blocks under ydot_in_kernel — block on dim 0 either way
+    # raw (q, hl, wl) volumes and (q, rows*128) flats: blocked on dim 0
     operand_specs = [
-        pl.BlockSpec((tq, t.shape[1], t.shape[2]), lambda i: (i, 0, 0))
-        for t in ts
+        pl.BlockSpec((tq, v.shape[1], v.shape[2]), lambda i: (i, 0, 0))
+        for v in vols
     ] + [pl.BlockSpec((tq, f.shape[1]), lambda i: (i, 0)) for f in flats]
     out_dtype = jnp.dtype(st.out_dtype) if st.out_dtype else jnp.float32
-    params = pltpu.CompilerParams(vmem_limit_bytes=limit)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
     if not st.project:
         kernel = functools.partial(_xtap_kernel, **static)
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((q, st.c_scratch), out_dtype),
             grid=(grid,),
-            in_specs=[cents_spec] + scale_specs + operand_specs,
+            in_specs=[cents_spec] + operand_specs,
             out_specs=pl.BlockSpec((tq, st.c_scratch), lambda i: (i, 0)),
             interpret=st.interpret,
             compiler_params=params,
-        )(cents, *scale_args, *ts, *flats)
+        )(cents, *vols, *flats)
 
     body = functools.partial(
         _xtap_project_kernel,
@@ -598,13 +528,12 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
             pl.BlockSpec(memory_space=pltpu.VMEM),  # w_mat, unblocked
             pl.BlockSpec(memory_space=pltpu.VMEM),  # bias, unblocked
         ]
-        + scale_specs
         + operand_specs,
         out_specs=pl.BlockSpec((tq, st.c_out), lambda i: (i, 0)),
         scratch_shapes=[pltpu.VMEM((tq, st.c_scratch), jnp.float32)],
         interpret=st.interpret,
         compiler_params=params,
-    )(cents, w_mat, bias, *scale_args, *ts, *flats)
+    )(cents, w_mat, bias, *vols, *flats)
 
 
 def _partition_dim0(mesh, dim0, q: int):
@@ -628,9 +557,9 @@ def _partitioned_xtap(st: _XtapStatic):
     The SPMD partitioner cannot see inside a TPU custom call, so under a
     mesh it would replicate the kernel (all-gathering its operands). What
     is true of the kernel: every query row is independent, all
-    q-carrying operands (cents, ts, flats) shard identically on dim 0,
-    everything else (projection weights, bias, dequant scales, the
-    tap/lane dims) is replicated. So when the program is traced under an
+    q-carrying operands (cents, vols, flats) shard identically on dim 0,
+    everything else (projection weights, bias, the tap/lane dims) is
+    replicated. So when the program is traced under an
     ambient mesh (``parallel.mesh.traced_under`` — the sharded step and
     serve programs enter it), the call is a ``shard_map`` of
     :func:`_invoke_xtap` over ALL mesh axes on the q dim: same kernel,
@@ -643,9 +572,8 @@ def _partitioned_xtap(st: _XtapStatic):
     evenly over the mesh the kernel also runs whole (the partitioner then
     inserts the reshards), so odd shapes stay correct, merely
     unpartitioned."""
-    nt, nf = len(st.widths), len(st.flat_levels)
-    n_pre = 1 + (2 if st.project else 0) + (1 if st.has_scales else 0)
-    n_args = n_pre + nt + nf
+    n_pre = 3 if st.project else 1
+    n_args = n_pre + len(st.widths) + len(st.flat_levels)
     q_positions = (0,) + tuple(range(n_pre, n_args))
 
     def call(*arrays):
@@ -680,19 +608,11 @@ def lookup_pyramid_fused(
     query_tile: int = DEFAULT_QUERY_TILE,
     interpret: bool = False,
     flats=None,
-    scales=None,
-    ydot_in_kernel: bool = True,
 ) -> jax.Array:
-    """Multi-scale (2r+1)^2 bilinear lookup: XLA y-dot + Pallas x-tap
-    (+ in-kernel 4-corner lookup for the small flat-packed levels).
-    With ``ydot_in_kernel`` the y-contraction ALSO moves into the kernel
-    as a batched MXU dot over double-buffered raw volume blocks — no HBM
-    t rows, no separate dequant pass (VERDICT r3 #3).
-
-    ``scales``: ``(1, L)`` fp32 dequantization factors for int8-quantized
-    pyramid levels (real value = stored int8 * scale); the y-dots run
-    int8 x int8 -> int32 and the kernel dequantizes each flat level with
-    one multiply. Pass ``weight_dtype=bfloat16`` alongside.
+    """Multi-scale (2r+1)^2 bilinear lookup in one Pallas call: batched
+    MXU y-dot over double-buffered raw volume blocks + lane-gather x-tap
+    for the large levels, 4-corner lane gathers for the small flat-packed
+    ones.
 
     Semantically equal to ``corr.lookup_pyramid`` (reference channel order,
     zero-padding; oracle-tested). Requires every y-dot-path level width in
@@ -705,10 +625,11 @@ def lookup_pyramid_fused(
     Args:
         pyramid: list of ``(B*Q, hl, wl, 1)`` (or 3D) pooled volume levels.
         centroids: ``(B, h, w, 2)`` level-0 (x, y) tap centers.
-        weight_dtype: dtype for the y-contraction weights/rows and the
-            emitted taps (e.g. ``jnp.bfloat16`` halves the dominant
-            HBM+VMEM traffic; the bf16 compute path converts taps right
-            after anyway). ``None`` keeps fp32 end to end.
+        weight_dtype: dtype the volume blocks are read in, of the
+            y-contraction's weights and rows, and of the emitted taps
+            (``jnp.bfloat16`` halves the HBM+VMEM traffic; the bf16
+            compute path converts taps right after anyway). ``None``
+            keeps fp32 end to end.
     Returns:
         ``(B, h, w, L*(2r+1)^2)`` correlation features.
     """
@@ -718,10 +639,7 @@ def lookup_pyramid_fused(
     rl = s + 1
     num_levels = len(pyramid)
     _check_fusable(pyramid, s, "lookup_pyramid_fused")
-    prep = _prepare_fused(
-        pyramid, centroids, radius, weight_dtype, flats, query_tile, scales,
-        ydot_in_kernel=ydot_in_kernel,
-    )
+    prep = _FusedPrep(pyramid, radius, weight_dtype, flats)
     c_out = num_levels * s * s
 
     st = _XtapStatic(
@@ -731,9 +649,7 @@ def lookup_pyramid_fused(
         interpret=interpret,
         **prep.static,
     )
-    out = _partitioned_xtap(st)(
-        prep.cents, *prep.scale_args, *prep.ts, *prep.flats
-    )
+    out = _partitioned_xtap(st)(_flat_cents(centroids), *prep.operands)
 
     # kernel layouts -> reference i-major channel order per level
     feats = []
@@ -748,15 +664,21 @@ def lookup_pyramid_fused(
     return out.reshape(b, h, w, c_out)
 
 
+def _flat_cents(centroids: jax.Array) -> jax.Array:
+    """``(B, h, w, 2)`` tap centers as the kernel's ``(q, 2)`` fp32 rows."""
+    return centroids.reshape(-1, 2).astype(jnp.float32)
+
+
 def _flat_max_rows(s: int) -> int:
-    """Largest packed-row count a level may have and still skip its XLA
-    y-dot for the in-kernel 4-corner flat-gather path. Swept on-chip at
-    Sintel scale per tap width (docs/perf_notes.md): raft_large (S=9)
-    wants only levels 2-3 flat (rows<=4; pulling level 1's 14-row masked
-    gather loop in loses ~1.1 pairs/s, pushing level 2 out loses ~2.0);
-    raft_small (S=7, cheaper gathers per level) wants level 1 flat too
-    (24.3 vs 23.1 pairs/s). Level 0 always stays on the HBM-roofline
-    y-dot."""
+    """Largest packed-row count a level may have and still take the
+    in-kernel 4-corner flat-gather path instead of the y-dot. A flat
+    level costs one masked gather per packed 128-lane row, a y-dot level
+    one batched dot and S gathers whatever its size, so the cut follows
+    the tap width: raft_large (S=9) packs levels of at most 4 rows
+    (levels 2-3 at 440x1024, level 3 alone at 1088x1920, where level 2
+    has 16), raft_small (S=7, fewer gathers a row) of at most 16 (levels
+    1-3 at 440x1024). Level 0 always takes the y-dot. The two thresholds
+    predate the ledger; no cell has timed their neighbours."""
     return 4 if s >= 9 else 16
 
 
@@ -798,14 +720,13 @@ def _flat_pack(vol, q):
     """(q, hl, wl[, 1]) volume -> (q, rows*128) lane-dense packing.
 
     Kept 2D: the last two dims of a 3D (q, rows, 128) array get sublane
-    tiling, which pads small row counts (catastrophically for int8's
-    (32, 128) native tile); a (q, rows*128) layout is dense for every
-    dtype and the kernel addresses row r as the static lane slice
-    [r*128, (r+1)*128).
+    tiling, which pads small row counts (to 16 rows for bf16); a
+    (q, rows*128) layout is dense for every dtype and the kernel
+    addresses row r as the static lane slice [r*128, (r+1)*128).
 
     Call at build_pyramid time, not per lookup: XLA's while-loop invariant
     code motion refuses to hoist size-increasing ops, so packing inside
-    the 32-iteration scan costs ~4 ms/pair (measured, docs/perf_notes.md).
+    the refinement scan would run every iteration.
     """
     hl, wl = vol.shape[1], vol.shape[2]
     flat = vol.reshape(q, hl * wl)
@@ -816,55 +737,11 @@ def _flat_pack(vol, q):
     return flat
 
 
-def _ydots(pyramid, centroids, radius, weight_dtype, levels=None, scales=None):
-    """Flattened centroids + y-contracted rows (XLA dots) for ``levels``
-    (all levels when None).
-
-    ``scales`` (the int8 path): pyramid levels are symmetric-quantized
-    int8 with real value ``q * scales[0, level]``. The bilinear y-weights
-    are quantized at 1/127 and the contraction runs int8 x int8 -> int32
-    on the MXU — half the HBM read of the bf16 dot — then one elementwise
-    rescale emits the bf16 rows the kernel consumes.
-    """
-    b, h, w, _ = centroids.shape
-    q = b * h * w
-    cents = centroids.reshape(q, 2).astype(jnp.float32)
-    r = jnp.arange(-radius, radius + 1, dtype=jnp.float32)
-    ts = []
-    for level, vol in enumerate(pyramid):
-        if levels is not None and level not in levels:
-            continue
-        hl = vol.shape[1]
-        v = vol.reshape(q, hl, vol.shape[2])
-        cy = cents[:, 1] * (1.0 / (2.0**level))
-        grid = jnp.arange(hl, dtype=jnp.float32)
-        wy = jax.nn.relu(1.0 - jnp.abs(cy[:, None, None] + r[None, :, None] - grid))
-        if scales is not None:
-            qw = jnp.round(wy * 127.0).astype(jnp.int8)
-            t32 = jnp.einsum(
-                "qjy,qyx->qjx", qw, v, preferred_element_type=jnp.int32
-            )
-            sc = scales[0, level] * (1.0 / 127.0)
-            t = (t32.astype(jnp.float32) * sc).astype(weight_dtype or jnp.float32)
-        else:
-            if weight_dtype is not None:
-                wy = wy.astype(weight_dtype)
-                v = v.astype(weight_dtype)
-            t = jnp.einsum(
-                "qjy,qyx->qjx",
-                wy,
-                v,
-                preferred_element_type=weight_dtype or jnp.float32,
-            )
-        ts.append(t)
-    return cents, ts
-
-
 def _pick_tile(q: int, query_tile: int) -> int:
     """Largest 8-aligned divisor of q <= query_tile when one exists (no
-    padding copies — a jnp.pad of the t operands measured 0.21 ms/lookup,
-    and every-divisor geometries like Sintel's q=7040 keep that fast
-    path); q itself is the degenerate single-tile fallback. Otherwise
+    masked tail, no padding copy of any operand: Sintel's q=7040 and every
+    other geometry with such a divisor); q itself is the degenerate
+    single-tile fallback. Otherwise
     (e.g. KITTI's q=47*156=7332, which has no 8-aligned divisor) return
     an 8-aligned tile and let :func:`_invoke_xtap` run a cdiv grid whose
     masked last block covers the tail — only the small cents operand is
@@ -884,77 +761,46 @@ def _pick_tile(q: int, query_tile: int) -> int:
 
 
 class _FusedPrep:
-    """Shared preamble of the two fused wrappers: level split, y-dots,
-    flat packing (when not prepacked), and the kernels' static
-    level-layout kwargs. One place, so the lookup and lookup+project
-    variants can never disagree on which levels take the flat path.
-    (Tile choice and block specs live in :func:`_invoke_xtap`, which must
-    rebuild them per shard under GSPMD partitioning.)"""
+    """The kernel's blocked operands for a pyramid, and the static
+    level-layout kwargs that go with them: level split, the y-dot levels'
+    raw ``(q, hl, wl)`` volumes (lane-padded, in ``weight_dtype``), the
+    flat levels' packed rows (packed here when not prepacked). The one
+    place either is decided, so the lookup and lookup+project variants
+    and the plan ``stats()`` reports (:meth:`FusedLookupCorrBlock.
+    kernel_rows`) can never disagree on what the kernel is handed.
+    (Tile choice and block specs live in :func:`_invoke_xtap`, which
+    rebuilds them per shard under a mesh.)"""
 
-    def __init__(self, pyramid, centroids, radius, weight_dtype, flats,
-                 query_tile, scales=None, ydot_in_kernel=False):
-        b, h, w, _ = centroids.shape
-        q = b * h * w
+    def __init__(self, pyramid, radius, weight_dtype, flats):
+        q = pyramid[0].shape[0]
         s = 2 * radius + 1
         ydot_levels, flat_levels = _split_levels(pyramid, s)
-        # the kernel sees lane-padded widths for >128-wide levels (zero
-        # data in the pad == out-of-range taps); FusedLookupCorrBlock
-        # prepads at build_pyramid time so _pad_width below is a no-op on
-        # that path — direct callers pay the pad per call
-        widths = tuple(
-            _pad_width_to_lanes(pyramid[l].shape[2]) for l in ydot_levels
-        )
-        flat_dims = tuple(
-            (pyramid[l].shape[1], pyramid[l].shape[2]) for l in flat_levels
-        )
         offsets, _, self.c_scratch = _scratch_layout(len(pyramid), ydot_levels, s)
         self.offsets = offsets
         self.ydot_levels, self.flat_levels = ydot_levels, flat_levels
-        heights = ()
-        if ydot_in_kernel:
-            # y-dot runs inside the kernel: hand it the RAW volume blocks
-            # (already int8/bf16/fp32-typed by build_pyramid)
-            self.cents = centroids.reshape(q, 2).astype(jnp.float32)
-            self.ts = [
-                _pad_width(
-                    pyramid[l].reshape(
-                        q, pyramid[l].shape[1], pyramid[l].shape[2]
-                    )
-                )
-                for l in ydot_levels
-            ]
-            if weight_dtype is not None and scales is None:
-                self.ts = [t.astype(weight_dtype) for t in self.ts]
-            heights = tuple(pyramid[l].shape[1] for l in ydot_levels)
-        else:
-            self.cents, self.ts = _ydots(
-                pyramid, centroids, radius, weight_dtype,
-                levels=ydot_levels, scales=scales,
-            )
-            self.ts = [_pad_width(t) for t in self.ts]
-        if flats is None:
+        # the kernel sees lane-padded widths for >128-wide levels (zero
+        # data in the pad == out-of-range taps); FusedLookupCorrBlock
+        # prepads at build_pyramid time so _pad_width is a no-op on that
+        # path — direct callers pay the pad per call
+        vols = [
+            _pad_width(pyramid[l].reshape((q,) + pyramid[l].shape[1:3]))
+            for l in ydot_levels
+        ]
+        if weight_dtype is not None:
+            vols = [v.astype(weight_dtype) for v in vols]
+        if not flats:
             # direct-call convenience; FusedLookupCorrBlock prepacks at
             # build_pyramid time (see _flat_pack)
             flats = [_flat_pack(pyramid[l], q) for l in flat_levels]
-        self.flats = list(flats)
-        self.scales = scales
+        self.operands = [*vols, *flats]
         self.static = dict(
-            radius=radius, ydot_levels=tuple(ydot_levels), widths=widths,
-            flat_levels=tuple(flat_levels), flat_dims=flat_dims,
+            radius=radius, ydot_levels=tuple(ydot_levels),
+            widths=tuple(v.shape[2] for v in vols),
+            flat_levels=tuple(flat_levels),
+            flat_dims=tuple(pyramid[l].shape[1:3] for l in flat_levels),
             ydot_offsets=tuple(offsets[l] for l in ydot_levels),
             flat_offsets=tuple(offsets[l] for l in flat_levels),
-            has_scales=scales is not None,
-            ydot_in_kernel=ydot_in_kernel, heights=heights,
         )
-        self.scale_args = [scales] if scales is not None else []
-
-
-def _prepare_fused(pyramid, centroids, radius, weight_dtype, flats, query_tile,
-                   scales=None, ydot_in_kernel=False):
-    return _FusedPrep(
-        pyramid, centroids, radius, weight_dtype, flats, query_tile, scales,
-        ydot_in_kernel=ydot_in_kernel,
-    )
 
 
 def _check_fusable(pyramid, s, who):
@@ -978,8 +824,6 @@ def lookup_project_fused(
     query_tile: int = DEFAULT_QUERY_TILE,
     interpret: bool = False,
     flats=None,
-    scales=None,
-    ydot_in_kernel: bool = True,
 ) -> jax.Array:
     """Multi-scale lookup + ``convcorr1`` 1x1 projection in one kernel.
 
@@ -998,7 +842,6 @@ def lookup_project_fused(
         ``(B, h, w, C_out)`` projected (relu'd) motion features.
     """
     b, h, w, _ = centroids.shape
-    q = b * h * w
     s = 2 * radius + 1
     rl = s + 1
     num_levels = len(pyramid)
@@ -1008,10 +851,7 @@ def lookup_project_fused(
     if kernel.shape[-2] != c_in:
         raise ValueError(f"kernel expects {kernel.shape[-2]} taps, lookup makes {c_in}")
 
-    prep = _prepare_fused(
-        pyramid, centroids, radius, weight_dtype, flats, query_tile, scales,
-        ydot_in_kernel=ydot_in_kernel,
-    )
+    prep = _FusedPrep(pyramid, radius, weight_dtype, flats)
 
     # Permute the projection rows from the reference tap channel order
     # (row l*S*S + i*S + j) into the kernel's scratch layout: j-major
@@ -1040,8 +880,7 @@ def lookup_project_fused(
         **prep.static,
     )
     out = _partitioned_xtap(st)(
-        prep.cents, w_mat, bias.reshape(1, c_out),
-        *prep.scale_args, *prep.ts, *prep.flats,
+        _flat_cents(centroids), w_mat, bias.reshape(1, c_out), *prep.operands
     )
 
     return out.reshape(b, h, w, c_out)
@@ -1071,9 +910,9 @@ def _fusable(pyramid: Sequence[jax.Array], s: int) -> bool:
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def lookup_fused_diff(pyramid, flats, centroids, radius, weight_dtype,
-                      query_tile, interpret, ydot_in_kernel=False):
+                      query_tile, interpret):
     """``flats`` are the prepacked small levels (derived from ``pyramid``
     at build time; empty tuple = pack inside). Their cotangent is zero by
     construction: the forward's value equals the XLA path applied to
@@ -1082,21 +921,19 @@ def lookup_fused_diff(pyramid, flats, centroids, radius, weight_dtype,
     return lookup_pyramid_fused(
         list(pyramid), centroids, radius,
         weight_dtype=weight_dtype, query_tile=query_tile, interpret=interpret,
-        flats=list(flats) if flats else None, ydot_in_kernel=ydot_in_kernel,
+        flats=flats,
     )
 
 
 def _lookup_fwd(pyramid, flats, centroids, radius, weight_dtype, query_tile,
-                interpret, ydot_in_kernel=False):
+                interpret):
     out = lookup_fused_diff(
-        pyramid, flats, centroids, radius, weight_dtype, query_tile, interpret,
-        ydot_in_kernel,
+        pyramid, flats, centroids, radius, weight_dtype, query_tile, interpret
     )
     return out, (pyramid, flats, centroids)
 
 
-def _lookup_bwd(radius, weight_dtype, query_tile, interpret, ydot_in_kernel,
-                res, g):
+def _lookup_bwd(radius, weight_dtype, query_tile, interpret, res, g):
     pyramid, flats, centroids = res
     _, vjp = jax.vjp(
         lambda p, c: lookup_pyramid(p, c, radius, weight_dtype=weight_dtype),
@@ -1110,34 +947,31 @@ def _lookup_bwd(radius, weight_dtype, query_tile, interpret, ydot_in_kernel,
 lookup_fused_diff.defvjp(_lookup_fwd, _lookup_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def project_fused_diff(
     pyramid, flats, centroids, kernel, bias, radius, weight_dtype, query_tile,
-    interpret, proj_dtype, ydot_in_kernel=False,
+    interpret, proj_dtype,
 ):
     return lookup_project_fused(
         list(pyramid), centroids, kernel, bias, radius,
         weight_dtype=weight_dtype, proj_dtype=proj_dtype,
-        query_tile=query_tile, interpret=interpret,
-        flats=list(flats) if flats else None, ydot_in_kernel=ydot_in_kernel,
+        query_tile=query_tile, interpret=interpret, flats=flats,
     )
 
 
 def _project_fwd(
     pyramid, flats, centroids, kernel, bias, radius, weight_dtype, query_tile,
-    interpret, proj_dtype, ydot_in_kernel=False,
+    interpret, proj_dtype,
 ):
     out = project_fused_diff(
         pyramid, flats, centroids, kernel, bias, radius, weight_dtype,
-        query_tile, interpret, proj_dtype, ydot_in_kernel,
+        query_tile, interpret, proj_dtype,
     )
     return out, (pyramid, flats, centroids, kernel, bias)
 
 
-def _project_bwd(
-    radius, weight_dtype, query_tile, interpret, proj_dtype, ydot_in_kernel,
-    res, g,
-):
+def _project_bwd(radius, weight_dtype, query_tile, interpret, proj_dtype,
+                 res, g):
     pyramid, flats, centroids, kernel, bias = res
 
     def xla_path(p, c, k, b):
@@ -1158,36 +992,6 @@ def _project_bwd(
 project_fused_diff.defvjp(_project_fwd, _project_bwd)
 
 
-def _inference_only(fn, *args):
-    """Run ``fn(*args)`` behind a custom_vjp whose backward raises a CLEAR
-    error. ``pallas_call`` has no autodiff rule, so without this a gradient
-    taken through the int8 lookup dies with an opaque missing-JVP error deep
-    inside pallas; the fp32/bf16 fused paths differentiate fine via
-    ``lookup_fused_diff``/``project_fused_diff`` above — int8 is the one
-    inference-only corner, and it should say so when touched by autodiff.
-
-    ``args`` must be a pytree of arrays (close over static config in
-    ``fn``)."""
-
-    @jax.custom_vjp
-    def run(args):
-        return fn(*args)
-
-    def fwd(args):
-        return fn(*args), None
-
-    def bwd(_, g):
-        raise NotImplementedError(
-            "corr_dtype='int8' is inference-only — the quantized fused "
-            "lookup defines no gradient. Train with corr_dtype='float32' "
-            "or 'bfloat16' (both differentiate through the fused path's "
-            "XLA-equivalent custom_vjp)."
-        )
-
-    run.defvjp(fwd, bwd)
-    return run(args)
-
-
 class FusedLookupCorrBlock(CorrBlock):
     """Dense correlation block whose per-iteration lookup (and optionally
     the motion encoder's ``convcorr1`` projection, via ``index_project``)
@@ -1203,7 +1007,7 @@ class FusedLookupCorrBlock(CorrBlock):
     fusable (see :func:`_fusable`); the rare shape the kernel cannot
     handle (a y-dot level narrower than S+1 or wider than MAX_WIDTH)
     silently falls back to the XLA separable path, which is semantically
-    identical.
+    identical, on the plain list of levels :class:`CorrBlock` builds.
     """
 
     def __init__(
@@ -1213,11 +1017,9 @@ class FusedLookupCorrBlock(CorrBlock):
         dtype=None,
         *,
         interpret: bool | None = None,
-        ydot_in_kernel: bool = True,
     ):
         super().__init__(num_levels=num_levels, radius=radius, dtype=dtype)
         self.interpret = interpret
-        self.ydot_in_kernel = ydot_in_kernel
 
     def _interpret(self) -> bool:
         if self.interpret is None:
@@ -1229,22 +1031,9 @@ class FusedLookupCorrBlock(CorrBlock):
         the small levels prepacked into lane-dense rows for the kernel's
         flat path. Packing here (once per pair) instead of in the lookup
         matters: XLA's while-loop invariant code motion refuses to hoist
-        the size-increasing pad out of the 32-iteration scan, which
-        measured ~4 ms/pair (docs/perf_notes.md).
-
-        With ``dtype=int8`` (inference-only) each pooled level is
-        symmetric-quantized at its own amax/127 and the per-level dequant
-        factors travel with the pyramid; non-fusable shapes skip
-        quantization entirely and fall back to the fp32 XLA path."""
+        the size-increasing pad out of the refinement scan."""
         s = 2 * self.radius + 1
-        int8 = self.dtype == jnp.int8
-        if int8:
-            # quantize AFTER pooling: pool fp32 levels via a dtype-None block
-            levels = CorrBlock(self.num_levels, self.radius).build_pyramid(
-                fmap1, fmap2
-            )
-        else:
-            levels = super().build_pyramid(fmap1, fmap2)
+        levels = super().build_pyramid(fmap1, fmap2)
         if not _fusable(levels, s):
             return levels
         # lane-pad >128-wide levels ONCE here (outside the update scan —
@@ -1252,34 +1041,20 @@ class FusedLookupCorrBlock(CorrBlock):
         # exactly out-of-range-tap semantics, so the XLA oracle/VJP paths
         # see an equivalent pyramid and every consumer splits identically
         levels = [_pad_width(v) for v in levels]
-        scales = None
-        if int8:
-            qlevels, scale_list = [], []
-            for v in levels:
-                amax = jnp.max(jnp.abs(v))
-                sc = jnp.maximum(amax, 1e-12) * (1.0 / 127.0)
-                q = jnp.clip(jnp.round(v * (1.0 / sc)), -127, 127)
-                qlevels.append(q.astype(jnp.int8))
-                scale_list.append(sc)
-            levels = qlevels
-            scales = jnp.stack(scale_list).reshape(1, -1).astype(jnp.float32)
         _, flat_levels = _split_levels(levels, s)
         flats = tuple(
             _flat_pack(levels[l], levels[l].shape[0]) for l in flat_levels
         )
-        out = {"levels": levels, "flats": flats}
-        if scales is not None:
-            out["scales"] = scales
-        return out
+        return {"levels": levels, "flats": flats}
 
     @staticmethod
     def _unwrap(pyramid):
         if isinstance(pyramid, dict):
-            return pyramid["levels"], pyramid["flats"], pyramid.get("scales")
-        return pyramid, (), None
+            return pyramid["levels"], pyramid["flats"]
+        return pyramid, ()
 
     def resident_pyramid(self, pyramid):
-        """The packed ``pyramid`` in the shapes to HOLD it in across many
+        """The built ``pyramid`` in the shapes to HOLD it in across many
         lookups (the serve pool's slot state): each level the kernel
         takes as a raw ``(q, hl, wl)`` volume block — the y-dot levels,
         contracted in the kernel — zero-padded to whole ``(8, 128)``
@@ -1297,90 +1072,56 @@ class FusedLookupCorrBlock(CorrBlock):
         It costs the padding the operand carried anyway: ``[55, 128]``
         -> ``[56, 128]``, ``[27, 64]`` -> ``[32, 128]``. The small
         levels' raw copies (they reach the kernel as ``flats``) stay
-        as built, and so does everything when XLA contracts the levels
-        (``ydot_in_kernel=False``)."""
+        as built, and so does the plain list of levels built for a
+        shape the kernel cannot take."""
+        if not isinstance(pyramid, dict):
+            return super().resident_pyramid(pyramid)
         levels = list(pyramid["levels"])
-        if self.ydot_in_kernel:
-            for l in _split_levels(levels, 2 * self.radius + 1)[0]:
-                hl, wl = levels[l].shape[1:3]
-                pads = [(0, 0)] * levels[l].ndim
-                pads[1:3] = (0, -hl % 8), (0, -wl % MAX_LANES)
-                levels[l] = jnp.pad(levels[l], pads)
+        for l in _split_levels(levels, 2 * self.radius + 1)[0]:
+            hl, wl = levels[l].shape[1:3]
+            pads = [(0, 0)] * levels[l].ndim
+            pads[1:3] = (0, -hl % 8), (0, -wl % MAX_LANES)
+            levels[l] = jnp.pad(levels[l], pads)
         return dict(pyramid, levels=levels)
 
     def kernel_rows(self, pyramid):
         """Shape specs of the kernel's blocked operands for the packed
-        ``pyramid`` (arrays or specs, query rows leading), as
-        :class:`_FusedPrep` hands them over: the y-dot levels' raw
-        ``(q, hl, wl)`` volumes (``(q, S, wl)`` rows when XLA contracts
-        them), lane-padded, then the flats."""
-        levels, flats, _ = self._unwrap(pyramid)
-        s = 2 * self.radius + 1
-        rows = [
-            jax.ShapeDtypeStruct(
-                (
-                    levels[l].shape[0],
-                    levels[l].shape[1] if self.ydot_in_kernel else s,
-                    _pad_width_to_lanes(levels[l].shape[2]),
-                ),
-                self.dtype or levels[l].dtype,
-            )
-            for l in _split_levels(levels, s)[0]
-        ]
-        return [*rows, *flats]
+        ``pyramid`` (arrays or specs, query rows leading): what
+        :class:`_FusedPrep` hands :func:`_invoke_xtap` for it, asked of
+        ``_FusedPrep`` itself."""
+        levels, flats = self._unwrap(pyramid)
+        return jax.eval_shape(
+            lambda lv, fl: _FusedPrep(lv, self.radius, self.dtype, fl).operands,
+            list(levels), tuple(flats),
+        )
 
     def lookup_plan(self, pyramid):
         """``(query tile, coordinates blocked?)`` the kernel picks for one
-        lookup of the packed ``pyramid``: :func:`_plan_tile` on
-        :meth:`kernel_rows`, which :func:`_invoke_xtap` calls on the
-        operands themselves (held equal in ``tests/test_hd_frames.py``).
-        Under a mesh the kernel runs per shard: hand this the rows one
-        device holds (``serve.pool.state_layout`` does, for
-        ``ServeEngine.stats()``)."""
+        lookup of ``pyramid``: :func:`_plan_tile`, as :func:`_invoke_xtap`
+        calls it, on :meth:`kernel_rows`; ``(None, None)`` for the plain
+        levels of a shape the kernel does not run. Under a mesh the
+        kernel runs per shard: hand this the rows one device holds
+        (``serve.pool.state_layout`` does, for ``ServeEngine.stats()``)."""
+        if not isinstance(pyramid, dict):
+            return None, None
         rows = self.kernel_rows(pyramid)
-        return _plan_tile(
-            rows[0].shape[0], DEFAULT_QUERY_TILE, rows,
-            _vmem_limit(self.ydot_in_kernel),
-        )
-
-    def _lookup_dtype(self, scales):
-        # int8 pyramids emit bf16 rows/taps; the block dtype otherwise
-        return jnp.bfloat16 if scales is not None else self.dtype
+        return _plan_tile(rows[0].shape[0], DEFAULT_QUERY_TILE, rows)
 
     def index_pyramid(self, pyramid, centroids: jax.Array) -> jax.Array:
-        levels, flats, scales = self._unwrap(pyramid)
-        s = 2 * self.radius + 1
-        if _fusable(levels, s):
-            if scales is not None:
-                # int8 is an inference-only knob: guarded so autodiff
-                # raises a clear error instead of pallas internals
-                feats = _inference_only(
-                    lambda lv, c, fl, sc: lookup_pyramid_fused(
-                        list(lv), c, self.radius,
-                        weight_dtype=self._lookup_dtype(sc),
-                        query_tile=DEFAULT_QUERY_TILE,
-                        interpret=self._interpret(),
-                        flats=list(fl), scales=sc,
-                        ydot_in_kernel=self.ydot_in_kernel,
-                    ),
-                    tuple(levels), centroids, tuple(flats), scales,
-                )
-            else:
-                feats = lookup_fused_diff(
-                    tuple(levels),
-                    flats,
-                    centroids,
-                    self.radius,
-                    self.dtype,
-                    DEFAULT_QUERY_TILE,
-                    self._interpret(),
-                    self.ydot_in_kernel,
-                )
+        levels, flats = self._unwrap(pyramid)
+        if _fusable(levels, 2 * self.radius + 1):
+            feats = lookup_fused_diff(
+                tuple(levels),
+                flats,
+                centroids,
+                self.radius,
+                self.dtype,
+                DEFAULT_QUERY_TILE,
+                self._interpret(),
+            )
         else:
-            # non-fusable int8 pyramids were left fp32 at build time
-            wd = None if self.dtype == jnp.int8 else self.dtype
             feats = lookup_pyramid(
-                levels, centroids, self.radius, weight_dtype=wd
+                levels, centroids, self.radius, weight_dtype=self.dtype
             )
         b, h, w, _ = centroids.shape
         assert feats.shape == (b, h, w, self.out_channels)
@@ -1397,39 +1138,23 @@ class FusedLookupCorrBlock(CorrBlock):
     ) -> jax.Array:
         """Lookup + ``convcorr1`` in one Pallas kernel (the tap tensor
         never reaches HBM); XLA fallback for non-fusable shapes."""
-        levels, flats, scales = self._unwrap(pyramid)
-        s = 2 * self.radius + 1
-        if not _fusable(levels, s):
-            # routes through our index_pyramid, whose int8 branch already
-            # handles the left-fp32 non-fusable pyramid — one fallback rule
+        levels, flats = self._unwrap(pyramid)
+        if not _fusable(levels, 2 * self.radius + 1):
             return super().index_project(
                 levels, centroids, kernel, bias, dtype=dtype
             )
-        if scales is not None:
-            out = _inference_only(
-                lambda lv, c, k, bi, fl, sc: lookup_project_fused(
-                    list(lv), c, k, bi, self.radius,
-                    weight_dtype=self._lookup_dtype(sc), proj_dtype=dtype,
-                    query_tile=DEFAULT_QUERY_TILE,
-                    interpret=self._interpret(), flats=list(fl), scales=sc,
-                    ydot_in_kernel=self.ydot_in_kernel,
-                ),
-                tuple(levels), centroids, kernel, bias, tuple(flats), scales,
-            )
-        else:
-            out = project_fused_diff(
-                tuple(levels),
-                flats,
-                centroids,
-                kernel,
-                bias,
-                self.radius,
-                self.dtype,
-                DEFAULT_QUERY_TILE,
-                self._interpret(),
-                dtype,
-                self.ydot_in_kernel,
-            )
+        out = project_fused_diff(
+            tuple(levels),
+            flats,
+            centroids,
+            kernel,
+            bias,
+            self.radius,
+            self.dtype,
+            DEFAULT_QUERY_TILE,
+            self._interpret(),
+            dtype,
+        )
         b, h, w, _ = centroids.shape
         assert out.shape == (b, h, w, kernel.shape[-1])
         return out

@@ -4,7 +4,9 @@ Duck-typed interface (kept from the reference's component contract,
 ``jax_raft/model.py:530-539``): a correlation block exposes
 ``build_pyramid(fmap1, fmap2)``, ``index_pyramid(pyramid, centroids)`` and
 ``out_channels``, so dense / fused-Pallas / on-the-fly variants are
-swappable.
+swappable. What a block builds is its own format: only its own methods
+read it, and ``resident_pyramid(pyramid)`` says whether, and in what
+shapes, it can be held across steps (the serve pool).
 
 TPU-first notes:
   * The volume matmul runs in fp32 accumulation (``preferred_element_type``)
@@ -383,6 +385,15 @@ class CorrBlock:
         if self.dtype is not None:
             vol = vol.astype(self.dtype)
         return pool_pyramid(vol, self.num_levels)
+
+    def resident_pyramid(self, pyramid):
+        """The built ``pyramid`` in the form to HOLD across many lookups
+        (``RAFT.begin_refinement``, for the serve pool's slot state): a
+        pytree of arrays, each with the ``B*Q`` query rows leading, that
+        ``index_pyramid`` / ``index_project`` take as they take the built
+        one. A block whose pyramid cannot be held by slot raises a
+        ``ValueError``. Here: the levels as built."""
+        return tuple(pyramid)
 
     def index_pyramid(self, pyramid: Sequence[jax.Array], centroids: jax.Array) -> jax.Array:
         feats = lookup_pyramid(

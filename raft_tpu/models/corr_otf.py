@@ -80,6 +80,16 @@ class OnTheFlyCorrBlock:
             levels.append(nn.avg_pool(levels[-1], (2, 2), strides=(2, 2)))
         return {"fmap1": fmap1, "fmap2_levels": levels}
 
+    def resident_pyramid(self, pyramid: Dict):
+        """Nothing to hold by slot: this block's 'pyramid' is feature
+        maps, not one row a query. Held as built it ran the 1080p cell
+        once at 46% of fused's rate (PR 30): no cell."""
+        raise ValueError(
+            "corr_impl='onthefly' cannot live in the resident slot "
+            "pool (its pyramid is feature maps, not per-query rows); "
+            "serve it with pool_capacity=0"
+        )
+
     def index_project(
         self, pyramid: Dict, centroids: jax.Array, kernel, bias, *, dtype=None
     ) -> jax.Array:

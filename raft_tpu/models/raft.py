@@ -100,7 +100,8 @@ class RAFT(nn.Module):
     Component contract (duck-typed, as in the reference docstring
     ``jax_raft/model.py:513-548``): ``feature_encoder`` / ``context_encoder``
     downsample 8x; ``corr_block`` exposes ``build_pyramid`` /
-    ``index_pyramid`` / ``out_channels``; ``update_block`` exposes
+    ``index_pyramid`` / ``out_channels`` (and ``resident_pyramid`` to be
+    held in the serve pool: ``begin_refinement``); ``update_block`` exposes
     ``hidden_state_size``; ``mask_predictor`` (optional) outputs 8*8*9
     channels.
     """
@@ -317,33 +318,15 @@ class RAFT(nn.Module):
         if context_out.shape[1:3] != (h8, w8):
             raise ValueError("context output must match the feature grid")
 
-        pyramid = self.corr_block.build_pyramid(fmap1, fmap2)
-        packed = isinstance(pyramid, dict)  # the fused block's packed form
-        if packed and "scales" in pyramid:
-            # int8 dequant scales are ONE (1, L) row per build (amax over
-            # the whole batch) — they cannot be held per slot
-            raise ValueError(
-                "corr_dtype='int8' pyramids cannot live in the resident "
-                "slot pool (per-build dequant scales); serve int8 with "
-                "pool_capacity=0"
-            )
-        if packed and "levels" not in pyramid:
-            # the on-the-fly block's 'pyramid' is feature maps, not one
-            # row a query: nothing to hold by slot. Held as built it ran
-            # the 1080p cell once at 46% of fused's rate (PR 30): no cell
-            raise ValueError(
-                "corr_impl='onthefly' cannot live in the resident slot "
-                "pool (its pyramid is feature maps, not per-query rows); "
-                "serve it with pool_capacity=0"
-            )
-        if packed:
-            # held across every iterate_step: in the block's resident
-            # shapes, so that no step re-lays a level before reading it
-            pyramid = self.corr_block.resident_pyramid(pyramid)
-        # every leaf (levels, and the packed form's flat rows) is q-major
+        # held across every iterate_step, so in the shapes the block wants
+        # it held in (or refused, where a block's pyramid cannot be held
+        # by slot): the block's answer, whatever its format. Every leaf
+        # is q-major
         pyramid = jax.tree.map(
             lambda lvl: lvl.reshape((b, h8 * w8) + lvl.shape[1:]),
-            pyramid if packed else tuple(pyramid),
+            self.corr_block.resident_pyramid(
+                self.corr_block.build_pyramid(fmap1, fmap2)
+            ),
         )
 
         hidden_size = self.update_block.hidden_state_size
@@ -388,8 +371,6 @@ class RAFT(nn.Module):
             ),
             state["pyramid"],
         )
-        if not isinstance(pyramid, dict):
-            pyramid = list(pyramid)
         body = partial(
             _refinement_step,
             coords0=coords_grid(b, h8, w8),

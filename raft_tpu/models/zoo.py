@@ -75,36 +75,32 @@ class RAFTConfig:
     # Mask predictor
     use_mask_predictor: bool
     mask_predictor_hidden: int = 256
-    # 'dense' materializes the pooled volume pyramid (reference semantics);
-    # 'fused' is dense with the Pallas x-tap lookup kernel
-    # (kernels/lookup_xtap.py); 'pallas' uses the fused volume+pyramid
-    # kernel (kernels/corr_pallas.py); 'onthefly' is the memory-free
-    # blockwise variant (corr_otf.py). All are parameter-free, so this
-    # never affects the checkpoint tree.
+    # Three correlation engines, each parameter-free (the checkpoint tree
+    # never depends on this):
+    #   'dense'    the pooled volume pyramid and the separable XLA lookup
+    #              (models/corr.py): reference semantics, the tests'
+    #              oracle, the fused block's backward pass, the CPU;
+    #   'fused'    the same pyramid, looked up and projected by the Pallas
+    #              kernel (kernels/lookup_xtap.py): what every benchmark
+    #              cell runs on the chip;
+    #   'onthefly' no volume at all, correlation rows recomputed a query
+    #              chunk at a time (models/corr_otf.py): what fits where a
+    #              volume does not (46% of fused's rate in 23% of the
+    #              memory at 1088x1920; builder's chip run, PR 30).
     corr_impl: str = "dense"
     # Computation dtype for the conv stacks ('float32' | 'bfloat16').
     # Parameters, norm statistics, correlation accumulation, flow/coordinate
     # arithmetic, and the convex-upsample softmax always stay fp32, so the
-    # checkpoint tree and EPE-critical paths are unaffected.
+    # checkpoint tree and EPE-critical paths are unaffected. The serving
+    # presets differ in it ('quality' fp32, 'throughput' bf16, which the
+    # cells run); the ledger has no cell at fp32 convs to compare.
     compute_dtype: str = "float32"
-    # Storage dtype for the correlation pyramid + lookup intermediates,
-    # independently of the conv compute dtype (None = follow compute_dtype).
-    # The pooled volume is the single largest per-iteration HBM read (the
-    # y-contraction re-reads it every flow update); 'bfloat16' halves that
-    # traffic while the volume matmul still accumulates fp32 and the convs
-    # keep their own dtype (bf16 convs measured SLOWER than fp32 on v5e —
-    # docs/perf_notes.md — so coupling the two wastes the corr win).
+    # Storage dtype for the correlation pyramid + lookup intermediates
+    # ('float32' | 'bfloat16'), independently of the conv compute dtype
+    # (None = follow compute_dtype). The pooled volume is the largest
+    # array a pair holds and every flow update reads it; 'bfloat16' halves
+    # it while the volume matmul still accumulates fp32.
     corr_dtype: Optional[str] = None
-    # Fused impl only: run the y-dot levels' bilinear y-contraction INSIDE
-    # the Pallas kernel (batched MXU dot over double-buffered raw volume
-    # blocks) instead of as XLA einsums feeding the kernel — removes the
-    # per-iteration HBM t rows, their custom-call staging copies, and the
-    # int8 path's standalone dequant convert (kernels/lookup_xtap.py).
-    # Default ON: measured faster in every fused config on v5e (+14% int8
-    # b=1 headline, +15% exact fp32, +35% bf16 b=8 — docs/perf_notes.md
-    # round 4); oracle-identical semantics, and the backward is the XLA
-    # path either way. False reproduces the round-3 kernel for A/B.
-    corr_ydot_in_kernel: bool = True
     # TPU options (no effect on the parameter tree)
     remat: bool = False
     # Selective-remat policy for the scan body (None = recompute everything;
@@ -175,18 +171,11 @@ def build_raft(
     dtype = _DTYPES[config.compute_dtype]
     if dtype == jnp.float32:
         dtype = None  # Flax default: no casting at all
-    if config.corr_dtype == "int8":
-        # symmetric per-level quantized pyramid: fused-impl inference only
-        # (the quantized lookup is not differentiable; see lookup_xtap)
-        if config.corr_impl != "fused":
-            raise ValueError("corr_dtype='int8' requires corr_impl='fused'")
-        corr_dtype = jnp.int8
-    else:
-        corr_dtype = (
-            _DTYPES[config.corr_dtype] if config.corr_dtype is not None else dtype
-        )
-        if corr_dtype == jnp.float32:
-            corr_dtype = None
+    corr_dtype = (
+        _DTYPES[config.corr_dtype] if config.corr_dtype is not None else dtype
+    )
+    if corr_dtype == jnp.float32:
+        corr_dtype = None
     if feature_encoder is None:
         feature_encoder = FeatureEncoder(
             block=_BLOCKS[config.feature_encoder_block],
@@ -212,14 +201,6 @@ def build_raft(
             corr_block = OnTheFlyCorrBlock(
                 num_levels=config.corr_levels, radius=config.corr_radius
             )
-        elif config.corr_impl == "pallas":
-            from raft_tpu.kernels import PallasCorrBlock
-
-            corr_block = PallasCorrBlock(
-                num_levels=config.corr_levels,
-                radius=config.corr_radius,
-                dtype=corr_dtype,
-            )
         elif config.corr_impl == "fused":
             from raft_tpu.kernels import FusedLookupCorrBlock
 
@@ -227,7 +208,6 @@ def build_raft(
                 num_levels=config.corr_levels,
                 radius=config.corr_radius,
                 dtype=corr_dtype,
-                ydot_in_kernel=config.corr_ydot_in_kernel,
             )
         elif config.corr_impl == "dense":
             corr_block = CorrBlock(
@@ -236,7 +216,10 @@ def build_raft(
                 dtype=corr_dtype,
             )
         else:
-            raise ValueError(f"unknown corr_impl {config.corr_impl!r}")
+            raise ValueError(
+                f"unknown corr_impl {config.corr_impl!r}: it is one of "
+                f"'dense', 'fused', 'onthefly'"
+            )
     if update_block is None:
         update_block = UpdateBlock(
             motion_encoder=MotionEncoder(

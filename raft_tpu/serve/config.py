@@ -17,31 +17,21 @@ from typing import Dict, Optional, Tuple
 
 __all__ = ["ServeConfig", "PRESETS"]
 
-# Named deployment presets: the fastest *validated* operating points,
-# promoted from bench footnotes (docs/perf_notes.md rounds 4-5) to
-# first-class serving configs. Each maps to RAFTConfig precision knobs
-# that change activation/storage casts only — never the parameter tree —
-# and each is gated by the trained-weight golden-EPE bounds in
-# tests/test_epe_golden.py (the bf16 combos are pinned there directly;
-# the int8 corr path at 3.5e-3 px delta on the fixture):
+# Named deployment presets. Each maps to RAFTConfig precision knobs that
+# change activation/storage casts only — never the parameter tree — and
+# each is gated by the trained-weight golden-EPE bounds in
+# tests/test_epe_golden.py:
 #
 #   quality     fp32 everywhere — the paper-native reference point.
-#   throughput  bf16 convs + bf16 corr storage on the fused kernel
-#               (+8% at b=8, measured round 5) — the default serving
-#               preset: the fastest config that passes the golden gates
-#               on trained weights.
-#   edge        int8 correlation storage on the fused kernel (2.02x
-#               correlation-lookup speedup, round 5) with fp32 convs —
-#               inference-only (the quantized lookup has no gradient).
+#   throughput  bf16 convs + bf16 corr storage on the fused kernel — the
+#               default serving preset, and what every benchmark cell
+#               runs (PERF.md §4).
 PRESETS: Dict[str, Dict[str, Optional[str]]] = {
     "quality": dict(
         compute_dtype="float32", corr_dtype=None, corr_impl=None,
     ),
     "throughput": dict(
         compute_dtype="bfloat16", corr_dtype="bfloat16", corr_impl="fused",
-    ),
-    "edge": dict(
-        compute_dtype="float32", corr_dtype="int8", corr_impl="fused",
     ),
 }
 
@@ -347,11 +337,10 @@ class ServeConfig:
         config, not a bench footnote).
 
         ``preset('quality')`` is fp32 everywhere; ``'throughput'`` is
-        bf16 convs + bf16 correlation storage on the fused kernel;
-        ``'edge'`` is int8 correlation storage (inference-only). Any
+        bf16 convs + bf16 correlation storage on the fused kernel. Any
         other :class:`ServeConfig` field can be overridden::
 
-            cfg = ServeConfig.preset("edge", buckets=((440, 1024),),
+            cfg = ServeConfig.preset("quality", buckets=((440, 1024),),
                                      warmup=True)
             model, variables = zoo.raft_for_serving(cfg, pretrained=True)
             engine = ServeEngine(model, variables, cfg)
@@ -559,15 +548,10 @@ class ServeConfig:
                 f"compute_dtype must be 'float32' or 'bfloat16', got "
                 f"{self.compute_dtype!r}"
             )
-        if self.corr_dtype not in (None, "bfloat16", "int8"):
+        if self.corr_dtype not in (None, "bfloat16"):
             raise ValueError(
-                f"corr_dtype must be None, 'bfloat16', or 'int8', got "
+                f"corr_dtype must be None or 'bfloat16', got "
                 f"{self.corr_dtype!r}"
-            )
-        if self.corr_dtype == "int8" and self.corr_impl != "fused":
-            raise ValueError(
-                "corr_dtype='int8' requires corr_impl='fused' (the "
-                "quantized pyramid lives in the fused lookup kernel)"
             )
         # QoS (ISSUE 17) — validated even when disabled, so a config that
         # will later be flipped on cannot carry a latent bad quota table

@@ -483,7 +483,8 @@ def state_layout(model, state) -> Dict[str, Any]:
     from shapes alone: bytes resident (``state_bytes``; ``slot_bytes`` a
     slot) and, where the state holds the fused block's packed pyramid,
     the kernel's query tile and whether its coordinate operand is blocked
-    by tile (``None`` otherwise). The kernel plans from the rows ONE
+    by tile (``None`` otherwise): the block's own ``lookup_plan``, which
+    is the plan the kernel call makes. The kernel plans from the rows ONE
     device holds (under a mesh it runs per shard), so the plan is asked
     for each leaf's shard shape."""
     leaves = jax.tree_util.tree_leaves(state)
@@ -493,7 +494,7 @@ def state_layout(model, state) -> Dict[str, Any]:
     )
     tile = blocked = None
     plan = getattr(getattr(model, "corr_block", None), "lookup_plan", None)
-    if plan is not None and isinstance(state["pyramid"], dict):
+    if plan is not None:
         def rows(v):
             shape = v.sharding.shard_shape(v.shape)
             return jax.ShapeDtypeStruct(

@@ -49,17 +49,16 @@ class TrainConfig:
     # selective-remat policy under remat=True (models.raft.REMAT_POLICIES)
     remat_policy: Optional[str] = None
     corr_impl: str = "dense"
-    # storage dtype for the correlation pyramid (None | 'bfloat16'); with
-    # corr_impl='fused' the bf16 pyramid measured +10% training
-    # throughput on one v5e (docs/perf_notes.md). Gradients are the VJP
-    # of the XLA formulation either way. 'int8' is inference-only.
+    # storage dtype for the correlation pyramid (None | 'bfloat16'): half
+    # the volume's bytes. Gradients are the VJP of the XLA formulation
+    # either way. Its effect on training throughput is not measured on
+    # this chip (the training cell is parked, PERF.md §7).
     corr_dtype: Optional[str] = None
-    # conv/activation compute dtype (None=fp32 | 'bfloat16'). bf16
-    # activations halve the backward graph's layout-copy bucket: +15%
-    # measured training throughput on raft_large (docs/perf_notes.md,
-    # round-4 train ceiling case). Params, norm statistics, flow
-    # arithmetic, and the loss stay fp32 — the checkpoint tree and
-    # EPE-critical paths are unaffected.
+    # conv/activation compute dtype (None=fp32 | 'bfloat16'): half the
+    # activations' bytes in the backward graph; throughput not measured
+    # on this chip either. Params, norm statistics, flow arithmetic, and
+    # the loss stay fp32 — the checkpoint tree and EPE-critical paths are
+    # unaffected.
     compute_dtype: Optional[str] = None
     data_mesh: bool = True  # shard over all devices' `data` axis
     # Fused multi-step dispatch (docs/perf_notes.md, training-throughput
@@ -205,11 +204,6 @@ class Trainer:
 
     def __init__(self, config: TrainConfig, dataset, *, init_from=None,
                  eval_dataset=None, eval_fn=None):
-        if config.corr_dtype == "int8":
-            # the quantized lookup has no autodiff path (lookup_xtap)
-            raise ValueError(
-                "corr_dtype='int8' is inference-only; train with 'bfloat16'"
-            )
         if config.compute_dtype not in (None, "float32", "bfloat16"):
             # fail here with the legal values, not as a KeyError deep in
             # the zoo's dtype table

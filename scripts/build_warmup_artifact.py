@@ -86,7 +86,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--tiny", action="store_true",
                     help="CPU-sized random-init model (smoke runs)")
     ap.add_argument("--preset", default=None,
-                    choices=["quality", "throughput", "edge"],
+                    choices=["quality", "throughput"],
                     help="precision preset baked into config + fingerprint")
     ap.add_argument("--pretrained", action="store_true")
     ap.add_argument("--checkpoint", default=None)

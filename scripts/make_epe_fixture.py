@@ -10,7 +10,7 @@ scalar on a real Sintel-layout directory. This script builds that pin once:
   1. trains a tiny (but genuinely converging) RAFT on synthetic warped
      pairs — trained weights make the 32-step refinement contractive, so
      cross-implementation fp32 noise cannot chaotically amplify (the same
-     argument as the int8 promotion evidence, scripts/parity_report.py);
+     argument as the bf16 storage evidence, scripts/parity_report.py);
   2. writes a miniature Sintel-layout dataset (two scenes, clean+final
      passes, .flo ground truth, non-%8 frame size so the split replicate
      padding genuinely engages);

@@ -139,19 +139,16 @@ def _warped_batch(key, b, h, w, max_flow=8.0):
     }
 
 
-def run_int8_evidence(steps: int = 600, train_hw=(256, 256), iters: int = 32):
+def run_storage_evidence(steps: int = 600, train_hw=(256, 256), iters: int = 32):
     """Train a tiny fused-impl RAFT on synthetic warped pairs ON-CHIP, then
     compare flows from the SAME trained weights across corr storage dtypes
     at the FULL acceptance scale (436x1024 padded, 32 iters).
 
-    This is the reproducible version of the promotion evidence behind the
-    int8 deployment config (docs/perf_notes.md): trained iterative
-    refinement is contractive, so per-iteration tap quantization noise
-    below the matching basin's margin converges to the same flow —
-    random-weight trajectory deltas (chaotic) say nothing, which is why
-    this trains first. corr_levels=3/radius=3 keeps every pyramid level
-    width a power of two >= 7 at both the train and eval scales, so the
-    quantized fused path genuinely engages (asserted)."""
+    This is the reproducible evidence behind bf16 correlation storage
+    (the 'throughput' preset): trained iterative refinement is
+    contractive, so per-iteration tap rounding noise below the matching
+    basin's margin converges to the same flow — random-weight trajectory
+    deltas (chaotic) say nothing, which is why this trains first."""
     import jax
     import jax.numpy as jnp
 
@@ -212,19 +209,8 @@ def run_int8_evidence(steps: int = 600, train_hw=(256, 256), iters: int = 32):
     im1, im2 = padder.pad(np.asarray(ev["image1"]), np.asarray(ev["image2"]))
 
     flows = {}
-    for cdt in ("float32", "bfloat16", "int8"):
+    for cdt in ("float32", "bfloat16"):
         m = build_raft(tiny.replace(corr_dtype=cdt))
-        # the quantized path must actually engage at this geometry
-        if cdt == "int8":
-            f = m.feature_encoder.apply(
-                {"params": trained["params"]["feature_encoder"]},
-                jnp.concatenate([jnp.asarray(im1), jnp.asarray(im2)], axis=0),
-            )
-            f1, f2 = jnp.split(f, 2, axis=0)
-            pyr = m.corr_block.build_pyramid(f1, f2)
-            assert isinstance(pyr, dict) and "scales" in pyr, (
-                "int8 fused path did not engage at eval scale"
-            )
         fn = jax.jit(
             partial(m.apply, trained, train=False, num_flow_updates=iters,
                     emit_all=False)
@@ -241,14 +227,13 @@ def run_int8_evidence(steps: int = 600, train_hw=(256, 256), iters: int = 32):
         "eval_epe_fp32": epe,
         "eval_flow_mag": gt_mag,
     }
-    for cdt in ("bfloat16", "int8"):
-        d = np.abs(flows[cdt].astype(np.float64) - flows["float32"])
-        out[f"{cdt}_max_dflow"] = float(d.max())
-        out[f"{cdt}_mean_dflow"] = float(d.mean())
+    d = np.abs(flows["bfloat16"].astype(np.float64) - flows["float32"])
+    out["bfloat16_max_dflow"] = float(d.max())
+    out["bfloat16_mean_dflow"] = float(d.mean())
     return out
 
 
-def int8_evidence_section(ev) -> list:
+def storage_evidence_section(ev) -> list:
     # margin matters: the documented dead-end generator plateaus AT
     # EPE ~= flow magnitude (labels wrong by ~|grad f||f|), which a bare
     # '<' would pass; demand clear separation before calling it trained
@@ -269,12 +254,11 @@ def int8_evidence_section(ev) -> list:
         ]
     return [
         "",
-        "## int8/bf16 correlation storage on TRAINED weights, full scale",
+        "## bf16 correlation storage on TRAINED weights, full scale",
         "",
-        f"Reproducible promotion evidence for the quantized deployment "
-        f"config (`scripts/parity_report.py --int8-evidence`): a tiny "
-        f"fused-impl RAFT (corr_levels=3, radius=3 — every level width "
-        f"pow2 >= 7 at both scales, quantized path engagement asserted) "
+        f"Reproducible evidence for the bf16-storage deployment config "
+        f"(`scripts/parity_report.py --storage-evidence`): a tiny "
+        f"fused-impl RAFT (corr_levels=3, radius=3) "
         f"trained {ev['train_steps']} steps on-chip on synthetic warped "
         f"pairs (correlation-dependent by construction), then the SAME "
         f"trained weights evaluated at the full acceptance scale "
@@ -287,9 +271,8 @@ def int8_evidence_section(ev) -> list:
         "|---|---|---|",
         f"| bfloat16 | {ev['bfloat16_max_dflow']:.2e} | "
         f"{ev['bfloat16_mean_dflow']:.2e} |",
-        f"| int8 | {ev['int8_max_dflow']:.2e} | {ev['int8_mean_dflow']:.2e} |",
         "",
-        "Trained refinement is contractive: per-iteration tap quantization",
+        "Trained refinement is contractive: per-iteration tap rounding",
         "noise converges to the same flow (random-weight trajectory deltas",
         "are chaotic and say nothing — which is why this trains first).",
         "A real-checkpoint Sintel EPE run remains the definitive check the",
@@ -307,13 +290,13 @@ def main():
                          "dense for the quick CPU run (the fused path "
                          "runs in interpret mode off-TPU)")
     ap.add_argument(
-        "--int8-evidence", action="store_true",
+        "--storage-evidence", action="store_true",
         help="also train a tiny fused RAFT on synthetic warped pairs and "
-             "record int8/bf16-vs-fp32 flow deltas from the trained weights "
-             "at full scale (the quantized-deployment promotion evidence)")
+             "record bf16-vs-fp32 correlation-storage flow deltas from the "
+             "trained weights at full scale")
     ap.add_argument(
         "--evidence-only", action="store_true",
-        help="skip the (slow) parity variants; run only the int8 evidence "
+        help="skip the (slow) parity variants; run only the storage evidence "
              "and splice its section into the existing PARITY.md")
     ap.add_argument("--evidence-steps", type=int, default=3000)
     ap.add_argument(
@@ -325,7 +308,7 @@ def main():
         "the MXU's default bf16 truncation",
     )
     args = ap.parse_args()
-    if (args.int8_evidence or args.evidence_only) and args.evidence_steps < 1:
+    if (args.storage_evidence or args.evidence_only) and args.evidence_steps < 1:
         ap.error("--evidence-steps must be >= 1")
     if args.device == "cpu":
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -335,8 +318,8 @@ def main():
     import jax
 
     if args.evidence_only:
-        evidence = run_int8_evidence(steps=args.evidence_steps)
-        section = "\n".join(int8_evidence_section(evidence))
+        evidence = run_storage_evidence(steps=args.evidence_steps)
+        section = "\n".join(storage_evidence_section(evidence))
         text = ""
         if os.path.exists(args.out):
             with open(args.out) as f:
@@ -344,7 +327,7 @@ def main():
         # replace ONLY the old evidence section (plus a legacy pre-table
         # WARNING immediately before it); any sections added after it
         # survive the splice
-        marker = "\n## int8/bf16 correlation storage"
+        marker = "\n## bf16 correlation storage"
         hpos = text.find(marker)
         start = hpos
         legacy_warn = text.find("\n**WARNING: the toy model did NOT converge")
@@ -404,8 +387,8 @@ def main():
         vals = " ".join(f"{v:.1e}" for v in r["per_iter_max"])
         lines.append(f"{r['arch']}: {vals}")
     evidence = None
-    if args.int8_evidence:
-        evidence = run_int8_evidence(steps=args.evidence_steps)
+    if args.storage_evidence:
+        evidence = run_storage_evidence(steps=args.evidence_steps)
 
     lines += [
         "```",
@@ -433,7 +416,7 @@ def main():
         "",
     ]
     if evidence is not None:
-        lines += int8_evidence_section(evidence)
+        lines += storage_evidence_section(evidence)
     with open(args.out, "w") as f:
         f.write("\n".join(lines))
     print("\n".join(lines))

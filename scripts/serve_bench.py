@@ -2370,7 +2370,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--queue-capacity", type=int, default=64)
     ap.add_argument("--no-warmup", action="store_true")
     ap.add_argument("--preset", default=None,
-                    choices=["quality", "throughput", "edge"],
+                    choices=["quality", "throughput"],
                     help="deployment precision preset (ServeConfig.preset): "
                          "threads corr_dtype/compute_dtype through the zoo "
                          "into the engine")

@@ -98,7 +98,7 @@ def main():
     p.add_argument("--profile-port", type=int, default=None,
                    help="start jax.profiler server on this port")
     p.add_argument("--init-from", default=None, help=".msgpack weights to start from")
-    p.add_argument("--corr-impl", default="dense", choices=["dense", "onthefly", "pallas", "fused"])
+    p.add_argument("--corr-impl", default="dense", choices=["dense", "onthefly", "fused"])
     p.add_argument("--corr-dtype", default=None, choices=["bfloat16"],
                    help="bf16 correlation pyramid storage (+10%% measured "
                         "training throughput with --corr-impl fused; "

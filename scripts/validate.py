@@ -60,18 +60,16 @@ def main():
     p.add_argument("--iters", type=int, default=32)
     p.add_argument("--fps-pairs", type=int, default=64)
     p.add_argument("--corr-impl", default=None,
-                   choices=["dense", "onthefly", "pallas", "fused"],
+                   choices=["dense", "onthefly", "fused"],
                    help="correlation implementation (default: library "
                         "dense; 'fused' engages the Pallas deployment "
                         "kernel — since round 5 at ANY geometry incl. "
                         "KITTI's 1242-wide frames, measured 2.3x the "
                         "dense path there)")
     p.add_argument("--corr-dtype", default=None,
-                   choices=["bfloat16", "int8"],
+                   choices=["bfloat16"],
                    help="reduced-precision correlation storage (bfloat16 "
-                        "is the deployment config, int8 the retired "
-                        "alternative; both inference-only, fine for "
-                        "validation; default exact fp32)")
+                        "is the deployment config; default exact fp32)")
     args = p.parse_args()
 
     from raft_tpu.eval import validate

@@ -24,17 +24,15 @@ def main():
     p.add_argument("--pretrained", action="store_true", default=None)
     p.add_argument("--iters", type=int, default=32)
     p.add_argument("--corr-impl", default=None,
-                   choices=["dense", "onthefly", "pallas", "fused"],
+                   choices=["dense", "onthefly", "fused"],
                    help="correlation implementation (default: library "
                         "dense fp32 — the published-protocol semantics; "
                         "'fused' runs the Pallas deployment kernel)")
     p.add_argument("--corr-dtype", default=None,
-                   choices=["bfloat16", "int8"],
+                   choices=["bfloat16"],
                    help="reduced-precision correlation storage (bfloat16 "
                         "is the deployment config, golden-fixture EPE "
-                        "delta bounded in tests/test_epe_golden.py; int8 "
-                        "is the retired alternative — both are "
-                        "inference-only knobs, fine for validation)")
+                        "delta bounded in tests/test_epe_golden.py)")
     args = p.parse_args()
 
     from raft_tpu.eval import validate_sintel

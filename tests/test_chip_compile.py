@@ -126,8 +126,7 @@ def test_lookup_xtap_forward_compiles(one_chip, h8, w8):
     """The deployment kernel (fused lookup + convcorr1, bf16 storage,
     y-dot in kernel) at the real level shapes of both eval geometries."""
     block = FusedLookupCorrBlock(
-        LEVELS, RADIUS, dtype=jnp.bfloat16, interpret=False,
-        ydot_in_kernel=True,
+        LEVELS, RADIUS, dtype=jnp.bfloat16, interpret=False
     )
     args = _on(one_chip, _project_args(block, 1, h8, w8))
     compiled = jax.jit(
@@ -381,18 +380,3 @@ def test_lookup_xtap_unsharded_under_mesh_is_refused(topo, no_persistent_cache):
             _on(row, pyramid), _on(row, cents), _on(rep, kernel),
             _on(rep, bias),
         )
-
-
-def test_fused_volume_pyramid_compiles(one_chip):
-    """``corr_impl='pallas'``'s volume+pool kernel at raft_large's real
-    widths (256 channels, 128-wide feature rows, 96 MiB VMEM limit).
-    Height is cut to 16 rows: the kernel unrolls over the feature height
-    and the full 55-row Sintel grid takes 92 s to compile (it does
-    compile — builder's rehearsal, PR 23)."""
-    from raft_tpu.kernels.corr_pallas import fused_volume_pyramid
-
-    fmap = _on(one_chip, jax.ShapeDtypeStruct((1, 16, 128, FMAP_C), jnp.float32))
-    compiled = jax.jit(
-        lambda a, b: fused_volume_pyramid(a, b, LEVELS, interpret=False)
-    ).lower(fmap, fmap).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1

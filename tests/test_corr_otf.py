@@ -127,8 +127,9 @@ def test_window_lookup_matches_gather_oracle(rng, radius):
 def test_onthefly_refuses_the_slot_pool(rng):
     """The slot pool's entry point on the on-the-fly block: its 'pyramid'
     is feature maps, not one row a query, so there is nothing to hold by
-    slot. ``begin_pair`` says so with a typed error (as for int8 storage)
-    and not an ``AttributeError`` from inside the fold; the scan serves
+    slot. ``begin_pair`` says so with a typed error (the block's own
+    ``resident_pyramid``) and not an ``AttributeError`` from inside the
+    fold; the scan serves
     it (``pool_capacity=0``). Held as built it ran the 1080p cell once at
     46% of the fused block's rate (PERF.md, PR 30) and got no cell."""
     cfg = RAFT_SMALL.replace(

@@ -146,33 +146,3 @@ def test_throughput_preset_is_the_gated_bf16_config():
     assert ServeConfig.preset("throughput").model_overrides() == dict(
         corr_impl="fused", corr_dtype="bfloat16", compute_dtype="bfloat16"
     )
-
-
-@pytest.mark.slow
-def test_edge_preset_epe_pinned(fixture_data):
-    """ISSUE 7 preset gate: the ``'edge'`` preset (int8 correlation
-    storage on the fused kernel, fp32 convs) against the
-    reference-produced golden scalar on real frames with trained
-    weights. Measured delta at gate introduction: 5.1e-3 px (the
-    trained 32-step refinement is contractive, so the ~1% per-tap
-    quantization noise does not amplify); tol = ~6x margin. Slow-marked
-    because the int8 lookup runs the Pallas kernel in interpret mode on
-    CPU — minutes, not seconds."""
-    from raft_tpu.data.datasets import Sintel
-    from raft_tpu.eval.validate import validate
-    from raft_tpu.models.zoo import build_raft
-    from raft_tpu.serve import ServeConfig
-
-    from scripts.make_epe_fixture import fixture_arch
-
-    _, trained, expected = fixture_data
-    knobs = ServeConfig.preset("edge").model_overrides()
-    model = build_raft(fixture_arch().replace(**knobs))
-    ds = Sintel(FIXTURE, split="training", dstype="clean")
-    m = validate(
-        model, trained, ds,
-        num_flow_updates=expected["protocol"]["iters"],
-        mode="sintel", fps_pairs=0, progress=False,
-    )
-    ref_epe = expected["reference"]["clean"]
-    assert abs(m["epe"] - ref_epe) < 3e-2, (knobs, m["epe"], ref_epe)

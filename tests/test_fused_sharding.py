@@ -5,7 +5,7 @@ multi-chip mesh were never exercised together — GSPMD cannot partition an
 opaque TPU custom call, so without a rule the kernel would replicate (or
 fail) under sharding. ``lookup_xtap._partitioned_xtap`` ``shard_map``s the
 kernel over the ambient mesh (query axis embarrassingly parallel; weights/
-scales/lane dims replicated) — sharded programs are traced under
+lane dims replicated) — sharded programs are traced under
 ``parallel.traced_under(mesh, ...)``. These tests pin, on the 8-device
 virtual CPU mesh (interpret-mode kernels — the same shard_map and
 per-shard lowering path a real slice takes):
@@ -15,8 +15,7 @@ per-shard lowering path a real slice takes):
   * lookup/project outputs under the mesh match the single-device kernel;
   * a full fused train step under (data=2, space=2) produces the SAME
     updated params as the single-device fused step (the DP-equivalence
-    bar of tests/test_train.py applied to the deployment corr path);
-  * the int8 (scales-carrying) project variant partitions too.
+    bar of tests/test_train.py applied to the deployment corr path).
 """
 
 import re
@@ -108,8 +107,7 @@ class TestPartitionedLookup:
         # partitioning evidence: per-shard (q/8-row) shapes exist in the
         # compiled module and NO q-row global shape survives anywhere —
         # a replicated (unpartitioned) kernel would keep its global-q
-        # operands (the raw (q, hl, wl) volume blocks under the default
-        # ydot_in_kernel, or (q, S, wl) t rows without it).
+        # operands (the raw (q, hl, wl) volume blocks).
         q = b * h * w
         txt = compiled.as_text()
         local = q // 8
@@ -243,58 +241,3 @@ class TestFusedTrainStepUnderMesh:
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b_), rtol=2e-3, atol=1e-5
             )
-
-
-class TestInt8ProjectUnderMesh:
-    def test_int8_project_partitions(self, rng):
-        """The scales-carrying int8 lookup+project variant under the mesh:
-        output matches single-device, per-shard shapes in the HLO."""
-        b, h, w = 8, 8, 16
-        h0, w0 = 8, 16
-        radius, levels = 2, 2
-        s = 2 * radius + 1
-        c_in = levels * s * s
-        c_out = 32
-
-        blk = FusedLookupCorrBlock(
-            num_levels=levels, radius=radius, dtype=jnp.int8, interpret=True
-        )
-        f1 = jnp.asarray(rng.standard_normal((b, h0, w0, 16)).astype(np.float32))
-        f2 = jnp.asarray(rng.standard_normal((b, h0, w0, 16)).astype(np.float32))
-        pyramid = blk.build_pyramid(f1, f2)
-        assert isinstance(pyramid, dict) and "scales" in pyramid
-        cents = _cents(rng, b, h, w, h0, w0)
-        kernel = jnp.asarray(
-            rng.standard_normal((1, 1, c_in, c_out)).astype(np.float32)
-        )
-        bias = jnp.asarray(rng.standard_normal((c_out,)).astype(np.float32))
-
-        want = blk.index_project(pyramid, cents, kernel, bias)
-
-        mesh = make_mesh(data=4, space=2)
-        qspec = P(("data", "space"))
-
-        def shard_pyr(p):
-            def put(x):
-                spec = [None] * x.ndim
-                if x.shape[0] == b * h * w:
-                    spec[0] = ("data", "space")
-                return jax.device_put(x, NamedSharding(mesh, P(*spec)))
-
-            return jax.tree.map(put, p)
-
-        fn = jax.jit(
-            lambda p, c, k, bi: blk.index_project(p, c, k, bi),
-        )
-        got = fn(
-            shard_pyr(pyramid),
-            jax.device_put(
-                cents, NamedSharding(mesh, P("data", "space", None, None))
-            ),
-            kernel,
-            bias,
-        )
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
-        )
-        del qspec

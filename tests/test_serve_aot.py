@@ -16,9 +16,8 @@ The claims under test, CPU-only and tier-1-collected:
     and a booting engine *degrades to compiling* instead of refusing to
     boot;
   * ``ServeConfig.preset`` names exactly the golden-EPE-gated precision
-    configs (the bf16 combos pinned in tests/test_epe_golden.py, the
-    int8 corr path gated there too) and a preset-built model runs the
-    serve fault ladder unchanged.
+    configs (the bf16 combos pinned in tests/test_epe_golden.py) and a
+    preset-built model runs the serve fault ladder unchanged.
 """
 
 import os
@@ -116,31 +115,21 @@ class TestPresets:
         assert cfg.corr_dtype is None and cfg.corr_impl is None
         assert cfg.model_overrides() == {}
 
-    def test_edge_is_int8_corr(self):
-        cfg = ServeConfig.preset("edge")
-        assert cfg.model_overrides() == dict(
-            corr_dtype="int8", corr_impl="fused"
-        )
-
     def test_unknown_preset_raises(self):
         with pytest.raises(ValueError, match="unknown precision preset"):
             ServeConfig.preset("warp9")
         with pytest.raises(ValueError, match="unknown precision preset"):
             ServeConfig(precision="warp9")
+        with pytest.raises(ValueError, match="compute_dtype"):
+            ServeConfig(compute_dtype="float16")
 
     def test_preset_composes_with_overrides(self):
         cfg = ServeConfig.preset(
-            "edge", buckets=((64, 80),), max_batch=4, warmup=True
+            "quality", buckets=((64, 80),), max_batch=4, warmup=True
         )
         assert cfg.buckets == ((64, 80),)
         assert cfg.max_batch == 4 and cfg.warmup
-        assert cfg.corr_dtype == "int8"
-
-    def test_int8_requires_fused_at_config_level(self):
-        with pytest.raises(ValueError, match="fused"):
-            ServeConfig(corr_dtype="int8", corr_impl="dense")
-        with pytest.raises(ValueError, match="compute_dtype"):
-            ServeConfig(compute_dtype="float16")
+        assert cfg.compute_dtype == "float32" and cfg.corr_dtype is None
 
     def test_preset_threads_dtypes_into_model(self):
         """raft_for_serving / build_raft wire the preset's dtypes into
@@ -159,10 +148,10 @@ class TestPresets:
         assert m.corr_block.dtype == jnp.bfloat16
         m = build_raft(
             tiny_config().replace(
-                **ServeConfig.preset("edge").model_overrides()
+                **ServeConfig.preset("quality").model_overrides()
             )
         )
-        assert m.corr_block.dtype == jnp.int8
+        assert m.corr_block.dtype is None  # fp32 storage
         assert m.feature_encoder.dtype is None  # fp32 convs
 
     def test_preset_knobs_are_the_golden_gated_sets(self):
@@ -176,10 +165,8 @@ class TestPresets:
             compute_dtype="bfloat16", corr_dtype="bfloat16",
             corr_impl="fused",
         )  # == the deploy-raft-small-knobs golden case
-        assert PRESETS["edge"] == dict(
-            compute_dtype="float32", corr_dtype="int8", corr_impl="fused",
-        )  # == the int8 golden case
         assert PRESETS["quality"]["compute_dtype"] == "float32"
+        assert set(PRESETS) == {"quality", "throughput"}
 
 
 class TestCompileCounter:
