@@ -6,10 +6,16 @@ current match, on every level of the pooled all-pairs volume, then the
 motion encoder's ``convcorr1`` 1x1 projection of those taps. In the
 serve pool's step program it is one Mosaic call, and the largest part of
 the tick: of 8.25 / 5.39 / 12.36 ms a tick (raft_large and raft_small at
-440x1024 on 16 slots, raft_large at 1088x1920 on 2; ledger, PR 29-31)
-the call takes 4.05 / 3.15 / 8.68 ms (builder's traces, PR 29-30), which
-is 4.46 / 3.07 / 1.20% of the bytes-bound least time for the cells its
-taps touch (``lookup_xtap_roofline.offline``; ledger, PR 31).
+440x1024 on 16 slots, raft_large at 1088x1920 on 2; ledger, PR 29-32)
+the call took 4.05 / 3.15 / 8.68 ms while it read whole levels. Since
+PR 34 a grid step reads a y-window of each large level (below): at
+1088x1920 the call alone takes 4.19 ms against 8.87 and the tick 7.65
+ms against 12.36 (builder's chip runs, PR 34), which is 2.66% of the
+bytes-bound least time for the cells its taps touch
+(``lookup_xtap_roofline.offline``) against 1.20%. At 440x1024 the body
+— the lane gathers — outlasts the DMA of whole levels (a window of 32
+of level 0's 56 rows left the call at 4.32 ms against 4.30 on 16
+slots), so those levels are read whole and the call is the one it was.
 
 How the work is split, and why:
 
@@ -30,6 +36,31 @@ How the work is split, and why:
     against bilinear y-weights built from iotas, as one batched MXU
     ``dot_general``: the ``(q, S, wl)`` rows never exist in HBM, and the
     volume is read once a step.
+  * ... and of that volume only the rows a query tile's taps can reach
+    (PR 34). A tile is ``tq`` consecutive queries — 2.7 image rows at
+    1088x1920, 5 at 440x1024 — so at level ``l`` its taps lie in rows
+    ``floor(min y / 2^l) - r .. floor(max y / 2^l) + r + 1``. The call
+    computes each tile's first row from the coordinates it already
+    takes (:func:`_window_plan`: a reduce over ``(tiles, tq)`` in XLA),
+    hands it to the pipeline as a scalar-prefetch operand, and the
+    level's block is ``H`` rows from there (an element-indexed
+    ``BlockSpec``, on a row tile of 8), ``H`` from shapes
+    (:func:`_window_heights`: 32 / 24 of 136 / 72 rows at 1088x1920);
+    the y-weights count rows from the window's first, so rows outside
+    it have the zero weight they had. A level whose window would be no
+    clear saving (under half its rows: level 2's 24 of 40 there, 32 of
+    56 at 440x1024), or whose rows are no whole row tiles (every level
+    outside the pool's resident form), is read whole by the same code: ``H`` is its rows, the Mosaic module
+    is the one it was. A tile whose taps do not fit one window
+    (vertical flow varying across the tile by more than ``H`` leaves)
+    sums the y-dots of as many windows as its span needs, in fp32,
+    before the one rounding to the storage dtype
+    (:func:`_ydot_windows`): same program, same result — bit for bit in
+    bf16 storage, on the chip too; to an ulp in fp32 storage, where a
+    tap's two rows in different windows are two rounded products and
+    not a fused multiply-add — at a cost in proportion to the span (at
+    1088x1920 with half the tiles on two or more windows the call takes
+    8.84 ms, the whole-level read 8.97).
   * the small pooled levels (``flat_levels``) skip the y-dot entirely:
     their whole volumes are packed at build time into lane-dense rows
     and both bilinear axes run as 4-corner lane gathers. A separate
@@ -129,43 +160,198 @@ _SCRATCH_LANE_BYTES = 160
 _VMEM_LIMIT = 100 << 20
 
 
-def _row_bytes(operands) -> int:
-    """VMEM bytes ONE query row of the blocked operands takes: a
-    ``(q, a, b)`` operand's ``(a, b)`` slab in whole (8, 128) tiles, a
-    ``(q, n)`` flat row in whole lanes."""
-    total = 0
+# Rows of a raw-volume level one DMA can start at: the (8, 128) tile of
+# the operand's HBM layout (bf16 is T(8,128)(2,1): eight rows are one
+# contiguous 2 KiB tile a 128-lane column, like fp32's eight).
+_ROW_TILE = 8
+
+# Level-0 rows (1/8 resolution: 8 px each) a window leaves, beside the
+# rows its tile spans itself, for how much the vertical flow VARIES
+# across one tile. A tile whose taps spread further takes more windows
+# (exact, slower). Once the window is cut to whole row tiles, 8 leaves
+# 12-19 rows of room at 1088x1920 and 11-18 at 440x1024, by where the
+# tile's first tap row falls in its row tile.
+_WINDOW_SPREAD = 8
+
+# A level is read by window only where the window is clearly smaller
+# than the level: under this share of its rows. Set once from what
+# Sintel's level 0 at 32 of 56 rows (0.57) measured on the chip (PR 34):
+# the kernel alone 4.30 -> 4.32 ms for raft_large on 16 slots, the tick
+# 8.2533 -> 8.2250 ms, and for raft_small 5.3895 -> 5.4991 — at 440x1024
+# the body outlasts the DMA of whole levels, so a window saves HBM
+# traffic and no time, and its accumulator costs a little. Under half,
+# the levels are those whose whole read was the bound: 32 of 136 and 24
+# of 72 rows at 1088x1920 (8.87 -> 4.19 ms); 24 of 40 stays whole.
+_WINDOW_SHARE = 0.5
+
+
+class _Plan(NamedTuple):
+    """How one call reads its blocked operands: ``tile`` query rows a
+    grid step; the coordinate operand ``coords_blocked`` by tile or whole
+    in VMEM; of the ``rows[k]`` that raw-volume operand ``k`` holds, a
+    step brings ``heights[k]`` into VMEM at a time (all of them where
+    the level is read whole)."""
+
+    tile: int
+    coords_blocked: bool
+    heights: tuple
+    rows: tuple
+
+
+def _lanes(x) -> int:
+    return -(-x.shape[-1] // MAX_LANES) * MAX_LANES
+
+
+def _row_bytes(operands, heights) -> int:
+    """VMEM bytes ONE query row of the blocked operands takes:
+    ``heights[k]`` rows of the ``k``-th ``(q, a, b)`` operand's ``(a,
+    b)`` slab in whole (8, 128) tiles, a ``(q, n)`` flat row in whole
+    lanes."""
+    total, k = 0, 0
     for x in operands:
-        lanes = -(-x.shape[-1] // MAX_LANES) * MAX_LANES
-        rows = -(-x.shape[1] // 8) * 8 if len(x.shape) == 3 else 1
-        total += rows * lanes * jnp.dtype(x.dtype).itemsize
+        rows = 1
+        if len(x.shape) == 3:
+            rows = -(-heights[k] // 8) * 8
+            k += 1
+        total += rows * _lanes(x) * jnp.dtype(x.dtype).itemsize
     return total
 
 
-def _plan_tile(q: int, query_tile: int, operands):
-    """``(tile, blocked)`` for ``q`` query rows of the blocked
-    ``operands`` (arrays or shape specs), from shapes alone. One rule:
-    what a tile needs — its level blocks twice (double-buffered), the
-    body's scratch (``_SCRATCH_LANE_BYTES``), and the coordinate operand
-    where it lies whole in VMEM (512 B a row, lane-padded) — fits the
-    call's VMEM limit (``_VMEM_LIMIT``).
+def _tile_row_bytes(operands, heights) -> int:
+    """VMEM bytes a tile takes a query row: its blocks twice
+    (double-buffered), a windowed level's a third time (the buffer that
+    windows past a tile's first are copied into), the body's scratch by
+    the widest raw level's lanes."""
+    raws = [x for x in operands if len(x.shape) == 3]
+    lanes = max([_lanes(x) for x in raws] or [MAX_LANES])
+    third = sum(
+        h * _lanes(x) * jnp.dtype(x.dtype).itemsize
+        for x, h in zip(raws, heights) if h < x.shape[1]
+    )
+    return (
+        2 * _row_bytes(operands, heights) + third
+        + _SCRATCH_LANE_BYTES * lanes
+    )
+
+
+def _window_heights(raws, levels, q, tq, width, radius):
+    """Rows of each raw-volume operand a ``tq``-query tile brings in at
+    a time, from shapes. A tile of consecutive queries spans
+    ``ceil(tq / width)`` rows of the ``width``-wide query grid (one more
+    where it starts mid-row); at level ``l`` its taps reach ``r`` rows
+    above and ``r + 1`` below that span halved ``l`` times, and the
+    window starts on a row tile, so up to ``_ROW_TILE - 1`` rows before
+    the first tap come along. The level is read whole where that is no
+    clear saving (``_WINDOW_SHARE``), where its rows are no whole row
+    tiles (a window at the bottom edge could not start on one: the
+    unpadded levels of ``RAFT.__call__`` and the trainer), where the
+    grid has a masked tail (an element-indexed block cannot run past the
+    array)."""
+    if q % tq:
+        return tuple(x.shape[1] for x in raws)
+    span = -(-tq // width) - (0 if tq % width else 1) + _WINDOW_SPREAD
+    heights = []
+    for x, level in zip(raws, levels):
+        reach = -(-span // 2**level) + 2 * radius + 2 + _ROW_TILE - 1
+        h = -(-reach // _ROW_TILE) * _ROW_TILE
+        rows = x.shape[1]
+        windowed = rows % _ROW_TILE == 0 and h < _WINDOW_SHARE * rows
+        heights.append(h if windowed else rows)
+    return tuple(heights)
+
+
+def _plan_tile(q: int, query_tile: int, operands, levels, width: int,
+               radius: int) -> _Plan:
+    """The :class:`_Plan` for ``q`` query rows of the blocked
+    ``operands`` (arrays or shape specs; ``levels`` are the pyramid
+    levels of the raw-volume ones, ``width`` the queries an image row),
+    from shapes alone. One rule: what a tile needs — its level blocks
+    twice (double-buffered; a windowed level a third time, for windows
+    past a tile's first), the body's scratch (``_SCRATCH_LANE_BYTES``),
+    and the coordinate operand where it lies whole in VMEM (512 B a row,
+    lane-padded) — fits the call's VMEM limit (``_VMEM_LIMIT``).
 
     The tile is the largest :func:`_pick_tile` gives under ``query_tile``
-    that fits with the coordinates blocked: 640 at every Sintel, KITTI
-    and training shape (a row of levels 0-1 is 23 KB at 440x1024), 408
-    at 1088x1920, where a row of levels 0-2 is 97 KB (640 of them, twice,
-    are 121 MiB; 480 miss the limit by 4.2 MiB, compiled). ``blocked``
-    says whether the coordinate operand is blocked by tile like the
-    levels: only where whole it would not fit beside them — a 16-slot
-    Sintel pool keeps it whole (55 MiB beside 29 + 12.5), a 32-slot one
-    (110 MiB) or any 1088x1920 pool does not."""
-    lanes = max(
-        [-(-x.shape[-1] // MAX_LANES) * MAX_LANES for x in operands
-         if len(x.shape) == 3] or [MAX_LANES]
-    )
-    per_row = 2 * _row_bytes(operands) + _SCRATCH_LANE_BYTES * lanes
-    tq = _pick_tile(q, max(8, min(query_tile, _VMEM_LIMIT // per_row)))
+    that fits with the coordinates blocked, its windows
+    (:func:`_window_heights`) counted at that tile: 640 at every
+    Sintel, KITTI and training shape (a row of whole levels 0-1 is 23 KB
+    at 440x1024) and, since PR 34, at 1088x1920, where a row of the
+    blocks is 33 KB with levels 0-1 by window and whole levels 0-2 took
+    97 KB and a 408-row tile. ``coords_blocked`` says whether the
+    coordinate operand is blocked by tile like the levels: only where
+    whole it would not fit beside them — a 16-slot Sintel pool keeps it
+    whole, a 32-slot one (110 MiB) or any 1088x1920 pool does not."""
+    raws = [x for x in operands if len(x.shape) == 3]
+    cap = query_tile
+    while True:
+        tq = _pick_tile(q, max(8, cap))
+        heights = _window_heights(raws, levels, q, tq, width, radius)
+        per_row = _tile_row_bytes(operands, heights)
+        if tq * per_row <= _VMEM_LIMIT or tq <= 8:
+            break
+        cap = min(tq - 8, _VMEM_LIMIT // per_row)
     cents_bytes = -(-q // tq) * tq * MAX_LANES * 4
-    return tq, tq * per_row + cents_bytes > _VMEM_LIMIT
+    return _Plan(
+        tq, tq * per_row + cents_bytes > _VMEM_LIMIT, heights,
+        tuple(x.shape[1] for x in raws),
+    )
+
+
+class _Window(NamedTuple):
+    """One windowed level of a call (static): ``index`` among the call's
+    windowed levels (its row of the prefetched starts and counts),
+    pyramid ``level``, the resident ``rows`` of its map and the
+    ``height`` a window reads of them."""
+
+    index: int
+    level: int
+    rows: int
+    height: int
+
+
+def _windows(vols, levels, heights):
+    """The :class:`_Window` of each raw-volume operand (``None`` where it
+    is read whole), per operand."""
+    out, n = [], 0
+    for v, level, h in zip(vols, levels, heights):
+        windowed = h < v.shape[1]
+        out.append(_Window(n, level, v.shape[1], h) if windowed else None)
+        n += windowed
+    return tuple(out)
+
+
+def _window_plan(cy, windows, radius: int):
+    """Where each tile's window of each windowed level starts, and how
+    many windows the tile needs: ``(starts, counts)``, int32
+    ``(len(windows), tiles)``, from the tiles' level-0 ``y`` coordinates
+    ``cy`` ``(tiles, tq)``, for the :class:`_Window` s ``windows``.
+
+    At level ``l`` a query's taps have weight on rows ``floor(y / 2^l) -
+    r .. floor(y / 2^l) + r + 1``, so a tile needs ``lo .. hi`` from its
+    least and largest ``y``, cut to the rows there are (the weights
+    vanish outside them). The first window starts on the row tile at or
+    before ``lo``, clamped so that it ends inside the level; ``counts``
+    windows of ``height`` rows from there reach ``hi``. Whatever the
+    coordinates hold, both stay in range: far outside the frame a window
+    misses every tap and multiplies by zero weights; a tile with a
+    coordinate that is not a number reads every window, so its other
+    queries get their taps."""
+    lo0, hi0 = jnp.min(cy, axis=1), jnp.max(cy, axis=1)
+    nan = jnp.isnan(lo0) | jnp.isnan(hi0)  # min and max carry a NaN
+    lo0, hi0 = jnp.where(nan, -jnp.inf, lo0), jnp.where(nan, jnp.inf, hi0)
+    starts, counts = [], []
+    for _, level, rows, h in windows:
+        inv = 1.0 / (2.0**level)
+
+        def row(v, off):
+            v = jnp.clip(jnp.floor(v * inv) + off, 0, rows - 1)
+            return jnp.clip(v.astype(jnp.int32), 0, rows - 1)
+
+        lo, hi = row(lo0, -radius), row(hi0, radius + 1)
+        start = jnp.minimum(lo // _ROW_TILE * _ROW_TILE, rows - h)
+        starts.append(start)
+        counts.append(jnp.clip((hi - start) // h + 1, 1, -(-rows // h)))
+    return jnp.stack(starts), jnp.stack(counts)
 
 
 def _corner_gather(src, idx_a, coef_a, coef_b):
@@ -182,10 +368,102 @@ def _corner_gather(src, idx_a, coef_a, coef_b):
     return g_a * coef_a + g_b * coef_b
 
 
+def _rows_read(cents, operands, levels, width, radius: int, query_tile: int):
+    """``(read, whole)`` 128-lane rows of the raw-volume ``operands`` a
+    lookup at ``cents`` ``(q, 2)`` reads: :func:`_plan_tile` and
+    :func:`_window_plan` as :func:`_invoke_xtap` runs them — per shard
+    under an ambient mesh that divides ``q``, as ``_partitioned_xtap``
+    splits the call."""
+    q = cents.shape[0]
+    mesh = jax.sharding.get_abstract_mesh()
+    shards = 1
+    if not mesh.empty and mesh.size > 1 and q % mesh.size == 0:
+        shards = mesh.size
+    raws = [x for x in operands if len(x.shape) == 3]
+    tq, _, heights, _ = _plan_tile(
+        q // shards, query_tile, operands, levels, width, radius
+    )
+    lane_rows = [_lanes(x) // MAX_LANES for x in raws]
+    whole = q * sum(x.shape[1] * n for x, n in zip(raws, lane_rows))
+    live = [
+        (w, n) for w, n in zip(_windows(raws, levels, heights), lane_rows)
+        if w is not None
+    ]
+    read = jnp.int32(whole)
+    if live:
+        # a windowed level: its windows' rows in place of its own
+        _, counts = _window_plan(
+            cents[:, 1].reshape(-1, tq), [w for w, _ in live], radius
+        )
+        a_window = jnp.asarray([tq * w.height * n for w, n in live], jnp.int32)
+        read = read - q * sum(w.rows * n for w, n in live) + jnp.sum(
+            counts * a_window[:, None]
+        )
+    return read, jnp.int32(whole)
+
+
+class _WindowRefs(NamedTuple):
+    """What a grid step needs to read windowed levels: the prefetched
+    ``starts`` and ``counts`` (``(windowed level, tile)``, flat; ``tiles``
+    a level), each windowed level once more in HBM (``anys``) with a
+    VMEM buffer one window large (``bufs``) and a DMA semaphore for the
+    windows past a tile's first, and the fp32 ``(T, S, lanes)``
+    accumulator ``t`` the windows' y-dots are summed in."""
+
+    starts: object
+    counts: object
+    anys: Sequence
+    t: object
+    bufs: Sequence
+    sem: object
+    tiles: int
+
+
+def _ydot_windows(ydot, vol_ref, win: _Window, refs: _WindowRefs, tq: int):
+    """The y-dot of a windowed level for this tile, fp32 ``(T, S, wl)``.
+
+    ``vol_ref`` is the tile's first window, brought in by the call's
+    pipeline like any block. The y-dot is linear in the rows, so a tile
+    whose taps reach past it (``counts > 1``: vertical flow varying
+    across the tile by more than the window leaves) adds the y-dots of
+    the windows that follow, each copied here from the level's alias in
+    HBM — not double-buffered: the cost of the rare case is in
+    proportion to its span, the whole level at worst. Window ``w`` owns
+    rows ``start + w * H`` on; the last is clamped to end inside the
+    level and gives the rows before its own no weight, so no row counts
+    twice. One window a tile is the cells' case and costs the store and
+    reload of the accumulator alone."""
+    i = pl.program_id(0)
+    at = win.index * refs.tiles + i
+    start, h = refs.starts[at], win.height
+    acc = refs.t.at[:, :, : vol_ref.shape[2]]
+    acc[...] = ydot(vol_ref, start)
+    any_ref, buf_ref = refs.anys[win.index], refs.bufs[win.index]
+
+    @pl.when(refs.counts[at] > 1)
+    def _():
+        def more(w, carry):
+            first = start + w * h
+            row0 = pl.multiple_of(jnp.minimum(first, win.rows - h), _ROW_TILE)
+            copy = pltpu.make_async_copy(
+                any_ref.at[pl.ds(i * tq, tq), pl.ds(row0, h), :], buf_ref,
+                refs.sem,
+            )
+            copy.start()
+            copy.wait()
+            acc[...] += ydot(buf_ref, row0, first)
+            return carry
+
+        jax.lax.fori_loop(1, refs.counts[at], more, 0)
+
+    return acc[...]
+
+
 def _write_taps(
     cents_ref, vol_refs, flat_refs, dst_ref, *,
     radius: int, ydot_levels, widths, flat_levels, flat_dims,
     ydot_offsets, flat_offsets, tq: int, cents_blocked: bool = False,
+    windows=(), win_refs=None,
 ):
     """Write one query tile of taps into ``dst_ref`` (the out ref, or the
     fp32 scratch of the projecting kernel), at the per-level column offsets
@@ -197,7 +475,13 @@ def _write_taps(
         RAW ``(T, hl, wl)`` volume; the y-contraction runs here as one
         batched MXU dot against bilinear y-weights built from iotas, then
         the 2-tap x-combine via lane gathers.
-        Block layout: j-major, ``off + j*S + i``.
+        Block layout: j-major, ``off + j*S + i``. A WINDOWED level
+        (``windows[k]`` rows, not ``None``) comes as the ``H`` rows from
+        this tile's first row (``win_refs``: the scalar-prefetched
+        starts and window counts) instead of the whole map: the
+        y-weights count rows from that start, and a tile whose taps
+        reach past one window adds the y-dots of as many more as it
+        needs, fetched here (:func:`_ydot_windows`).
       * flat levels (``flat_refs``, the small pooled levels): the level's
         whole (hl, wl) volume is packed as dense 128-lane rows and BOTH
         bilinear axes run here as lane gathers — no y-dot at all (a
@@ -225,9 +509,9 @@ def _write_taps(
     cx = cents_ref[rows, 0]  # (T,) f32 level-0 x
     cy = cents_ref[rows, 1]  # (T,) f32 level-0 y
 
-    for level, vol_ref, wl, off in zip(
+    for k, (level, vol_ref, wl, off) in enumerate(zip(
         ydot_levels, vol_refs, widths, ydot_offsets
-    ):
+    )):
         cxl = cx * (1.0 / (2.0**level))
         x0 = jnp.floor(cxl)
         fx = (cxl - x0).astype(jnp.float32)
@@ -272,23 +556,36 @@ def _write_taps(
         # rounded to the storage dtype and the fp32 accumulation is
         # rounded back to it, as the XLA form (corr.lookup_pyramid with
         # this weight_dtype) rounds its rows
-        hl = vol_ref.shape[1]
         cyl = (cy * (1.0 / (2.0**level))).astype(jnp.float32)
-        jj = jax.lax.broadcasted_iota(
-            jnp.int32, (tq, s, hl), 1
-        ).astype(jnp.float32)
-        yy = jax.lax.broadcasted_iota(
-            jnp.int32, (tq, s, hl), 2
-        ).astype(jnp.float32)
-        wy = jnp.maximum(
-            1.0 - jnp.abs(cyl[:, None, None] + (jj - radius) - yy), 0.0
-        )
-        vol = vol_ref[...]
-        t = jax.lax.dot_general(
-            wy.astype(vol.dtype), vol,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ).astype(vol.dtype)
+
+        def ydot(ref, row0=None, first=None, cyl=cyl):
+            """``ref``'s rows are the level's from ``row0``; rows before
+            ``first`` belong to an earlier window and get no weight."""
+            hl = ref.shape[1]
+            jj = jax.lax.broadcasted_iota(
+                jnp.int32, (tq, s, hl), 1
+            ).astype(jnp.float32)
+            yi = jax.lax.broadcasted_iota(jnp.int32, (tq, s, hl), 2)
+            if row0 is not None:
+                yi = yi + row0
+            yy = yi.astype(jnp.float32)
+            wy = jnp.maximum(
+                1.0 - jnp.abs(cyl[:, None, None] + (jj - radius) - yy), 0.0
+            )
+            if first is not None:
+                wy = jnp.where(yi >= first, wy, 0.0)
+            vol = ref[...]
+            return jax.lax.dot_general(
+                wy.astype(vol.dtype), vol,
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )
+
+        if windows and windows[k] is not None:
+            t = _ydot_windows(ydot, vol_ref, windows[k], win_refs, tq)
+        else:
+            t = ydot(vol_ref)
+        t = t.astype(vol_ref.dtype)
 
         for j in range(s):
             # fp32 before the gather (Mosaic's tpu.dynamic_gather has no
@@ -382,50 +679,53 @@ def _write_taps(
         dst_ref[:, off : off + nlanes] = acc[:, :nlanes].astype(dst_ref.dtype)
 
 
-def _xtap_kernel(
-    cents_ref, *refs, radius: int, ydot_levels, widths, flat_levels, flat_dims,
-    ydot_offsets, flat_offsets, cents_blocked: bool = False,
-):
-    """One query tile of taps.
+def _tile_taps(refs, *, project: bool, tiles: int, **static):
+    """Sort one grid step's ``refs`` and write the tile's taps.
 
-    refs = (vol_*, flat_*, out): vol_l is the RAW (T, hl, wl) volume block
-    of a y-dot level (the y-contraction runs here as a batched MXU dot);
-    flat_l is (T, rows*128) packed volume for the flat levels; out is
-    (T, c_scratch) taps in the :func:`_scratch_layout` column order.
-    """
-    out_ref = refs[-1]
-    nv = len(widths)
+    ``refs`` come as ``[starts, counts,] cents, [w, b,] vol_*, flat_*,
+    [any_*,] out, [acc,] [t, buf_*, sem]``: the bracketed window refs
+    only where a level is windowed, ``w``, ``b`` and the fp32 tap scratch
+    ``acc`` only in the projecting kernel. The taps go to ``acc`` there
+    and to ``out`` otherwise, in :func:`_scratch_layout`'s columns.
+    Returns ``(out, acc, w, b)``, ``(out, None)`` without the projection."""
+    nv, nf = len(static["widths"]), len(static["flat_levels"])
+    nw = sum(w is not None for w in static["windows"])
+    refs = list(refs)
+    pre = [refs.pop(0) for _ in range(2 if nw else 0)]
+    cents_ref = refs.pop(0)
+    wb = [refs.pop(0) for _ in range(2 if project else 0)]
+    vols = [refs.pop(0) for _ in range(nv)]
+    flats = [refs.pop(0) for _ in range(nf)]
+    anys = [refs.pop(0) for _ in range(nw)]
+    out_ref = refs.pop(0)
+    acc_ref = refs.pop(0) if project else None
+    win_refs = None
+    if nw:
+        t_ref, *bufs, sem = refs
+        win_refs = _WindowRefs(*pre, anys, t_ref, bufs, sem, tiles)
     _write_taps(
-        cents_ref, refs[:nv], refs[nv:-1], out_ref,
-        radius=radius, ydot_levels=ydot_levels, widths=widths,
-        flat_levels=flat_levels, flat_dims=flat_dims,
-        ydot_offsets=ydot_offsets, flat_offsets=flat_offsets,
-        tq=out_ref.shape[0], cents_blocked=cents_blocked,
+        cents_ref, vols, flats, acc_ref if project else out_ref,
+        tq=out_ref.shape[0], win_refs=win_refs, **static,
     )
+    return (out_ref, acc_ref, *wb)
 
 
-def _xtap_project_kernel(
-    cents_ref, w_ref, b_ref, *refs,
-    radius: int, ydot_levels, widths, flat_levels, flat_dims,
-    ydot_offsets, flat_offsets, mxu_dtype, cents_blocked: bool = False,
-):
+def _xtap_kernel(*refs, **static):
+    """One query tile of taps: out is (T, c_scratch) taps in the
+    :func:`_scratch_layout` column order. vol_l is the RAW (T, hl, wl)
+    volume block of a y-dot level — or its first (T, H, wl) window — (the
+    y-contraction runs here as a batched MXU dot); flat_l is (T,
+    rows*128) packed volume for the flat levels."""
+    _tile_taps(refs, **static)
+
+
+def _xtap_project_kernel(*refs, mxu_dtype, **static):
     """x-tap + ``convcorr1`` projection in one pass: the j-major taps land
     in an fp32 VMEM scratch, one (T, L*S*S) @ (L*S*S, C_out) MXU matmul +
     bias + relu emits the motion-encoder input directly — the tap tensor
-    never reaches HBM in reference layout.
-
-    refs = (vol_*, flat_*, out, acc): ``w_ref`` is the row-permuted
-    (j-major) projection matrix, ``b_ref`` the (1, C_out) bias.
-    """
-    out_ref, acc_ref = refs[-2], refs[-1]
-    nv = len(widths)
-    _write_taps(
-        cents_ref, refs[:nv], refs[nv:-2], acc_ref,
-        radius=radius, ydot_levels=ydot_levels, widths=widths,
-        flat_levels=flat_levels, flat_dims=flat_dims,
-        ydot_offsets=ydot_offsets, flat_offsets=flat_offsets,
-        tq=out_ref.shape[0], cents_blocked=cents_blocked,
-    )
+    never reaches HBM in reference layout. ``w`` is the row-permuted
+    (j-major) projection matrix, ``b`` the (1, C_out) bias."""
+    out_ref, acc_ref, w_ref, b_ref = _tile_taps(refs, **static)
     taps = acc_ref[...].astype(mxu_dtype)
     w = w_ref[...].astype(mxu_dtype)
     y = jax.lax.dot_general(
@@ -456,6 +756,7 @@ class _XtapStatic(NamedTuple):
     out_dtype: Optional[str]  # dtype *name* (dtype objects don't hash stably)
     query_tile: int
     interpret: bool
+    q_width: int  # queries an image row (the windows' rule)
     project: bool = False
     c_out: int = 0
     mxu_dtype: Optional[str] = None
@@ -465,21 +766,32 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
     """Build and run the x-tap pallas_call for this operand set's q.
 
     ``arrays`` order: ``cents, [w_mat, bias (project),] *vols, *flats``.
-    Shape-polymorphic in q only: the query tile, grid, and block specs
-    are derived here so the same static config serves both the global
-    trace and the per-shard call under a mesh (``_partitioned_xtap``
-    hands this q/n-row operands)."""
+    Shape-polymorphic in q only: the query tile, grid, block specs and
+    the windows' starts (:func:`_window_plan`, on THESE coordinates) are
+    derived here so the same static config serves both the global trace
+    and the per-shard call under a mesh (``_partitioned_xtap`` hands this
+    q/n-row operands)."""
     cents = arrays[0]
-    i = 1
-    if st.project:
-        w_mat, bias = arrays[1], arrays[2]
-        i = 3
+    head = arrays[1:3] if st.project else ()
     nv = len(st.widths)
-    vols, flats = arrays[i : i + nv], arrays[i + nv :]
+    vols = arrays[1 + len(head) : 1 + len(head) + nv]
+    flats = arrays[1 + len(head) + nv :]
 
     q = cents.shape[0]
-    tq, cents_blocked = _plan_tile(q, st.query_tile, [*vols, *flats])
+    tq, cents_blocked, heights, _ = _plan_tile(
+        q, st.query_tile, [*vols, *flats], st.ydot_levels, st.q_width,
+        st.radius,
+    )
     grid = -(-q // tq)
+    windows = _windows(vols, st.ydot_levels, heights)
+    live = [(v, w) for v, w in zip(vols, windows) if w is not None]
+    prefetch = ()
+    if live:
+        # microseconds of XLA before the call: a reduce over (grid, tq)
+        starts, counts = _window_plan(
+            cents[:, 1].reshape(grid, tq), [w for _, w in live], st.radius
+        )
+        prefetch = (starts.reshape(-1), counts.reshape(-1))
     if grid * tq != q:
         # non-divisible q (no 8-aligned divisor <= the tile): the last
         # block is masked by Pallas (OOB stores dropped, OOB operand rows
@@ -489,51 +801,71 @@ def _invoke_xtap(st: _XtapStatic, *arrays) -> jax.Array:
         radius=st.radius, ydot_levels=st.ydot_levels, widths=st.widths,
         flat_levels=st.flat_levels, flat_dims=st.flat_dims,
         ydot_offsets=st.ydot_offsets, flat_offsets=st.flat_offsets,
-        cents_blocked=cents_blocked,
+        cents_blocked=cents_blocked, windows=windows, tiles=grid,
+        project=st.project,
     )
+    # index maps take the grid index, then the prefetched scalars
     cents_spec = (
-        pl.BlockSpec((tq, 2), lambda i: (i, 0)) if cents_blocked
+        pl.BlockSpec((tq, 2), lambda i, *_: (i, 0)) if cents_blocked
         else pl.BlockSpec(memory_space=pltpu.VMEM)
     )
-    # raw (q, hl, wl) volumes and (q, rows*128) flats: blocked on dim 0
-    operand_specs = [
-        pl.BlockSpec((tq, v.shape[1], v.shape[2]), lambda i: (i, 0, 0))
-        for v in vols
-    ] + [pl.BlockSpec((tq, f.shape[1]), lambda i: (i, 0)) for f in flats]
-    out_dtype = jnp.dtype(st.out_dtype) if st.out_dtype else jnp.float32
-    params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
-    if not st.project:
-        kernel = functools.partial(_xtap_kernel, **static)
-        return pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((q, st.c_scratch), out_dtype),
-            grid=(grid,),
-            in_specs=[cents_spec] + operand_specs,
-            out_specs=pl.BlockSpec((tq, st.c_scratch), lambda i: (i, 0)),
-            interpret=st.interpret,
-            compiler_params=params,
-        )(cents, *vols, *flats)
 
-    body = functools.partial(
-        _xtap_project_kernel,
-        mxu_dtype=jnp.dtype(st.mxu_dtype) if st.mxu_dtype else jnp.float32,
-        **static,
+    def vol_spec(v, w):
+        if w is None:  # the whole (hl, wl) map, blocked on dim 0
+            return pl.BlockSpec(
+                (tq, v.shape[1], v.shape[2]), lambda i, *_: (i, 0, 0)
+            )
+        # H rows from the tile's first: element-indexed, so the row the
+        # block starts at is a scalar the pipeline reads before the step
+        return pl.BlockSpec(
+            (pl.Element(tq), pl.Element(w.height), pl.Element(v.shape[2])),
+            lambda i, starts, _, at=w.index * grid: (
+                i * tq, pl.multiple_of(starts[at + i], _ROW_TILE), 0
+            ),
+        )
+
+    in_specs = (
+        [cents_spec]
+        # w_mat and bias whole in VMEM, unblocked
+        + [pl.BlockSpec(memory_space=pltpu.VMEM) for _ in head]
+        + [vol_spec(v, w) for v, w in zip(vols, windows)]
+        + [pl.BlockSpec((tq, f.shape[1]), lambda i, *_: (i, 0)) for f in flats]
+        # a windowed level once more, left in HBM: windows past a tile's
+        # first are copied from it in the body
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in live]
     )
-    return pl.pallas_call(
-        body,
-        out_shape=jax.ShapeDtypeStruct((q, st.c_out), out_dtype),
-        grid=(grid,),
-        in_specs=[
-            cents_spec,  # whole where it fits, else blocked by tile
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # w_mat, unblocked
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # bias, unblocked
+    scratch = []
+    if st.project:
+        scratch.append(pltpu.VMEM((tq, st.c_scratch), jnp.float32))
+    if live:
+        wide = max(v.shape[2] for v, _ in live)
+        scratch.append(pltpu.VMEM((tq, 2 * st.radius + 1, wide), jnp.float32))
+        scratch += [
+            pltpu.VMEM((tq, w.height, v.shape[2]), v.dtype) for v, w in live
         ]
-        + operand_specs,
-        out_specs=pl.BlockSpec((tq, st.c_out), lambda i: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((tq, st.c_scratch), jnp.float32)],
+        scratch.append(pltpu.SemaphoreType.DMA(()))
+    out_dtype = jnp.dtype(st.out_dtype) if st.out_dtype else jnp.float32
+    c_out = st.c_out if st.project else st.c_scratch
+    kernel = functools.partial(_xtap_kernel, **static)
+    if st.project:
+        kernel = functools.partial(
+            _xtap_project_kernel,
+            mxu_dtype=jnp.dtype(st.mxu_dtype) if st.mxu_dtype else jnp.float32,
+            **static,
+        )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((q, c_out), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(grid,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((tq, c_out), lambda i, *_: (i, 0)),
+            scratch_shapes=scratch,
+        ),
         interpret=st.interpret,
-        compiler_params=params,
-    )(cents, w_mat, bias, *vols, *flats)
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+    )(*prefetch, cents, *head, *vols, *flats, *[v for v, _ in live])
 
 
 def _partition_dim0(mesh, dim0, q: int):
@@ -647,6 +979,7 @@ def lookup_pyramid_fused(
         out_dtype=jnp.dtype(weight_dtype).name if weight_dtype else None,
         query_tile=query_tile,
         interpret=interpret,
+        q_width=w,
         **prep.static,
     )
     out = _partitioned_xtap(st)(_flat_cents(centroids), *prep.operands)
@@ -877,6 +1210,7 @@ def lookup_project_fused(
         project=True,
         c_out=c_out,
         mxu_dtype=jnp.dtype(proj_dtype).name if proj_dtype else None,
+        q_width=w,
         **prep.static,
     )
     out = _partitioned_xtap(st)(
@@ -1095,17 +1429,44 @@ class FusedLookupCorrBlock(CorrBlock):
             list(levels), tuple(flats),
         )
 
-    def lookup_plan(self, pyramid):
-        """``(query tile, coordinates blocked?)`` the kernel picks for one
-        lookup of ``pyramid``: :func:`_plan_tile`, as :func:`_invoke_xtap`
-        calls it, on :meth:`kernel_rows`; ``(None, None)`` for the plain
-        levels of a shape the kernel does not run. Under a mesh the
-        kernel runs per shard: hand this the rows one device holds
-        (``serve.pool.state_layout`` does, for ``ServeEngine.stats()``)."""
+    def lookup_plan(self, pyramid, width: int):
+        """The :class:`_Plan` the kernel makes for one lookup of
+        ``pyramid`` by a query grid ``width`` wide — tile, coordinates
+        blocked?, rows of each raw-volume level a grid step reads at a
+        time: :func:`_plan_tile`, as :func:`_invoke_xtap` calls it, on
+        :meth:`kernel_rows`; ``None`` for the plain levels of a shape the
+        kernel does not run. Under a mesh the kernel runs per shard: hand
+        this the rows one device holds (``serve.pool.state_layout``
+        does, for ``ServeEngine.stats()``)."""
         if not isinstance(pyramid, dict):
-            return None, None
+            return None
         rows = self.kernel_rows(pyramid)
-        return _plan_tile(rows[0].shape[0], DEFAULT_QUERY_TILE, rows)
+        ydot_levels, _ = _split_levels(pyramid["levels"], 2 * self.radius + 1)
+        return _plan_tile(
+            rows[0].shape[0], DEFAULT_QUERY_TILE, rows, ydot_levels, width,
+            self.radius,
+        )
+
+    def lookup_rows(self, pyramid, centroids: jax.Array):
+        """``(read, whole)``, int32 scalars: the 128-lane rows of the
+        raw-volume levels ONE lookup of ``pyramid`` at ``centroids``
+        brings into VMEM, and what reading every level whole would take
+        — the same plan and the same window counts the call makes,
+        computed beside it (no kernel runs). ``read / whole`` is the
+        share of the y-dot levels the kernel read: the windows' heights
+        over the levels' rows where every tile fits one window, more
+        where the vertical flow spreads a tile's taps over several.
+        ``None`` for a pyramid the kernel does not run. Under a mesh the
+        call plans per shard, and so does this."""
+        levels, _ = self._unwrap(pyramid)
+        if not isinstance(pyramid, dict):
+            return None
+        rows = self.kernel_rows(pyramid)
+        ydot_levels, _ = _split_levels(levels, 2 * self.radius + 1)
+        return _rows_read(
+            _flat_cents(centroids), rows, ydot_levels, centroids.shape[2],
+            self.radius, DEFAULT_QUERY_TILE,
+        )
 
     def index_pyramid(self, pyramid, centroids: jax.Array) -> jax.Array:
         levels, flats = self._unwrap(pyramid)

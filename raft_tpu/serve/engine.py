@@ -1682,7 +1682,22 @@ class ServeEngine:
     def stats(self) -> dict:
         """Serving counters + degradation + per-bucket latency quantiles +
         hot-path efficiency (padding waste, encoder cache hit rate,
-        compiled-program counts)."""
+        compiled-program counts).
+
+        ``["pool"]["buckets"]`` says, per live bucket, what the slot
+        state holds and how the lookup kernel reads it
+        (``pool.state_layout``): ``state_bytes`` / ``slot_bytes``,
+        ``query_tile``, ``coords_blocked``, and per raw-volume level the
+        rows held resident (``level_rows``) and the rows a grid step
+        brings into VMEM at a time (``window_rows``; equal where the
+        level is read whole). ``lookup_rows_read`` /
+        ``lookup_rows_whole`` sum, over the ticks whose pacing token was
+        fetched, the 128-lane rows of those levels the kernel read and
+        what reading every level whole would have taken: their ratio is
+        the share of the y-dot levels the kernel read — the windows'
+        share of the levels' rows where every query tile's taps fit one
+        window, more where vertical flow spreads a tile's taps over
+        several (docs/observability.md, section 1)."""
         with self._lock:
             counters = dict(self._counters)
             latency = {
@@ -3013,6 +3028,8 @@ class ServeEngine:
             p2 = self._staging.fill(
                 ("pool_p2", pool.bucket), shape, [r.p2 for r in live], rung
             )
+            # one admission's rows on the device at a time
+            pool.await_rows()
             t0 = time.monotonic()
             self._trace_span(live, "batch_form", t_form, t0, rung=rung)
         return p1, p2, rung, t0
@@ -3108,6 +3125,7 @@ class ServeEngine:
                 ("pool_init", pool.bucket), ishape,
                 [rr[3] for rr in rows], rung2,
             )
+            pool.await_rows()
             t0 = time.monotonic()
         state_rows, tripped = self._guarded_dispatch(
             flow_reqs,
@@ -3246,7 +3264,7 @@ class ServeEngine:
         _, tok, occ = pool.pending.popleft()
         mask, tripped = self._guarded_dispatch(live, lambda: np.asarray(tok))
         now = time.monotonic()
-        pool.note_drain(now)
+        pool.note_drain(now, mask)
         with self._lock:
             self._batch_ms_ewma += 0.2 * (
                 pool.tick_ewma_ms - self._batch_ms_ewma

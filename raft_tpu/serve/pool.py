@@ -94,6 +94,7 @@ import jax.numpy as jnp
 __all__ = [
     "PoolPrograms", "BucketPool", "state_spec", "zero_state", "state_layout",
     "RESID_HISTORY", "RESID_SENTINEL", "unpack_converged",
+    "unpack_lookup_rows",
 ]
 
 # Default length of the rolling per-slot residual history. The engine
@@ -111,10 +112,27 @@ RESID_SENTINEL = 1e30
 
 def unpack_converged(packed, capacity: int):
     """Host-side inverse of the step program's ``jnp.packbits`` pacing
-    token: the per-slot converged bool vector for ``capacity`` slots."""
+    token: the per-slot converged bool vector for ``capacity`` slots
+    (the mask's bytes lead the token; what may follow them is
+    :func:`unpack_lookup_rows`')."""
     import numpy as np
 
     return np.unpackbits(np.asarray(packed, np.uint8))[:capacity].astype(bool)
+
+
+def unpack_lookup_rows(packed, capacity: int):
+    """``(read, whole)`` off a fetched pacing token, or ``None`` where the
+    step program appended none (a correlation block with no windowed
+    lookup): the two int32 counts of ``lookup_rows`` for that tick, as
+    eight little-endian bytes after the ``ceil(capacity / 8)`` bytes of
+    the converged mask."""
+    import numpy as np
+
+    tail = np.asarray(packed, np.uint8)[-(-capacity // 8):]
+    if tail.size != 8:
+        return None
+    read, whole = tail.view("<i4")
+    return int(read), int(whole)
 
 
 def forward_warp_flow(flow):
@@ -254,6 +272,23 @@ class PoolPrograms:
         from raft_tpu.parallel.mesh import traced_under
 
         apply = traced_under(mesh, model.apply)
+        lookup_rows = getattr(
+            getattr(model, "corr_block", None), "lookup_rows", None
+        )
+        if lookup_rows is not None:
+            block_rows = traced_under(mesh, lookup_rows)
+
+            def lookup_rows(pyramid, coords):
+                # iterate_step's view of the state: slots folded into rows
+                return block_rows(
+                    jax.tree.map(
+                        lambda v: v.reshape(
+                            (v.shape[0] * v.shape[1],) + v.shape[2:]
+                        ),
+                        pyramid,
+                    ),
+                    coords,
+                )
 
         def _with_hist(rows):
             # admission rows start with a sentinel-seeded residual
@@ -364,6 +399,19 @@ class PoolPrograms:
             # zero new host syncs. (A token also keeps the worker from
             # holding a buffer a later insert might donate.)
             token = jnp.packbits(converged.astype(jnp.uint8))
+            # ... and where the lookup kernel reads its levels by window,
+            # how many rows this tick's lookup read and what whole
+            # levels would have taken (unpack_lookup_rows): eight bytes
+            # more on the same fetch, summed on the host
+            if lookup_rows is not None:
+                rows = lookup_rows(state["pyramid"], state["coords1"])
+                if rows is not None:
+                    token = jnp.concatenate([
+                        token,
+                        jax.lax.bitcast_convert_type(
+                            jnp.stack(rows), jnp.uint8
+                        ).reshape(-1),
+                    ])
             return coords1, hidden, hist, converged, token
 
         self.step = jax.jit(
@@ -482,32 +530,43 @@ def state_layout(model, state) -> Dict[str, Any]:
     """What a pool's ``state`` costs and how the lookup kernel reads it,
     from shapes alone: bytes resident (``state_bytes``; ``slot_bytes`` a
     slot) and, where the state holds the fused block's packed pyramid,
-    the kernel's query tile and whether its coordinate operand is blocked
-    by tile (``None`` otherwise): the block's own ``lookup_plan``, which
-    is the plan the kernel call makes. The kernel plans from the rows ONE
-    device holds (under a mesh it runs per shard), so the plan is asked
-    for each leaf's shard shape."""
+    the kernel's query tile, whether its coordinate operand is blocked
+    by tile, and per raw-volume level the rows it holds resident
+    (``level_rows``) and the rows a grid step reads of them at a time
+    (``window_rows``; equal where the level is read whole) — ``None``
+    otherwise: the block's own ``lookup_plan``, which is the plan the
+    kernel call makes. The kernel plans from the rows ONE device holds
+    (under a mesh it runs per shard), so the plan is asked for each
+    leaf's shard shape. ``lookup_rows_read`` / ``lookup_rows_whole``
+    start at 0: :meth:`BucketPool.note_drain` sums what the ticks'
+    tokens report."""
     leaves = jax.tree_util.tree_leaves(state)
     capacity = int(leaves[0].shape[0])
     state_bytes = sum(
         int(x.size) * jnp.dtype(x.dtype).itemsize for x in leaves
     )
-    tile = blocked = None
-    plan = getattr(getattr(model, "corr_block", None), "lookup_plan", None)
-    if plan is not None:
+    plan = None
+    ask = getattr(getattr(model, "corr_block", None), "lookup_plan", None)
+    if ask is not None:
         def rows(v):
             shape = v.sharding.shard_shape(v.shape)
             return jax.ShapeDtypeStruct(
                 (shape[0] * shape[1],) + shape[2:], v.dtype
             )
 
-        tile, blocked = plan(jax.tree_util.tree_map(rows, state["pyramid"]))
-    return {
+        pyramid = jax.tree_util.tree_map(rows, state["pyramid"])
+        plan = ask(pyramid, state["coords1"].shape[2])
+    layout = {
         "state_bytes": state_bytes,
         "slot_bytes": state_bytes // capacity,
-        "query_tile": tile,
-        "coords_blocked": blocked,
+        "query_tile": plan and plan.tile,
+        "coords_blocked": plan and plan.coords_blocked,
+        "level_rows": plan and list(plan.rows),
+        "window_rows": plan and list(plan.heights),
     }
+    if plan:
+        layout.update(lookup_rows_read=0, lookup_rows_whole=0)
+    return layout
 
 
 class BucketPool:
@@ -564,11 +623,33 @@ class BucketPool:
         self.last_drain_t = None
         return metas
 
-    def note_drain(self, now: float) -> None:
+    def await_rows(self) -> None:
+        """Block until the last admission's rows are in their slots, so
+        that the buffer they were computed into is free before the next
+        admission's is allocated (a dispatch allocates its outputs when
+        it is enqueued, not when it runs). A row is the size of a slot:
+        3.3 GB at 1088x1920, where two admissions enqueued a tick apart
+        — both slots free while clients are still joining — held 13.4 GB
+        where 10.0 is the pool's peak, 92.7% of the chip with the
+        programs' reservation (builder's chip runs, PR 34; the same race
+        read as 16.3 GB in PR 33's). Behind a retirement the wait is
+        over before it starts: the retirement's fetch has drained the
+        device. The pyramid leaves are ``insert``'s own outputs, and no
+        tick replaces them."""
+        jax.block_until_ready(self.state["pyramid"])
+
+    def note_drain(self, now: float, token=None) -> None:
         """One pipeline drain completed: fold the drain-to-drain gap into
         the tick-time estimate (host loop rate == device tick rate at
         steady state; the clamp keeps a scheduling stall from blowing up
-        the EWMA)."""
+        the EWMA), and add the lookup's row counts the fetched ``token``
+        carries to the layout ``stats()`` reports."""
+        rows = None if token is None else unpack_lookup_rows(
+            token, self.capacity
+        )
+        if rows is not None:
+            self.layout["lookup_rows_read"] += rows[0]
+            self.layout["lookup_rows_whole"] += rows[1]
         if self.last_drain_t is not None:
             dt = (now - self.last_drain_t) * 1e3
             dt = min(dt, 10.0 * self.tick_ewma_ms)

@@ -118,7 +118,8 @@ def _project_args(block, batch, h8, w8):
         (47, 156),  # KITTI-pad 376x1248: chunked >128-lane gathers and the
                     # masked tail tile (7332 rows have no 8-aligned divisor)
         (136, 240),  # 1080p 1088x1920: three raw levels, 97 KB of blocks a
-                     # row, so 408-row tiles (640 of them: 170 MiB of VMEM)
+                     # row unpadded as the build leaves them (136 rows are
+                     # whole row tiles, so levels 0-1 are read by window)
     ],
     ids=["sintel-440x1024", "kitti-376x1248", "hd1080-1088x1920"],
 )
@@ -199,6 +200,66 @@ def test_lookup_xtap_reads_pool_state_in_place(one_chip, arch, bucket, held):
     assert not copies, copies
     one_slot_level0 = q * h8 * w8 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_slot_level0
+
+
+@pytest.mark.parametrize(
+    "arch,bucket,slots,iters,windows,parent",
+    [
+        # (argument, temporary, output) bytes of the parent's step program
+        # (PERF.md, PR 32: compiled here for a described v5e, no chip)
+        ("raft_large", (440, 1024), 16, 32, (640, (56, 32), (56, 32)),
+         (2_866_390_016, 117_085_184, 29_746_688)),
+        ("raft_small", (440, 1024), 16, 32, (640, (56,), (56,)),
+         (2_708_046_336, 116_730_368, 22_537_728)),
+        ("raft_large", (1088, 1920), 2, 20, (640, (32, 24, 40), (136, 72, 40)),
+         (6_595_431_424, 148_333_568, 17_271_296)),
+    ],
+    ids=["raft_large-sintel", "raft_small-sintel", "raft_large-hd1080"],
+)
+def test_step_program_reads_windows_in_the_parents_footprint(
+    one_chip, arch, bucket, slots, iters, windows, parent
+):
+    """The three cells' step programs since the lookup reads a level by
+    window where the window is under half of it (PR 34: levels 0 and 1
+    at 1088x1920, none at 440x1024): the resident state is the parent's to the byte (same
+    argument bytes: no level re-padded, nothing admission or ``insert``
+    has to learn), the temporaries stay within 10% of the parent's, and
+    there is one program with one Mosaic call in it — no second instance
+    under a conditional, no fallback program. That this compiles is the
+    proof that the tile the one VMEM rule picks with the window blocks
+    counted (640 rows at 1088x1920, where whole levels allowed 408) fits
+    the call's limit: Mosaic refuses a body that does not."""
+    from raft_tpu.serve.pool import PoolPrograms, state_spec
+
+    _, block, model, variables, _ = _serving_model(arch, bucket)
+    state = state_spec(model, variables, slots, bucket, resid_len=iters)
+    tile, heights, rows = windows
+    plan = block.lookup_plan(
+        jax.tree.map(
+            lambda v: jax.ShapeDtypeStruct(
+                (v.shape[0] * v.shape[1],) + v.shape[2:], v.dtype
+            ),
+            state["pyramid"],
+        ),
+        bucket[1] // 8,
+    )
+    assert (plan.tile, plan.heights, plan.rows) == (tile, heights, rows)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    count = jax.ShapeDtypeStruct((), jnp.int32)
+    progs = PoolPrograms(model, resid_len=iters)
+    compiled = progs.step.lower(
+        *_on(one_chip, (variables, state, scalar, count, count))
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " conditional(" not in text
+    mem = compiled.memory_analysis()
+    arg_bytes, temp_bytes, out_bytes = parent
+    assert mem.argument_size_in_bytes == arg_bytes
+    assert mem.temp_size_in_bytes <= 1.1 * temp_bytes
+    # the token carries the two row counts: eight bytes, under the
+    # allocator's granule
+    assert out_bytes <= mem.output_size_in_bytes <= out_bytes + 4096
 
 
 def test_hd1080_admission_fits_beside_the_pool(one_chip):

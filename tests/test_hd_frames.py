@@ -13,7 +13,12 @@ What 1080p frames change is shapes, so the rules under test read shapes:
     lanes (chunked gathers, lane padding) and whose rows are no multiple
     of 8 (row padding in the resident form) agrees with the benchmark's
     plain reference;
-  * ``stats()`` says what a slot holds and how the kernel reads it.
+  * ``stats()`` says what a slot holds and how the kernel reads it;
+  * (PR 34) a raw-volume level is read by a window of rows a query tile
+    where that is clearly less than the level: the windowed kernel is
+    the whole-level kernel bit for bit, at every border and where a
+    tile's taps spread over several windows, and the two row counters
+    say what was read.
 
 CPU, interpret mode, seeded random weights; the real widths are compiled
 for a described v5e in ``tests/test_chip_compile.py``.
@@ -45,36 +50,52 @@ def _resident_spec(block, slots, h8, w8):
 
 
 @pytest.mark.parametrize(
-    "h8,w8,slots,tile,blocked",
+    "h8,w8,slots,tile,blocked,heights",
     [
-        (55, 128, 1, 640, False),    # Sintel 440x1024, one pair
-        (55, 128, 16, 640, False),   # the Sintel cells' pool: as before PR 30
-        (55, 128, 32, 640, True),    # 110 MiB of coordinates: blocked
-        (47, 156, 1, 616, False),    # KITTI 376x1248: cdiv grid, as before
-        (46, 96, 8, 552, False),     # the training crop, batch 8: as before
-        (136, 240, 1, 408, True),    # one 1088x1920 pair
-        (136, 240, 2, 408, True),    # the 1080p cell's pool
-        (136, 240, 4, 408, True),
+        # Sintel 440x1024, one pair; the Sintel cells' pool; 110 MiB of
+        # coordinates at 32 slots: blocked. Whole levels: a window of 32
+        # of level 0's 56 rows is over half of it, and measured no gain
+        (55, 128, 1, 640, False, (56, 32)),
+        (55, 128, 16, 640, False, (56, 32)),
+        (55, 128, 32, 640, True, (56, 32)),
+        # KITTI 376x1248: cdiv grid with a masked tail, whole levels
+        (47, 156, 1, 616, False, (48, 24)),
+        # the training crop in the pool's resident form, batch 8
+        (46, 96, 8, 552, False, (48, 24)),
+        # 1088x1920: levels 0 and 1 by window, 33 KB of blocks a row
+        # where whole levels took 97 KB and a 408-row tile
+        (136, 240, 1, 640, False, (32, 24, 40)),
+        (136, 240, 2, 640, True, (32, 24, 40)),   # the 1080p cell's pool
+        (136, 240, 4, 640, True, (32, 24, 40)),
     ],
 )
-def test_tile_follows_the_blocks_bytes(h8, w8, slots, tile, blocked):
+def test_tile_follows_the_blocks_bytes(h8, w8, slots, tile, blocked, heights):
     """The plan for the pool's resident pyramid — ``lookup_plan``, which
     is ``_plan_tile`` on the operands ``_FusedPrep`` hands the call, the
     same two functions ``_invoke_xtap`` runs and ``stats()`` reports —
-    is the expected tile, and what the tile needs fits the VMEM limit."""
+    is the expected tile and windows, and what the tile needs fits the
+    VMEM limit with the window blocks counted."""
     block = FusedLookupCorrBlock(LEVELS, RADIUS, dtype=jnp.bfloat16)
     pyramid = _resident_spec(block, slots, h8, w8)
     rows = block.kernel_rows(pyramid)
     q = slots * h8 * w8
-    tq, is_blocked = block.lookup_plan(pyramid)
-    assert (tq, is_blocked) == (tile, blocked)
+    plan = block.lookup_plan(pyramid, w8)
+    tq = plan.tile
+    assert plan[:3] == (tile, blocked, heights)
+    assert plan.rows == tuple(r.shape[1] for r in rows[:len(heights)])
+    assert all(h % 8 == 0 or h == r for h, r in zip(heights, plan.rows))
     assert tq % 8 == 0 and tq <= lx.DEFAULT_QUERY_TILE
     if q % 8 == 0 and h8 * w8 % tq == 0:
         assert q % tq == 0  # a divisor where one exists: no masked tail
-    # double-buffered level blocks, the body's scratch, and the
-    # coordinates where whole, fit
-    need = tq * (2 * lx._row_bytes(rows) + lx._SCRATCH_LANE_BYTES * rows[0].shape[2])
-    if not is_blocked:
+    # double-buffered blocks (a windowed level's once more), the body's
+    # scratch, and the coordinates where whole, fit
+    need = tq * (
+        2 * lx._row_bytes(rows, heights)
+        + sum(h * r.shape[2] * 2 for h, r in zip(heights, rows) if h < r.shape[1])
+        + lx._SCRATCH_LANE_BYTES * rows[0].shape[2]
+    )
+    assert need == tq * lx._tile_row_bytes(rows, heights)
+    if not plan.coords_blocked:
         need += -(-q // tq) * tq * lx.MAX_LANES * 4
     assert need <= lx._VMEM_LIMIT
 
@@ -119,7 +140,7 @@ def test_blocked_coordinates_equal_whole_bitwise(monkeypatch, rng, h8, w8):
         LEVELS, RADIUS, dtype=jnp.bfloat16, interpret=True
     )
     pyramid = block.resident_pyramid(block.build_pyramid(f1, f2))
-    assert block.lookup_plan(pyramid)[1] is False
+    assert block.lookup_plan(pyramid, w8).coords_blocked is False
 
     def run():
         return np.asarray(
@@ -129,9 +150,8 @@ def test_blocked_coordinates_equal_whole_bitwise(monkeypatch, rng, h8, w8):
     whole = run()
     real_plan = lx._plan_tile
 
-    def blocked_plan(q, query_tile, operands):
-        tq, _ = real_plan(q, query_tile, operands)
-        return tq, True
+    def blocked_plan(*args):
+        return real_plan(*args)._replace(coords_blocked=True)
 
     monkeypatch.setattr(lx, "_plan_tile", blocked_plan)
     lx._partitioned_xtap.cache_clear()
@@ -141,6 +161,228 @@ def test_blocked_coordinates_equal_whole_bitwise(monkeypatch, rng, h8, w8):
     jax.clear_caches()
     assert np.isfinite(whole).all() and np.abs(whole).max() > 0
     np.testing.assert_array_equal(whole, blocked)
+
+
+# -- levels read by window (PR 34) ---------------------------------------------
+# A grid step brings in only the rows of a raw-volume level its query
+# tile's taps can reach: H rows from a start the call computes from the
+# coordinates, more windows where a tile's taps spread past one. The
+# pool's resident form (rows in whole tiles of 8) is what windows.
+
+# The plan windows a level only under half its rows (1088x1920: 32 of
+# 136, 24 of 72). These grids are cut to what the CPU interprets in
+# seconds, so the cases lift that one threshold (``WINDOWED``: every
+# level whose window is shorter than it) and compare with the same call
+# under the threshold at 0 (every level whole); nothing else differs.
+WINDOWED = 0.99
+
+WINDOW_GRIDS = {
+    # name: (h8, w8, radius, (tile, heights) at bf16 storage and WINDOWED)
+    # Sintel 440x1024: 32 of level 0's 56 rows, 24 of level 1's 32
+    "sintel": (55, 128, 4, (640, (32, 24))),
+    # KITTI 376x1248: 7332 rows have no 8-aligned divisor, the grid has a
+    # masked tail, and an element-indexed block may not run past the
+    # array: every level whole, whatever the threshold
+    "kitti-tail": (47, 156, 4, (616, (48, 24))),
+    # one row more: a divisor (624), 2 lane chunks, level 0 by window
+    "kitti-2chunks": (48, 156, 4, (624, (32, 24))),
+    # a cut of the 1088x1920 bucket: >128 lanes, three y-dot levels
+    # (level 2's 18x34 is 5 packed rows, over the 4 a flat level may
+    # have), levels 0 and 1 by window — by the plan's own threshold too
+    "hd-cut": (72, 136, 4, (576, (32, 24, 24))),
+    # raft_small's geometry: radius 3, level 0 the only y-dot level
+    "sintel-r3": (55, 128, 3, (640, (32,))),
+}
+
+WINDOW_COORDS = {
+    # the cells' case: small flows, one window a tile
+    "near": lambda rng, ys, xs: (
+        xs + rng.uniform(-3, 3, xs.shape), ys + rng.uniform(-3, 3, ys.shape)
+    ),
+    # at and far beyond every border: clamped starts, zero taps
+    "borders": lambda rng, ys, xs: (
+        rng.choice([-300.0, -4.5, -0.5, 0.0, xs.max() + 0.5, xs.max() + 5, 900.0], xs.shape)
+        + rng.uniform(-1, 1, xs.shape),
+        rng.choice([-250.0, -5.0, -1.0, 0.0, ys.max(), ys.max() + 4.5, 700.0], ys.shape)
+        + rng.uniform(-1, 1, ys.shape),
+    ),
+    # vertical flow that varies across a tile by more than a window holds
+    "spread": lambda rng, ys, xs: (
+        xs + rng.uniform(-2, 2, xs.shape),
+        ys + 0.45 * ys.max() * np.sin(xs / 5.0) + rng.uniform(-2, 2, ys.shape),
+    ),
+}
+
+
+def _window_case(rng, grid, coords, dtype):
+    h8, w8, radius, _ = WINDOW_GRIDS[grid]
+    f1 = jnp.asarray(rng.normal(size=(1, h8, w8, 8)), jnp.float32)
+    f2 = jnp.asarray(rng.normal(size=(1, h8, w8, 8)), jnp.float32)
+    block = FusedLookupCorrBlock(
+        4, radius, dtype=None if dtype == jnp.float32 else dtype,
+        interpret=True,
+    )
+    pyramid = block.resident_pyramid(block.build_pyramid(f1, f2))
+    xs, ys = np.meshgrid(np.arange(w8, dtype=np.float64), np.arange(h8, dtype=np.float64))
+    cx, cy = WINDOW_COORDS[coords](rng, ys, xs)
+    cents = jnp.asarray(np.stack([cx, cy], -1)[None], jnp.float32)
+    return block, pyramid, cents
+
+
+def _count_rows_by_hand(plan, cents, levels, radius, lanes):
+    """128-lane rows a lookup reads, counted tile by tile from the
+    coordinates with nothing of the kernel's: a window covers ``H`` rows
+    from the row tile at or before the tile's first tap row (clamped into
+    the level), and as many more follow as reach its last."""
+    cy = np.asarray(cents, np.float64).reshape(-1, 2)[:, 1]
+    q = cy.size
+    read = whole = 0
+    for level, rows, h, n_lanes in zip(levels, plan.rows, plan.heights, lanes):
+        whole += q * rows * n_lanes
+        if h == rows:
+            read += q * rows * n_lanes
+            continue
+        for t in range(q // plan.tile):
+            y = np.floor(cy[t * plan.tile:(t + 1) * plan.tile] / 2**level)
+            lo = int(np.clip(y.min() - radius, 0, rows - 1))
+            hi = int(np.clip(y.max() + radius + 1, 0, rows - 1))
+            start = min(lo - lo % 8, rows - h)
+            windows = 1
+            while start + windows * h <= hi:
+                windows += 1
+            read += windows * h * plan.tile * n_lanes
+    return read, whole
+
+
+WINDOW_CASES = (
+    # the storage every cell runs, at every grid and kind of coordinates
+    [(g, c, jnp.bfloat16) for g in WINDOW_GRIDS if g != "kitti-tail"
+     for c in WINDOW_COORDS]
+    # fp32 storage (the quality preset): the oracle at the borders, and
+    # several windows a tile
+    + [("sintel", "borders", jnp.float32), ("sintel", "spread", jnp.float32)]
+    + [("kitti-tail", "near", jnp.bfloat16), ("kitti-tail", "spread", jnp.float32)]
+)
+
+
+@pytest.mark.parametrize(
+    "grid,coords,dtype", WINDOW_CASES,
+    ids=[f"{g}-{c}-{jnp.dtype(d).name}" for g, c, d in WINDOW_CASES],
+)
+def test_windowed_lookup_equals_whole_levels(monkeypatch, rng, grid, coords, dtype):
+    """The lookup + projection with its levels read by window is, bit
+    for bit (to an ulp in fp32 storage where a tile takes several
+    windows), the one that reads them whole (the plan's windows replaced
+    by whole levels, nothing else), and agrees with the XLA oracle —
+    at every border, and where a tile takes several windows; and
+    ``lookup_rows`` counts what a count by hand from the coordinates
+    gives."""
+    from raft_tpu.models.corr import lookup_pyramid, project_taps
+    from tests.test_pallas import BF16_TOL
+
+    h8, w8, radius, (tile, heights) = WINDOW_GRIDS[grid]
+    block, pyramid, cents = _window_case(rng, grid, coords, dtype)
+    monkeypatch.setattr(lx, "_WINDOW_SHARE", WINDOWED)
+    plan = block.lookup_plan(pyramid, w8)
+    if dtype == jnp.bfloat16:
+        assert (plan.tile, plan.heights) == (tile, heights)
+    windowed = [h < r for h, r in zip(plan.heights, plan.rows)]
+    assert any(windowed) == (grid != "kitti-tail")
+    s = 2 * radius + 1
+    kernel = jnp.asarray(rng.normal(size=(1, 1, 4 * s * s, 24)) * 0.1, jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(24,)) * 0.1, jnp.float32)
+    proj = None if dtype == jnp.float32 else dtype
+
+    def run():
+        lx._partitioned_xtap.cache_clear()
+        jax.clear_caches()
+        return np.asarray(
+            block.index_project(pyramid, cents, kernel, bias, dtype=proj),
+            np.float32,
+        )
+
+    got = run()
+    read, whole = (int(x) for x in block.lookup_rows(pyramid, cents))
+    rows = block.kernel_rows(pyramid)
+    levels = lx._split_levels(pyramid["levels"], s)[0]
+    assert (read, whole) == _count_rows_by_hand(
+        plan, cents, levels, radius,
+        [-(-r.shape[2] // 128) for r in rows[:len(plan.rows)]],
+    )
+    if coords == "near" and any(windowed):
+        assert read < whole  # one window a tile
+    if coords == "spread" and any(windowed):
+        one_each = whole - sum(
+            h8 * w8 * (r - h) * -(-x.shape[2] // 128)
+            for h, r, x in zip(plan.heights, plan.rows, rows)
+        )
+        assert read > one_each  # some tile took more than one
+
+    monkeypatch.setattr(lx, "_WINDOW_SHARE", 0.0)  # every level whole
+    assert block.lookup_plan(pyramid, w8).heights == plan.rows
+    want = run()
+    monkeypatch.undo()
+    lx._partitioned_xtap.cache_clear()
+    jax.clear_caches()
+    assert np.isfinite(want).all() and np.abs(want).max() > 0.5
+    if dtype == jnp.bfloat16 or coords == "near":
+        np.testing.assert_array_equal(got, want)
+    else:
+        # fp32 storage, a tap's two rows in different windows: the sum
+        # of two rounded products where one dot fuses the second into
+        # the first (bf16 products are exact in fp32: no difference)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+    if coords != "borders" and grid != "kitti-tail":
+        return  # the oracle once a grid and dtype, at the hardest taps
+    levels_held = [v.astype(dtype) for v in pyramid["levels"]]
+    wd = None if dtype == jnp.float32 else dtype
+    oracle = project_taps(
+        lookup_pyramid(levels_held, cents, radius, weight_dtype=wd),
+        kernel, bias, dtype=wd,
+    )
+    tol = dict(rtol=1e-4, atol=1e-4) if wd is None else BF16_TOL
+    np.testing.assert_allclose(got, np.asarray(oracle, np.float32), **tol)
+
+
+def test_window_plan_stays_in_range_whatever_the_coordinates():
+    """Starts are row tiles inside ``[0, rows - H]`` and counts lie in
+    ``[1, ceil(rows / H)]`` for coordinates far outside the frame,
+    infinite or not a number: the DMA never leaves the level."""
+    cy = jnp.asarray([
+        [0.0, 1.0, 2.0, 3.0], [-1e9, 5.0, 6.0, 7.0], [1e9, 1e9, 1e9, 1e9],
+        [-jnp.inf, 0.0, 50.0, jnp.inf], [jnp.nan, 3.0, 4.0, 5.0],
+        [130.0, 131.0, 135.9, 136.5], [-40.0, -30.0, -20.0, -10.0],
+    ], jnp.float32)
+    windows = [
+        lx._Window(0, 0, 136, 32), lx._Window(1, 1, 72, 24),
+        lx._Window(2, 2, 40, 24),
+    ]
+    starts, counts = lx._window_plan(cy, windows, 4)
+    starts, counts = np.asarray(starts), np.asarray(counts)
+    for (_, level, rows, h), s, n in zip(windows, starts, counts):
+        assert (s % 8 == 0).all() and (s >= 0).all() and (s <= rows - h).all()
+        assert (n >= 1).all() and (n <= -(-rows // h)).all()
+    # a tile inside the frame: its first tap row's tile, one window
+    assert starts[0, 0] == 0 and counts[0, 0] == 1
+    assert starts[0, 5] == 104 and counts[0, 5] == 1   # 126 -> 120, clamped
+    # from row 0 to the last, or not a number: every window of the level
+    for tile in (3, 4):
+        assert starts[:, tile].tolist() == [0, 0, 0]
+        assert counts[:, tile].tolist() == [5, 3, 2]
+
+
+def test_lookup_rows_ride_the_pacing_token():
+    """The step program appends the tick's two row counts to the packed
+    converged mask; the host takes them off the same fetch."""
+    from raft_tpu.serve.pool import unpack_converged, unpack_lookup_rows
+
+    mask = np.packbits(np.array([1, 0, 1] + [0] * 14, np.uint8))  # 17 slots
+    rows = np.array([14_336 * 65_280, 2_000_000_000], "<i4")
+    token = np.concatenate([mask, rows.view(np.uint8)])
+    assert unpack_lookup_rows(token, 17) == (14_336 * 65_280, 2_000_000_000)
+    assert unpack_converged(token, 17).tolist() == [True, False, True] + [False] * 14
+    assert unpack_lookup_rows(mask, 17) is None  # a block with no windows
 
 
 # -- the pool on a wide, odd bucket against the plain reference ----------------
@@ -233,6 +475,35 @@ def test_pool_on_a_wide_odd_bucket_matches_the_reference(hd_engine):
         assert stats["flow_epe_mean_px"] < 0.08, stats
 
 
+def test_one_admission_row_on_the_device_at_a_time(hd_engine, monkeypatch):
+    """Two admissions a tick apart (both slots free, two pairs queued:
+    clients still joining) do not hold two rows: when ``pool_begin_pair``
+    is dispatched — its output row is allocated then, not when it runs —
+    the rows of the admission before are in their slots. At 1088x1920 a
+    row is 3.3 GB and the second one took the pool to 13.4 GB of the
+    chip's 16.9 (PERF.md, PR 34)."""
+    eng, _, _ = hd_engine
+    pool = eng._pools[BUCKET]
+    begun = []
+    real = eng._run_pool_begin
+
+    def begin(p1, p2):
+        leaves = jax.tree_util.tree_leaves(pool.state["pyramid"])
+        begun.append((pool.free_count(), all(x.is_ready() for x in leaves)))
+        return real(p1, p2)
+
+    monkeypatch.setattr(eng, "_run_pool_begin", begin)
+    from benchmarks import inputs
+
+    pairs = inputs.serve_pairs(2147483779, 4, IMAGE_HW)
+    reqs = eng.submit_many([{"image1": a, "image2": b} for a, b in pairs])
+    for r in reqs:
+        assert r.wait(600.0) and r.error is None, r.error
+    assert len(begun) == 4
+    assert [free for free, _ in begun[:2]] == [2, 1]  # consecutive loops
+    assert all(landed for _, landed in begun)
+
+
 def test_stats_report_the_slot_and_the_fetch(hd_engine):
     """``stats()``: per live bucket the bytes a slot and the state hold,
     the kernel's tile and whether its coordinates are blocked; and the
@@ -247,6 +518,15 @@ def test_stats_report_the_slot_and_the_fetch(hd_engine):
         2 * layout["slot_bytes"], abs=2
     )
     assert layout["query_tile"] % 8 == 0 and layout["coords_blocked"] is False
+    # level 0 (17 -> 24 rows) and level 1 (8 rows) are far too short for
+    # a window to save anything: read whole, and the two row counts the
+    # ticks' tokens carried are equal — a whole number of ticks' worth
+    # (a 128-lane row a query: 24 x 2 + 8)
+    assert layout["level_rows"] == layout["window_rows"] == [24, 8]
+    a_tick = 2 * q * (24 * 2 + 8)
+    assert layout["lookup_rows_read"] == layout["lookup_rows_whole"] > 0
+    assert layout["lookup_rows_whole"] % a_tick == 0
+    assert layout["lookup_rows_whole"] // a_tick <= s["pool"]["ticks"]
     flow_bytes = BUCKET[0] * BUCKET[1] * 2 * 4
     assert s["completed"] >= 3
     assert s["fetched_bytes"] >= s["completed"] * flow_bytes

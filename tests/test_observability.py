@@ -539,7 +539,8 @@ ENGINE_BOOT_KEYS = frozenset({
 })
 ENGINE_POOL_KEYS = frozenset({
     # PR 30: "buckets" = per live bucket slot_bytes / state_bytes /
-    # query_tile / coords_blocked (pool.state_layout)
+    # query_tile / coords_blocked; PR 34: level_rows / window_rows /
+    # lookup_rows_read / lookup_rows_whole (pool.state_layout)
     "buckets", "capacity", "mesh_devices", "occupancy", "occupied",
     "per_device_occupancy", "tick_ms_ewma", "ticks", "ttfd_p50_ms",
 })
