@@ -307,7 +307,7 @@ class RAFT(nn.Module):
         at the 1/8 grid) warm-starts the refinement: ``coords1`` is seeded
         at ``coords0 + init_flow`` instead of the zero-flow identity —
         RAFT's video-mode trick (Teed & Deng 2020) of initializing pair
-        (t, t+1) from the forward-warped flow of (t-1, t), which puts the
+        (t, t+1) from the forward-interpolated flow of (t-1, t), which puts the
         recurrence near its fixed point so far fewer iterations reach the
         same answer. Zeros (or ``None``) reproduce the cold start exactly.
         """

@@ -88,7 +88,11 @@ __all__ = [
 # pacing token, and stream admission (`pool_begin_features`) takes the
 # traced warm-start initial flow. A pre-ISSUE-12 (v2) artifact refuses
 # typed at load and the boot degrades to compile.
-ARTIFACT_VERSION = 3
+# v4 (ISSUE 36): stream sessions live in a device table — `encode`
+# returns its frames' finite flags, `stream_swap` / `stream_store_flow`
+# joined the set, and `pool_begin_features` / `iterate` take the
+# encoders' outputs in the dtype they are computed in.
+ARTIFACT_VERSION = 4
 
 ProgramKey = Tuple[Any, ...]  # (family, *shape dims[, iters])
 
@@ -200,8 +204,27 @@ def program_specs(engine) -> List[ProgramSpec]:
     stream = engine._encode is not None
 
     def encode_specs(x):
-        fm, cx = jax.eval_shape(engine._encode, var_specs, x)
+        fm, cx, _ = jax.eval_shape(engine._encode, var_specs, x)
         return _spec_of(fm), _spec_of(cx)
+
+    def stream_specs(bucket, r, fm, cx, c1=None):
+        """The session table's two programs at rung ``r``: operands are
+        the encode program's own outputs (their dtype, not float32) and
+        the host's index vectors."""
+        cache = engine._stream_cache
+        table = cache.table_spec(bucket)
+        h8, w8 = int(fm.shape[1]), int(fm.shape[2])
+        lanes = (_sds(r, dtype=jnp.int32), _sds(r, dtype=jnp.bool_))
+        out = [ProgramSpec(
+            ("stream_swap", r, h8, w8), cache.programs.swap,
+            (table, fm, cx, *lanes, _sds(r, dtype=jnp.bool_)), {},
+        )]
+        if c1 is not None and cache.programs.store_flow is not None:
+            out.append(ProgramSpec(
+                ("stream_store_flow", r, h8, w8), cache.programs.store_flow,
+                (table, c1, *lanes), {},
+            ))
+        return out
 
     if engine._pool_progs is not None:
         from raft_tpu.serve.pool import state_spec
@@ -268,6 +291,7 @@ def program_specs(engine) -> List[ProgramSpec]:
                         progs.begin_features,
                         (var_specs, fm, fm, cx, ifl), {},
                     ))
+                    specs.extend(stream_specs(bucket, r, fm, cx, row_c1))
         return specs
 
     for bucket in engine._router.buckets:
@@ -286,6 +310,7 @@ def program_specs(engine) -> List[ProgramSpec]:
                     ("encode", b, bh, bw), engine._encode, (var_specs, x), {},
                 ))
                 fm, cx = encode_specs(x)
+                specs.extend(stream_specs(bucket, b, fm, cx))
                 for iters in cfg.ladder:
                     specs.append(ProgramSpec(
                         ("iterate", b, int(fm.shape[1]), int(fm.shape[2]),
@@ -403,6 +428,10 @@ def fingerprint(engine) -> Dict[str, Any]:
         "pool_capacity": cfg.pool_capacity,
         "admit_ladder": tuple(engine._admit_ladder),
         "stream_enabled": engine._encode is not None,
+        # with warm start the retirement's stream_store_flow is in the set
+        "stream_warm_start": bool(
+            engine._encode is not None and engine._warm_start
+        ),
         "precision": cfg.precision,
         "compute_dtype": cfg.compute_dtype,
         "corr_dtype": cfg.corr_dtype,

@@ -81,17 +81,23 @@ class ServeConfig:
             required before a slot counts as converged (default 2 — a
             single small update can be a plateau, not a fixed point).
             Must fit the residual history (``<= ladder[0]``).
-        stream_warm_start: seed each stream pair's refinement with the
-            forward-warped final flow of the previous pair (RAFT's
-            video-mode warm start) instead of the zero-flow cold start.
-            Warm-started requests enter near the fixed point, so with
-            ``pool_converge_thresh`` set they retire in a fraction of
-            the iteration ladder — the two mechanisms multiply exactly
-            where the stream feature cache already halved encoder cost.
-            The warm-start flow is a traced input of the (unchanged)
-            admission program — zeros when off or un-primed, so the
-            cold path is bitwise identical. Default off (gated like the
-            threshold); pool mode only (the fallback engine ignores it).
+        stream_warm_start: start each stream pair's refinement from the
+            session's previous pair, as upstream's video mode does
+            (princeton-vl/RAFT ``forward_interpolate``): the points
+            ``p + flow[p]`` of the last pair's final 1/8-grid flow that
+            land strictly inside the frame, and every cell the flow of
+            the nearest of them (zeros if none lands). The flow is
+            written into the session's row of the device-resident cache
+            by the program that retires the pair and interpolated on the
+            device by the next admission (``stream_swap``); nothing of it
+            crosses the host. Warm-started requests enter near the fixed
+            point, so with ``pool_converge_thresh`` set they retire in a
+            fraction of the iteration ladder. The start is a traced input
+            of the admission program — zeros when off or un-primed, so
+            the cold path is bitwise identical. Default off (gated like
+            the threshold); pool mode only (the fallback engine ignores
+            it). A session never warm-starts across a dropped, expired
+            or failed frame.
         max_batch: micro-batch size cap — for the ``pool_capacity=0``
             fallback engine this is the whole-request micro-batch bound;
             for the pool it bounds how many queued requests are encoded
@@ -132,10 +138,17 @@ class ServeConfig:
             the worker drains before dispatching ahead, so flood p99 and
             shed behavior are depth-independent (as are deadline,
             degradation, and quarantine semantics).
-        stream_cache_size: LRU bound on cached stream sessions (per-stream
-            frame feature/context maps for the encode-once stream path);
-            0 disables stream serving entirely (stream programs are then
-            neither compiled nor warmed).
+        stream_cache_size: rows of the device-resident stream cache
+            (:mod:`raft_tpu.serve.stream_cache`), and the LRU bound on
+            remembered sessions: one table a bucket, allocated at boot,
+            a row a session — its last frame's feature map and context
+            output in the encoders' dtype and its last pair's 1/8-grid
+            flow (7.3 MB at 440x1024 with raft_large in bf16, 33 MB at
+            1088x1920). Size it to the live sessions: one beyond the
+            bound loses its row and primes again. ``stats()`` reports
+            ``stream_sessions`` and the bytes their rows hold
+            (``stream_cache_bytes``). 0 disables stream serving entirely
+            (stream programs are then neither compiled nor warmed).
         max_wait_ms: how long the batch thread waits for stragglers after
             the first request of a batch arrives (capped by that request's
             own deadline slack — the queue never dawdles past a deadline).

@@ -72,9 +72,18 @@ throughput rework:
     (:meth:`ServeEngine.open_stream`) encode each video frame once and
     reuse frame t's feature/context maps as pair (t, t+1)'s first-frame
     inputs (``RAFT.encode_frame`` / ``RAFT.iterate``), roughly halving
-    encoder FLOPs on streams. Sessions are LRU-bounded
-    (``stream_cache_size``); any dropped/failed frame invalidates its
-    session so the next frame re-primes rather than pairing across a gap.
+    encoder FLOPs on streams. The cache is on the device
+    (:mod:`raft_tpu.serve.stream_cache`): a table of ``stream_cache_size``
+    rows a bucket holds each session's last frame's features in the
+    dtype the encoders compute them in, and its last pair's 1/8-grid
+    flow; the host keeps the index (which session holds which row, LRU)
+    and sends frames and row numbers only — between a frame's arrival
+    and its pair's ``insert`` nothing is fetched. Any dropped/failed
+    frame invalidates its session so the next frame re-primes rather
+    than pairing across a gap; with ``stream_warm_start`` a pair starts
+    from upstream's ``forward_interpolate`` of the session's last flow,
+    computed on the device. Both engines share the cache (this one's
+    stream batches run ``encode`` -> ``stream_swap`` -> ``iterate``).
 
 Boot pays as little as possible (ISSUE 7, :mod:`raft_tpu.serve.aot`):
 warmup is compile-only AOT lowering (concurrent, no forward passes on
@@ -103,10 +112,10 @@ import collections
 import dataclasses
 import threading
 import time
-from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu.inference import FlowEstimator
@@ -138,6 +147,7 @@ from raft_tpu.serve.pool import (
     state_layout,
     zero_state,
 )
+from raft_tpu.serve.stream_cache import StreamCache, encode_frame_program
 from raft_tpu.serve.qos import (
     QosPolicy,
     QosStats,
@@ -203,6 +213,11 @@ class ServeResult:
     # edge cache never caches them
     tiled: bool = False
     tiles: int = 0
+    # the 1/8-grid flow ``flow`` was upsampled from (``coords1 - coords0``
+    # on the bucket's grid, ``(bh/8, bw/8, 2)``; upstream's ``flow_low``),
+    # for callers that asked (``StreamSession.submit(return_flow8=True)``,
+    # pool mode): what the session's next pair warm-starts from
+    flow8: Optional[np.ndarray] = None
 
     @property
     def early_exit(self) -> bool:
@@ -210,25 +225,6 @@ class ServeResult:
         request stopped before its own target (deadline- or
         convergence-driven)."""
         return self.exit_reason in ("deadline", "converged")
-
-
-class _StreamState:
-    """Worker-side cache entry for one stream session (LRU-bounded)."""
-
-    __slots__ = ("sid", "bucket", "hw", "fmap", "ctx", "busy", "flow8")
-
-    def __init__(self, sid: int, bucket: Tuple[int, int], hw: Tuple[int, int]):
-        self.sid = sid
-        self.bucket = bucket
-        self.hw = hw
-        self.fmap: Optional[np.ndarray] = None   # (1, h/8, w/8, Cf)
-        self.ctx: Optional[np.ndarray] = None    # (1, h/8, w/8, Cc)
-        self.busy = False                        # one in-flight frame per stream
-        # warm start (ISSUE 12): the previous pair's FINAL 1/8-grid flow,
-        # cached alongside the frame features; forward-warped at the next
-        # admission to seed coords1 near the fixed point. Invalidated
-        # with the features — a stream never warm-starts across a gap.
-        self.flow8: Optional[np.ndarray] = None  # (h/8, w/8, 2)
 
 
 class StreamSession:
@@ -254,12 +250,15 @@ class StreamSession:
         trace_ctx: Optional[TraceContext] = None,
         priority: Optional[str] = None,
         tenant: Optional[str] = None,
+        return_flow8: bool = False,
     ) -> ServeResult:
         kw = {} if trace_ctx is None else {"trace_ctx": trace_ctx}
         if priority is not None:
             kw["priority"] = priority
         if tenant is not None:
             kw["tenant"] = tenant
+        if return_flow8:
+            kw["return_flow8"] = True
         return self._engine.submit_frame(
             self.stream_id, frame, deadline_ms=deadline_ms,
             num_flow_updates=num_flow_updates, **kw,
@@ -285,11 +284,22 @@ class _Inflight:
     t0: float
     flow_dev: Any
     kind: str                                   # 'pair' | 'stream'
-    # stream only: per-request (fmap1, fmap2, ctx, init_flow) rows for
-    # singles retry (init_flow unused on the fallback iterate path)
-    retry_rows: Optional[
-        List[Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]]
-    ] = None
+    # stream only: ``live[j]``'s row of ``flow_dev`` (a stream batch keeps
+    # its primes' lanes), and the batch's (fmap1, fmap2, ctx) device
+    # arrays for the singles retry
+    lanes: Optional[List[int]] = None
+    retry_rows: Optional[Tuple[Any, Any, Any]] = None
+
+
+def _coords0(shape) -> np.ndarray:
+    """The identity coordinates of an ``(h8, w8, 2)`` grid, (x, y) last:
+    what ``coords1`` starts from, so ``coords1 - _coords0`` is the flow."""
+    h8, w8 = shape[0], shape[1]
+    ys, xs = np.meshgrid(
+        np.arange(h8, dtype=np.float32), np.arange(w8, dtype=np.float32),
+        indexing="ij",
+    )
+    return np.stack([xs, ys], axis=-1)
 
 
 # engine trace ring: a 6 s traced window of the busiest cell finishes
@@ -506,10 +516,10 @@ class ServeEngine:
         # The whole-request iterate program only exists in fallback mode —
         # pooled stream pairs refine through the slot-wise step program.
         self._encode = self._iterate = None
+        self._stream_cache: Optional[StreamCache] = None
         if cfg.stream_cache_size > 0:
             self._encode = jax.jit(
-                partial(apply, train=False, method="encode_frame"),
-                **_sh("rep", "row"),
+                encode_frame_program(apply), **_sh("rep", "row")
             )
             if cfg.pool_capacity == 0:
                 def _iterate_fwd(variables, f1, f2, ctx, num_flow_updates):
@@ -522,11 +532,16 @@ class ServeEngine:
                     _iterate_fwd, static_argnums=(4,),
                     **_sh("rep", "row", "row", "row"),
                 )
-        self._streams: "collections.OrderedDict[int, _StreamState]" = (
-            collections.OrderedDict()
-        )
-        self._streams_lock = threading.Lock()
-        self._next_sid = 0
+            self._stream_cache = StreamCache(
+                cfg.stream_cache_size, self._warm_start,
+                row_spec=self._stream_row_spec, count=self._count,
+                mesh=self._mesh,
+            )
+        # stream primes wait here for their frame's finite flag: (flag
+        # array, [(lane, request)], iters, level) an admission cohort,
+        # settled by the pool worker once the encode that computes the
+        # flag has run (_stream_settle)
+        self._stream_checks: "collections.deque[Tuple]" = collections.deque()
         self._lock = threading.Lock()
         # Observability spine (ISSUE 10): the unified metrics registry,
         # the per-request tracer, and the fault flight recorder. The
@@ -938,10 +953,9 @@ class ServeEngine:
             np.asarray(self._run_batch(z, z, iters))
             self._boot["smoke_runs"] += 1
             if self._encode is not None:
-                fm, cx = self._run_encode(z)
-                zf = np.zeros(fm.shape, np.float32)
-                zc = np.zeros(cx.shape, np.float32)
-                np.asarray(self._run_iterate(zf, zf, zc, iters))
+                fm, cx, _ = self._run_encode(z)
+                f1, c1, _ = self._smoke_swap(bucket, fm, cx)
+                np.asarray(self._run_iterate(f1, fm, c1, iters))
                 self._boot["smoke_runs"] += 1
 
     def _smoke_pool(self) -> None:
@@ -967,17 +981,31 @@ class ServeEngine:
             np.asarray(self._run_pool_final(c1, hid))
             self._boot["smoke_runs"] += 1
             if self._encode is not None:
-                fm, cx = self._run_encode(z)
-                zf = np.zeros(fm.shape, np.float32)
-                zc = np.zeros(cx.shape, np.float32)
-                zi = np.zeros(tuple(fm.shape[:3]) + (2,), np.float32)
-                srows = self._run_pool_begin_features(zf, zf, zc, zi)
+                # the stream admission's chain, on the operands it runs
+                # on: the encoders' own outputs, in their own dtype
+                fm, cx, _ = self._run_encode(z)
+                f1, c1, ifl = self._smoke_swap(bucket, fm, cx)
+                srows = self._run_pool_begin_features(f1, fm, c1, ifl)
                 pool.state = self._pool_insert(
                     pool.state, srows,
                     np.zeros((r,), np.int32),
                     np.asarray([True] + [False] * (r - 1), bool),
                 )
+                if self._warm_start:
+                    self._run_stream_store_flow(
+                        bucket, srows["coords1"], np.zeros((r,), np.int32),
+                        np.zeros((r,), bool),
+                    )
                 self._boot["smoke_runs"] += 1
+
+    def _smoke_swap(self, bucket, fm, cx):
+        """The smoke run's ``stream_swap``: every lane masked off, so the
+        table (allocated here, at boot) stays zeros."""
+        r = int(fm.shape[0])
+        return self._run_stream_swap(
+            bucket, fm, cx, np.zeros((r,), np.int32), np.zeros((r,), bool),
+            np.zeros((r,), bool),
+        )
 
     # -- public API --------------------------------------------------------
 
@@ -1467,9 +1495,7 @@ class ServeEngine:
             raise InvalidInput(
                 "stream serving is disabled (stream_cache_size=0)"
             )
-        with self._streams_lock:
-            sid = self._next_sid
-            self._next_sid += 1
+        sid = self._stream_cache.open()
         return StreamSession(self, sid)
 
     def submit_frame(
@@ -1483,6 +1509,7 @@ class ServeEngine:
         priority: Optional[str] = None,
         tenant: Optional[str] = None,
         shadow: bool = False,
+        return_flow8: bool = False,
     ) -> ServeResult:
         """Advance stream ``stream_id`` by one frame.
 
@@ -1492,7 +1519,9 @@ class ServeEngine:
         invalidation/eviction). One outstanding frame per stream.
         ``trace_ctx`` joins an externally-sampled trace, and ``priority``
         / ``tenant`` classify the request for QoS, exactly as in
-        :meth:`submit`.
+        :meth:`submit`. ``return_flow8`` asks for the pair's 1/8-grid flow
+        beside the full one (``ServeResult.flow8``: 56 KB more on the
+        retirement's fetch at 440x1024; the iteration pool only).
         """
         if self._encode is None:
             raise InvalidInput(
@@ -1512,24 +1541,9 @@ class ServeEngine:
                 f"{list(self._router.buckets)}); streams have no slow path "
                 f"— resize or reconfigure"
             )
-        with self._streams_lock:
-            st = self._streams.get(stream_id)
-            if st is None:
-                st = _StreamState(stream_id, bucket, hw)
-                self._streams[stream_id] = st
-                self._evict_streams_locked()
-            self._streams.move_to_end(stream_id)
-            if st.busy:
-                raise InvalidInput(
-                    f"stream {stream_id} already has a frame in flight; "
-                    f"streams are strictly ordered — submit sequentially"
-                )
-            if st.bucket != bucket or st.hw != hw:
-                # resolution change mid-stream: re-prime rather than pair
-                # frames across different buckets
-                st.fmap = st.ctx = None
-                st.bucket, st.hw = bucket, hw
-            st.busy = True
+        refusal = self._stream_cache.begin_frame(stream_id, bucket, hw)
+        if refusal is not None:
+            raise InvalidInput(refusal)
         req = None
         rel = None
         try:
@@ -1543,6 +1557,7 @@ class ServeEngine:
                 deadline, kind="stream", stream_id=stream_id, iters=iters,
                 priority=pr, tenant=ten, shadow=shadow,
             )
+            req.want_flow8 = bool(return_flow8)
             req.trace = self.tracer.start(
                 "stream", rid, t_start=t_sub,
                 trace_id=None if trace_ctx is None else trace_ctx.trace_id,
@@ -1557,8 +1572,7 @@ class ServeEngine:
         finally:
             if rel is not None:
                 rel()  # one-shot: covers the shed path (req unfinished)
-            with self._streams_lock:
-                st.busy = False
+            self._stream_cache.end_frame(stream_id)
             if (
                 trace_ctx is not None
                 and req is not None
@@ -1568,8 +1582,8 @@ class ServeEngine:
 
     def close_stream(self, stream_id: int) -> None:
         """Drop a stream session and its cached features."""
-        with self._streams_lock:
-            self._streams.pop(stream_id, None)
+        if self._stream_cache is not None:
+            self._stream_cache.close(stream_id)
 
     def health(self) -> dict:
         """Liveness/readiness for an external supervisor or LB probe."""
@@ -1834,6 +1848,13 @@ class ServeEngine:
             "encoder_cache_hit_rate": (
                 hits / (hits + misses) if (hits + misses) else None
             ),
+            # the device-resident session cache: frames admitted through
+            # it, sessions remembered, bytes of table rows they hold
+            "stream_frames": hits + misses,
+            **(
+                self._stream_cache.stats() if self._stream_cache is not None
+                else {"stream_sessions": 0, "stream_cache_bytes": 0}
+            ),
             "batch_ladder": list(self._batch_ladder),
             "programs": self.program_counts(),
             "degradation": self._controller.snapshot(),
@@ -1927,13 +1948,15 @@ class ServeEngine:
             "encode": n(self._encode) + overlay.get("encode", 0),
             "iterate": n(self._iterate) + overlay.get("iterate", 0),
         }
+        families = {}
         if self._pool_progs is not None:
-            counts.update(
-                {
-                    name: cnt + overlay.get(name, 0)
-                    for name, cnt in self._pool_progs.counts().items()
-                }
-            )
+            families.update(self._pool_progs.counts())
+        if self._stream_cache is not None:
+            families.update(self._stream_cache.programs.counts())
+        counts.update(
+            {name: cnt + overlay.get(name, 0)
+             for name, cnt in families.items()}
+        )
         return counts
 
     # -- admission ---------------------------------------------------------
@@ -2440,13 +2463,16 @@ class ServeEngine:
 
     def _dispatch_stream(self, live: List[Request]) -> Optional[_Inflight]:
         """Stream batch: encode the new frames (one program per rung),
-        transact each session's feature cache, then dispatch the iterate
-        stage for the requests that had a cached previous frame.
+        swap them into the session cache against the sessions' previous
+        frames (``stream_swap``, on the device), then dispatch the
+        iterate stage for the requests that had a cached previous frame.
 
-        The encode stage is fetched synchronously (its outputs feed the
-        host-side cache); the iterate stage — the dominant FLOPs, 12-32
-        GRU refinements — is what pipelines against the next batch.
-        """
+        Only the frames' finite flags come back to the host here (a few
+        bytes; this worker has no later fetch to put a prime's answer
+        on); the iterate stage — the dominant FLOPs, 12-32 GRU
+        refinements — is what pipelines against the next batch. It runs
+        at the encode's rung, prime lanes included: their rows are not
+        read."""
         bucket = live[0].bucket
         iters, level = self._observe(live)
         iters, level = self._qos_levels(live, iters, level)
@@ -2463,42 +2489,67 @@ class ServeEngine:
         t0 = time.monotonic()
 
         def run_encode():
-            fm, cx = self._run_encode(frames)
-            return np.asarray(fm), np.asarray(cx)
+            fm, cx, finite = self._run_encode(frames)
+            co = self._stream_cache.plan(live, rung)
+            f1, c1, _ = self._run_stream_swap(
+                bucket, fm, cx, co.idx, co.put, co.warm
+            )
+            return fm, f1, c1, co, np.asarray(finite)
 
-        (fmap_np, ctx_np), tripped = self._guarded_dispatch(live, run_encode)
+        out, tripped = self._guarded_dispatch(live, run_encode)
         if tripped:
+            for r in live:
+                self._invalidate_stream(r.stream_id)
             return None
+        fm, f1, c1, co, finite = out
         self._trace_span(live, "encode", t0, rung=rung)
-        flow_reqs, retry_rows = self._stream_transact(
-            live, fmap_np, ctx_np, iters, level
-        )
-        if not flow_reqs:
+        self._count_stream_frames(co)
+        self._settle_primes(co.primes, finite, iters, level)
+        pairs = []
+        for lane, r in co.pairs:
+            if finite[lane]:
+                pairs.append((lane, r))
+            else:
+                self._poisoned_frame(r)
+        if not pairs:
             return None
-        rung2 = self._rung(len(flow_reqs))
-        fshape = (self._max_batch,) + fmap_np.shape[1:]
-        cshape = (self._max_batch,) + ctx_np.shape[1:]
-        f1 = self._staging.fill(
-            ("f1", bucket), fshape, [rr[0] for rr in retry_rows], rung2
-        )
-        f2 = self._staging.fill(
-            ("f2", bucket), fshape, [rr[1] for rr in retry_rows], rung2
-        )
-        cx = self._staging.fill(
-            ("ctx", bucket), cshape, [rr[2] for rr in retry_rows], rung2
-        )
-        self._note_padding(rung2, len(flow_reqs))
+        flow_reqs = [r for _, r in pairs]
         t_d = time.monotonic()
         flow_dev, tripped = self._guarded_dispatch(
-            flow_reqs, lambda: self._run_iterate(f1, f2, cx, iters)
+            flow_reqs, lambda: self._run_iterate(f1, fm, c1, iters)
         )
         if tripped:
             return None
         self._trace_span(flow_reqs, "dispatch", t_d, iters=iters)
         return _Inflight(
             flow_reqs, iters, level, t0, flow_dev, "stream",
-            retry_rows=retry_rows,
+            lanes=[lane for lane, _ in pairs], retry_rows=(f1, fm, c1),
         )
+
+    def _count_stream_frames(self, co) -> None:
+        with self._lock:
+            self._counters["encode_cache_hits"] += len(co.pairs)
+            self._counters["encode_cache_misses"] += len(co.primes)
+            self._counters["stream_primes"] += len(co.primes)
+            self._counters["stream_warm_starts"] += int(co.warm.sum())
+        for lane, r in co.pairs:
+            r.warm = bool(co.warm[lane])
+
+    def _settle_primes(self, primes, finite, iters, level) -> None:
+        """Answer a cohort's primes once their frames' finite flags are
+        on the host: ``primed``, or poisoned."""
+        for lane, r in primes:
+            if finite[lane]:
+                self._finish_ok(r, None, iters, level=level, primed=True)
+            else:
+                self._poisoned_frame(r)
+
+    def _poisoned_frame(self, r: Request) -> None:
+        """The encoders made non-finite features of this frame: it is
+        quarantined and its session forgets it — never paired with the
+        next frame."""
+        self._quarantine(r)
+        self._invalidate_stream(r.stream_id)
 
     def _complete(self, inf: _Inflight) -> None:
         """Fetch one in-flight batch's flow and finish its requests."""
@@ -2513,7 +2564,10 @@ class ServeEngine:
             self._batch_ms_ewma += 0.2 * (batch_ms - self._batch_ms_ewma)
         if tripped:
             return  # requests already failed (and the trip counted)
-        flows = [self._request_flow(r, flow[i]) for i, r in enumerate(inf.live)]
+        lanes = inf.lanes if inf.lanes is not None else range(len(inf.live))
+        flows = [
+            self._request_flow(r, flow[i]) for i, r in zip(lanes, inf.live)
+        ]
         if all(np.isfinite(f).all() for f in flows):
             for r, f in zip(inf.live, flows):
                 self._finish_ok(r, f, inf.iters, level=inf.level)
@@ -2559,17 +2613,18 @@ class ServeEngine:
         finite — the poison appeared in the flow), but a stream that just
         failed a frame should re-prime, not pair across the failure.
         """
-        for r, (f1, f2, cx, _ifl) in zip(inf.live, inf.retry_rows or []):
+        for r, lane in zip(inf.live, inf.lanes):
             if r.done:
                 continue
             t_r = time.monotonic()
             try:
-                f = np.asarray(
-                    self._run_iterate(
-                        self._pad_rows(f1), self._pad_rows(f2),
-                        self._pad_rows(cx), inf.iters,
-                    )
+                # a fault path: the lane's rows cross the host on their
+                # way to the smallest rung
+                f1, f2, cx = (
+                    self._pad_rows(np.asarray(a[lane:lane + 1]))
+                    for a in inf.retry_rows
                 )
+                f = np.asarray(self._run_iterate(f1, f2, cx, inf.iters))
                 f = self._request_flow(r, f[0])
                 if r.trace is not None:
                     r.trace.add_span("retry_single", t_r, iters=inf.iters)
@@ -2634,6 +2689,7 @@ class ServeEngine:
                 self._sched_seal(closed, loop)
                 self._log_counters()
                 self._alerts.maybe_observe()
+                self._stream_settle()
             try:
                 for pool in list(self._pools.values()):
                     self._pool_retire(pool)
@@ -2813,9 +2869,14 @@ class ServeEngine:
                 np.int32,
             )
             live = [m.req for _, m, _ in due]
-            fetch_c1 = self._warm_start and any(
-                m.req.kind == "stream" for _, m, _ in due
+            # warm start: which retiring pairs' sessions still hold the
+            # row they were admitted from (their final 1/8-grid flow is
+            # written there, on the device, by stream_store_flow)
+            flow_rows = self._warm_start and self._stream_cache.flow_rows(
+                pool.bucket,
+                [r if r.kind == "stream" else None for r in live], rung,
             )
+            fetch_c1 = any(r.want_flow8 for r in live)
             t_f = time.monotonic()
             for _, meta, _ in due:
                 r = meta.req
@@ -2833,16 +2894,21 @@ class ServeEngine:
                     pool.state["resid_hist"], idx,
                 )
             flow = self._run_pool_final(c1, hid)
-            # the residual trajectories (and, with warm start on, the
-            # retiring streams' final 1/8-grid coords) ride the fetch
-            # the finalize already pays — the flow asarray below is the
-            # sync point, both are computed and resident by then. The
-            # wait on the device has a phase to itself.
+            if flow_rows:
+                self._run_stream_store_flow(pool.bucket, c1, *flow_rows)
+            # the residual trajectories (and the finite flags of the
+            # retiring stream pairs' frames, computed by their encode;
+            # and the 1/8-grid coordinates where a caller asked for its
+            # flow8) ride the fetch the finalize already pays — the flow
+            # asarray below is the sync point, the rest is computed and
+            # resident by then. The wait on the device has a phase to
+            # itself.
             with self._phase("serve/sched/fetch"):
                 return (
                     np.asarray(flow),
                     np.asarray(res),
                     np.asarray(c1) if fetch_c1 else None,
+                    [m.frame_finite() for _, m, _ in due],
                 )
 
         out, tripped = self._guarded_dispatch(live, run)
@@ -2857,7 +2923,7 @@ class ServeEngine:
             self._counters["batches"] += 1
             if not tripped:
                 self._counters["fetched_bytes"] += sum(
-                    a.nbytes for a in out if a is not None
+                    a.nbytes for a in out[:3] if a is not None
                 )
         if tripped:
             # requests already failed by the watchdog callback; their
@@ -2869,7 +2935,7 @@ class ServeEngine:
             return
         if self._loop is not None:
             self._loop.retired.extend(r.rid for r in live)
-        flows, resids, c1_rows = out
+        flows, resids, c1_rows, frame_ok = out
         for pos, (i, meta, reason) in enumerate(due):
             r = meta.req
             f = self._request_flow(r, flows[pos])
@@ -2894,7 +2960,13 @@ class ServeEngine:
                 traj = traj[n_sent:]
                 eff -= n_sent
                 k = len(traj)
-            if np.isfinite(f).all():
+            if not frame_ok[pos]:
+                # the pair's new frame came out of the encoders non-finite
+                # (its flow may be finite all the same: only the context
+                # would have been, and the NEXT pair reads that)
+                self._poisoned_frame(r)
+                pool.release(i)
+            elif np.isfinite(f).all():
                 saved = max(0, self._controller.ladder[meta.level] - eff)
                 with self._lock:
                     self._counters["early_exit_iters_saved"] += saved
@@ -2920,13 +2992,17 @@ class ServeEngine:
                         r.trace.annotate(
                             final_residual=round(float(traj[-1]), 6)
                         )
-                if c1_rows is not None and r.kind == "stream":
-                    # warm start: cache the retiring pair's final
-                    # 1/8-grid flow next to the session's frame features
-                    self._store_stream_flow(r.stream_id, c1_rows[pos])
+                if self._warm_start and r.kind == "stream":
+                    # warm start: the session's row holds this pair's
+                    # final 1/8-grid flow now
+                    self._stream_cache.mark_flow(r.stream_id)
                 self._finish_ok(
                     r, f, eff, level=meta.level, exit_reason=reason,
                     warm_started=meta.warm,
+                    flow8=(
+                        c1_rows[pos] - _coords0(c1_rows[pos].shape)
+                        if r.want_flow8 else None
+                    ),
                     residuals=(
                         tuple(float(x) for x in traj)
                         if (k and r.trace is not None) else None
@@ -2953,7 +3029,7 @@ class ServeEngine:
             return self._pool_cap if pool is None else pool.free_count()
 
         with self._phase("serve/sched/poll"):
-            busy = any(
+            busy = bool(self._stream_checks) or any(
                 p.occupied_count() or p.pending for p in self._pools.values()
             )
             batch = self._queue.next_batch(
@@ -3056,7 +3132,7 @@ class ServeEngine:
         )
         if tripped:
             return
-        (f1, c1), (f2, _c2) = out
+        (f1, c1, _), (f2, _c2, _) = out
         self._trace_span(live, "encode", t_e, rung=rung)
         with self._phase("serve/sched/stage"):
             ishape = (self._admit_cap,) + tuple(f1.shape[1:3]) + (2,)
@@ -3077,6 +3153,12 @@ class ServeEngine:
         self, pool: BucketPool, live: List[Request], ctrl_iters: int,
         level: int,
     ) -> None:
+        """Admit a cohort of stream frames: ``encode_frame`` ->
+        ``stream_swap`` -> ``pool_begin_features`` -> ``insert``, each
+        fed the last one's device arrays. The host sends the frames and
+        the sessions' row numbers and fetches nothing; a prime's answer
+        waits for its frame's finite flag (``_stream_settle``), a pair's
+        flag is read at its retirement."""
         with self._phase("serve/sched/stage"):
             bh, bw = pool.bucket
             rung = self._rung_admit(len(live))
@@ -3087,60 +3169,58 @@ class ServeEngine:
                 ("pool_frames", pool.bucket), shape,
                 [r.p2 for r in live], rung,
             )
+            co = self._stream_cache.plan(live, rung)
+            self._count_stream_frames(co)
+            # one admission's rows on the device at a time
+            pool.await_rows()
             t_e = time.monotonic()
 
-        def run_encode():
-            fm, cx = self._run_encode(frames)
-            # the admit group's wait on the device, a phase to itself
-            with self._phase("serve/sched/encode_fetch"):
-                return np.asarray(fm), np.asarray(cx)
-
-        (fmap_np, ctx_np), tripped = self._guarded_dispatch(live, run_encode)
-        if tripped:
-            return
-        self._trace_span(live, "encode", t_e, rung=rung)
-        with self._phase("serve/sched/stage"):
-            flow_reqs, rows = self._stream_transact(
-                live, fmap_np, ctx_np, ctrl_iters, level
+        def run():
+            fm, cx, finite = self._run_encode(frames)
+            f1, c1, ifl = self._run_stream_swap(
+                pool.bucket, fm, cx, co.idx, co.put, co.warm
             )
-            if not flow_reqs:
-                return
-            rung2 = self._rung_admit(len(flow_reqs))
-            fshape = (self._admit_cap,) + fmap_np.shape[1:]
-            cshape = (self._admit_cap,) + ctx_np.shape[1:]
-            ishape = (self._admit_cap,) + fmap_np.shape[1:3] + (2,)
-            f1 = self._staging.fill(
-                ("pool_f1", pool.bucket), fshape,
-                [rr[0] for rr in rows], rung2,
-            )
-            f2 = self._staging.fill(
-                ("pool_f2", pool.bucket), fshape,
-                [rr[1] for rr in rows], rung2,
-            )
-            cx = self._staging.fill(
-                ("pool_ctx", pool.bucket), cshape,
-                [rr[2] for rr in rows], rung2,
-            )
-            ifl = self._staging.fill(
-                ("pool_init", pool.bucket), ishape,
-                [rr[3] for rr in rows], rung2,
-            )
-            pool.await_rows()
             t0 = time.monotonic()
-        state_rows, tripped = self._guarded_dispatch(
-            flow_reqs,
-            lambda: self._run_pool_begin_features(f1, f2, cx, ifl),
-        )
+            if not co.pairs:
+                return finite, None, t0
+            rows = self._run_pool_begin_features(f1, fm, c1, ifl)
+            return finite, rows, t0
+
+        (finite, rows, t0), tripped = self._guarded_dispatch(live, run)
         if tripped:
-            for r in flow_reqs:
+            for r in live:
                 self._invalidate_stream(r.stream_id)
             return
-        self._trace_span(flow_reqs, "dispatch", t0, rung=rung2)
-        self._pool_insert_live(pool, state_rows, flow_reqs, ctrl_iters, level)
+        self._trace_span(live, "encode", t_e, t0, rung=rung)
+        self._trace_span([r for _, r in co.pairs], "dispatch", t0, rung=rung)
+        if co.primes:
+            self._stream_checks.append((finite, co.primes, ctrl_iters, level))
+        if co.pairs:
+            self._pool_insert_live(
+                pool, rows, [r for _, r in co.pairs], ctrl_iters, level,
+                lanes=[lane for lane, _ in co.pairs], frame_ok=finite,
+            )
+
+    def _stream_settle(self) -> None:
+        """Answer the stream primes whose frames' finite flags have been
+        computed. The flag of a cohort is read once its ``encode_frame``
+        has run — which the loop learns without waiting (``is_ready``)
+        while the pool ticks, and by waiting where nothing else would
+        run on the device anyway (no resident, so no tick, drain or
+        retirement fetch this loop)."""
+        if not self._stream_checks:
+            return
+        idle = not any(p.occupied_count() for p in self._pools.values())
+        while self._stream_checks:
+            finite, primes, iters, level = self._stream_checks[0]
+            if not (idle or finite.is_ready()):
+                return
+            self._stream_checks.popleft()
+            self._settle_primes(primes, np.asarray(finite), iters, level)
 
     def _pool_insert_live(
         self, pool: BucketPool, rows, live: List[Request], ctrl_iters: int,
-        level: int,
+        level: int, lanes: Optional[List[int]] = None, frame_ok=None,
     ) -> None:
         """Write each admitted request's state row into a free slot.
 
@@ -3149,27 +3229,34 @@ class ServeEngine:
         degradation under the pool is a per-request admission decision,
         not a compile-time ladder. The whole cohort's slot writes go
         through ONE insert dispatch (rows beyond ``len(live)`` are
-        padding lanes, masked out).
+        padding lanes, masked out). ``lanes`` names the rows of ``rows``
+        that are ``live``'s where they are not its first ones (a stream
+        cohort's primes keep their lanes and take no slot);
+        ``frame_ok`` is the cohort's device array of finite flags, kept
+        on each slot for its retirement to read.
         """
         with self._phase("serve/sched/insert"):
-            self._pool_insert_slots(pool, rows, live, ctrl_iters, level)
+            self._pool_insert_slots(
+                pool, rows, live, ctrl_iters, level,
+                range(len(live)) if lanes is None else lanes, frame_ok,
+            )
 
-    def _pool_insert_slots(self, pool, rows, live, ctrl_iters, level) -> None:
+    def _pool_insert_slots(
+        self, pool, rows, live, ctrl_iters, level, lanes, frame_ok
+    ) -> None:
         now = time.monotonic()
         rung = int(rows["coords1"].shape[0])
         slots = [pool.alloc() for _ in live]
-        idx = np.asarray(
-            slots + [0] * (rung - len(slots)), np.int32
-        )
-        mask = np.asarray(
-            [True] * len(slots) + [False] * (rung - len(slots)), bool
-        )
+        lanes = list(lanes)
+        idx = np.zeros((rung,), np.int32)
+        mask = np.zeros((rung,), bool)
+        idx[lanes], mask[lanes] = slots, True
         pool.state = self._pool_insert(pool.state, rows, idx, mask)
         if self._loop is not None:
             self._loop.admitted.extend(r.rid for r in live)
         qos_on = self.config.qos_enabled
         ladder = self._controller.ladder
-        for i, r in zip(slots, live):
+        for i, r, lane in zip(slots, live, lanes):
             requested = r.iters if r.iters is not None else self.config.ladder[0]
             # class-aware brownout (ISSUE 17): under pressure each slot's
             # iteration target browns out by its class's extra levels —
@@ -3184,6 +3271,7 @@ class ServeEngine:
                 level=eff_level,
                 admitted_t=now,
                 warm=r.warm,
+                frame_ok=None if frame_ok is None else (frame_ok, lane),
             )
             with self._lock:
                 self._counters["pool_admitted"] += 1
@@ -3413,111 +3501,54 @@ class ServeEngine:
             lambda: self._pool_progs.gather(coords1, hidden, resid_hist, idx),
         )
 
-    def _stream_transact(
-        self,
-        live: List[Request],
-        fmap_np: np.ndarray,
-        ctx_np: np.ndarray,
-        iters: int,
-        level: int,
-    ) -> Tuple[
-        List[Request],
-        List[Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]],
-    ]:
-        """Transact each session's feature cache against a fetched encode
-        batch (shared by the fallback worker and the pool's stream
-        admission). Primes finish immediately; returns the requests that
-        had a cached previous frame plus their (prev_fmap, new_fmap,
-        prev_ctx, init_flow) rows for the refinement stage.
+    def _run_stream_swap(self, bucket, fmap, ctx, idx, put, warm):
+        """Dispatch one cohort's cache transaction: the sessions' previous
+        rows (and warm-start seeds) out, the new frames' rows in."""
+        cache = self._stream_cache
+        key = ("stream_swap", fmap.shape[0], fmap.shape[1], fmap.shape[2])
+        ex = self._aot_execs.get(key) or cache.programs.swap
+        with self._phase("serve/stream_swap"):
+            table, f1, c1, init = self.ledger.run(
+                key,
+                lambda: ex(cache.table(bucket), fmap, ctx, idx, put, warm),
+            )
+        cache.set_table(bucket, table)
+        return f1, c1, init
 
-        ``init_flow`` is the warm-start seed (ISSUE 12): the previous
-        pair's cached final flow, forward-warped by itself — or zeros
-        (the bitwise cold start) when warm start is off, the session has
-        no flow yet, or the fallback engine is serving (its whole-request
-        iterate has no seed input)."""
-        from raft_tpu.serve.pool import forward_warp_flow
-
-        flow_reqs: List[Request] = []
-        rows: List[
-            Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
-        ] = []
-        h8, w8 = int(fmap_np.shape[1]), int(fmap_np.shape[2])
-        zero_flow = np.zeros((1, h8, w8, 2), np.float32)
-        with self._streams_lock:
-            for i, r in enumerate(live):
-                st = self._streams.get(r.stream_id)
-                if st is None:
-                    st = _StreamState(r.stream_id, r.bucket, r.orig_hw)
-                    self._streams[r.stream_id] = st
-                    self._evict_streams_locked()
-                self._streams.move_to_end(r.stream_id)
-                fm_new = fmap_np[i:i + 1].copy()
-                cx_new = ctx_np[i:i + 1].copy()
-                if not (
-                    np.isfinite(fm_new).all() and np.isfinite(cx_new).all()
-                ):
-                    # encoder-poisoned frame: never cache it, never pair it
-                    st.fmap = st.ctx = st.flow8 = None
-                    self._quarantine(r)
-                    continue
-                prev_fm, prev_cx = st.fmap, st.ctx
-                prev_flow = st.flow8
-                st.fmap, st.ctx = fm_new, cx_new
-                st.flow8 = None   # consumed (or stale); refreshed at retire
-                if prev_fm is None:
-                    self._count("encode_cache_misses")
-                    self._count("stream_primes")
-                    self._finish_ok(r, None, iters, level=level, primed=True)
-                else:
-                    self._count("encode_cache_hits")
-                    init = zero_flow
-                    if self._warm_start and prev_flow is not None:
-                        init = forward_warp_flow(prev_flow)[None]
-                        r.warm = True
-                        self._count("stream_warm_starts")
-                    flow_reqs.append(r)
-                    rows.append((prev_fm, fm_new, prev_cx, init))
-        return flow_reqs, rows
-
-    def _store_stream_flow(self, stream_id: Optional[int], c1_row) -> None:
-        """Cache a retiring stream pair's final 1/8-grid flow (coords1 -
-        coords0) on its session for the next admission's warm start.
-        Skipped when the session is gone or was invalidated mid-flight
-        (a stream never warm-starts across a gap)."""
-        if stream_id is None:
-            return
-        c1 = np.asarray(c1_row, np.float32)         # (h8, w8, 2), (x, y)
-        h8, w8 = c1.shape[0], c1.shape[1]
-        ys, xs = np.meshgrid(
-            np.arange(h8, dtype=np.float32),
-            np.arange(w8, dtype=np.float32),
-            indexing="ij",
+    def _run_stream_store_flow(self, bucket, coords1, idx, mask) -> None:
+        """Dispatch a retirement's write of its stream pairs' final
+        1/8-grid flows into their sessions' rows (warm start)."""
+        cache = self._stream_cache
+        key = (
+            "stream_store_flow", coords1.shape[0], coords1.shape[1],
+            coords1.shape[2],
         )
-        flow8 = c1 - np.stack([xs, ys], axis=-1)
-        with self._streams_lock:
-            st = self._streams.get(stream_id)
-            if st is not None and st.fmap is not None:
-                st.flow8 = flow8
+        ex = self._aot_execs.get(key) or cache.programs.store_flow
+        with self._phase("serve/stream_store_flow"):
+            cache.set_table(bucket, self.ledger.run(
+                key, lambda: ex(cache.table(bucket), coords1, idx, mask)
+            ))
+
+    def _stream_row_spec(self, bucket):
+        """Shape/dtype of ``encode_frame``'s feature map and context
+        output for one frame of ``bucket``: a row of the session table."""
+        bh, bw = bucket
+        fm, cx, _ = jax.eval_shape(
+            self._encode, self._dev_vars,
+            jax.ShapeDtypeStruct((self._batch_ladder[0], bh, bw, 3),
+                                 jnp.float32),
+        )
+        return fm, cx
+
+    @property
+    def _streams(self):
+        """The live sessions, by id (empty with stream serving off)."""
+        cache = self._stream_cache
+        return {} if cache is None else cache.sessions
 
     def _invalidate_stream(self, stream_id: Optional[int]) -> None:
-        if stream_id is None:
-            return
-        with self._streams_lock:
-            st = self._streams.get(stream_id)
-            if st is not None and (st.fmap is not None or st.ctx is not None):
-                st.fmap = st.ctx = st.flow8 = None
-                self._count("stream_invalidations")
-
-    def _evict_streams_locked(self) -> None:
-        """LRU-evict cached sessions beyond the bound (never a busy one)."""
-        excess = len(self._streams) - self.config.stream_cache_size
-        if excess <= 0:
-            return
-        for sid in [
-            s for s, st in self._streams.items() if not st.busy
-        ][:excess]:
-            del self._streams[sid]
-            self._count("stream_evictions")
+        if self._stream_cache is not None:
+            self._stream_cache.invalidate(stream_id)
 
     def _quarantine(self, r: Request) -> None:
         r.finish(
@@ -3545,6 +3576,7 @@ class ServeEngine:
         t0: Optional[float] = None,
         residuals: Optional[Tuple[float, ...]] = None,
         warm_started: bool = False,
+        flow8: Optional[np.ndarray] = None,
     ) -> ServeResult:
         level = self._controller.level if level is None else level
         latency_ms = (time.monotonic() - (t0 if t0 is not None else r.t_submit)) * 1e3
@@ -3571,6 +3603,7 @@ class ServeEngine:
             trace_id=None if r.trace is None else r.trace.trace_id,
             residuals=residuals,
             warm_started=warm_started,
+            flow8=flow8,
         )
         def _account(r_: Request) -> None:
             # rides finish(on_first=...): counted BEFORE the waiter wakes

@@ -135,42 +135,6 @@ def unpack_lookup_rows(packed, capacity: int):
     return int(read), int(whole)
 
 
-def forward_warp_flow(flow):
-    """Forward-warp a 1/8-grid flow field by itself (host-side numpy).
-
-    The classic RAFT video-mode warm start: flow(t-1 -> t) predicts
-    where each pixel lands in frame t, so the *same vector* is the best
-    prior for where that content moves next — splat each source pixel's
-    flow to its (rounded) target location. Holes (content nothing warped
-    into) stay zero — the cold-start prior; collisions keep the
-    larger-magnitude vector (a mover occluding static background should
-    carry its motion into the cell it lands on). Nearest-splat is cheap
-    and fully adequate at the 1/8 grid, where one cell is an 8-pixel
-    block.
-
-    Args:
-        flow: ``(h8, w8, 2)`` float32, (x, y) pixel units at the 1/8 grid.
-
-    Returns:
-        ``(h8, w8, 2)`` float32 warped field.
-    """
-    import numpy as np
-
-    flow = np.asarray(flow, np.float32)
-    h, w = flow.shape[:2]
-    ys, xs = np.meshgrid(np.arange(h), np.arange(w), indexing="ij")
-    xt = np.rint(xs + flow[..., 0]).astype(np.int64)
-    yt = np.rint(ys + flow[..., 1]).astype(np.int64)
-    valid = (xt >= 0) & (xt < w) & (yt >= 0) & (yt < h)
-    vecs = flow[valid]
-    # write in ascending-magnitude order: numpy fancy assignment keeps
-    # the LAST write per duplicate target, so the largest motion wins
-    order = np.argsort(np.sqrt((vecs ** 2).sum(-1)), kind="stable")
-    out = np.zeros_like(flow)
-    out[yt[valid][order], xt[valid][order]] = vecs[order]
-    return out
-
-
 @dataclasses.dataclass
 class _SlotMeta:
     """Host-side bookkeeping for one resident request."""
@@ -189,6 +153,20 @@ class _SlotMeta:
     # nothing (bitwise) and are accounted as idle slot-iterations.
     converged: bool = False
     converged_done: int = 0
+    # a stream pair's new frame: (its admission cohort's device array of
+    # the encoders' finite flags, its lane), read at retirement
+    frame_ok: Optional[Tuple[Any, int]] = None
+
+    def frame_finite(self) -> bool:
+        """Whether the encoders made finite features of this slot's new
+        frame (True for a pair admitted whole: its flow says so). A read
+        of a few bytes computed an admission ago."""
+        if self.frame_ok is None:
+            return True
+        import numpy as np
+
+        flags, lane = self.frame_ok
+        return bool(np.asarray(flags)[lane])
 
 
 def _insert_rows(state, rows, idx, mask):
@@ -324,7 +302,7 @@ class PoolPrograms:
         )
         # Stream admission takes the warm-start initial flow as a TRACED
         # input (ISSUE 12): zeros reproduce the cold start bitwise, a
-        # forward-warped previous-pair flow seeds coords1 near the fixed
+        # forward-interpolated previous-pair flow seeds coords1 near the fixed
         # point — one compiled program either way.
         def pool_begin_features(variables, fmap1, fmap2, context_out,
                                 init_flow):

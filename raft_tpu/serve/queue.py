@@ -41,7 +41,7 @@ class Request:
     __slots__ = (
         "rid", "bucket", "p1", "p2", "orig_hw", "deadline", "t_submit",
         "slow_path", "kind", "stream_id", "iters", "trace", "warm",
-        "init8", "priority", "tenant", "rank", "shadow",
+        "init8", "priority", "tenant", "rank", "shadow", "want_flow8",
         "_event", "_lock", "_done", "_callbacks", "result", "error",
     )
 
@@ -83,6 +83,7 @@ class Request:
         self.init8 = None     # (1, bh/8, bw/8, 2) init_flow seed (ISSUE 19):
         #                       pair requests only, set by submit when the
         #                       edge supplies a near-dup neighbor's flow
+        self.want_flow8 = False  # the result carries its 1/8-grid flow too
         self._event = threading.Event()
         self._lock = threading.Lock()
         self._done = False

@@ -441,3 +441,122 @@ def test_lookup_xtap_unsharded_under_mesh_is_refused(topo, no_persistent_cache):
             _on(row, pyramid), _on(row, cents), _on(rep, kernel),
             _on(rep, bias),
         )
+
+
+# -- the video deployment (PR 36): stream programs and the 32-slot pool --------
+
+VIDEO_BUCKET = (440, 1024)
+
+
+@pytest.fixture(scope="module")
+def video_model():
+    return _serving_model("raft_large", VIDEO_BUCKET)
+
+
+@pytest.mark.parametrize("rung", [1, 8])
+def test_stream_admission_takes_the_encoders_own_dtype(
+    one_chip, video_model, rung
+):
+    """``encode_frame`` and ``pool_begin_features`` at the cell's bucket,
+    at the smallest and the largest admit rung, compiled for one v5e:
+    the feature map and the context output leave the encode program in
+    bf16 (the ``throughput`` preset's conv dtype) and ``pool_begin_features``
+    is lowered for exactly those operands — what ``aot.program_specs``
+    does since PR 36, where the engine used to hand it fetched float32
+    arrays (PERF.md, §7 row 4: "compiled with bfloat16[...] and called
+    with float32[...]"). A rung-8 cohort's rows and temporaries fit
+    beside a 32-slot pool."""
+    from raft_tpu.serve.pool import PoolPrograms
+    from raft_tpu.serve.stream_cache import encode_frame_program
+
+    _, _, model, variables, _ = video_model
+    frames = jax.ShapeDtypeStruct((rung,) + VIDEO_BUCKET + (3,), jnp.float32)
+    encode = jax.jit(encode_frame_program(model.apply))
+    fm, cx, ok = jax.eval_shape(encode, variables, frames)
+    assert fm.dtype == cx.dtype == jnp.bfloat16 and ok.dtype == jnp.bool_
+    assert fm.shape == (rung, 55, 128, 256) and cx.shape == fm.shape
+    enc = encode.lower(*_on(one_chip, (variables, frames))).compile()
+    progs = PoolPrograms(model, resid_len=32)
+    init = jax.ShapeDtypeStruct((rung, 55, 128, 2), jnp.float32)
+    begin = progs.begin_features.lower(
+        *_on(one_chip, (variables, fm, fm, cx, init))
+    ).compile()
+    args = begin.as_text().split("ENTRY ")[1].split("\n")[0]
+    assert f"bf16[{rung},55,128,256]" in args and "f32[" + f"{rung},55,128,256]" not in args
+    gb = 1e9
+    mem = begin.memory_analysis()
+    # a row is 0.22 GB (the slot's resident pyramid); 32 slots are 7.2 GB
+    assert mem.output_size_in_bytes < 0.23 * gb * rung
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 4.0 * gb
+    assert enc.memory_analysis().temp_size_in_bytes < 1.5 * gb
+
+
+def test_step_program_on_a_32_slot_sintel_pool_blocks_its_coordinates(
+    one_chip, video_model
+):
+    """The video cell's pool is twice as deep as the Sintel cells': the
+    lookup's coordinate operand, ``f32[32 x 7040, 2]``, is 115 MB
+    lane-padded against the call's 100 MiB, so ``_plan_tile`` blocks it
+    by tile (640 rows) — by itself since PR 30, never compiled at this
+    shape before PR 36. One Mosaic call, no level copied, the state read
+    in place (temporaries far under a level)."""
+    from raft_tpu.serve.pool import PoolPrograms, state_spec
+
+    slots = 32
+    _, block, model, variables, _ = video_model
+    state = state_spec(model, variables, slots, VIDEO_BUCKET, resid_len=32)
+    plan = block.lookup_plan(
+        jax.tree.map(
+            lambda v: jax.ShapeDtypeStruct(
+                (v.shape[0] * v.shape[1],) + v.shape[2:], v.dtype
+            ),
+            state["pyramid"],
+        ),
+        VIDEO_BUCKET[1] // 8,
+    )
+    assert plan.tile == 640 and plan.coords_blocked
+    assert plan.heights == plan.rows == (56, 32)       # levels read whole
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    count = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = PoolPrograms(model, resid_len=32).step.lower(
+        *_on(one_chip, (variables, state, scalar, count, count))
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    # 16 slots are 2.87 GB of arguments (the test above): twice that
+    assert 5.6e9 < mem.argument_size_in_bytes < 5.9e9
+    assert mem.temp_size_in_bytes < 0.3e9
+
+
+@pytest.mark.parametrize(
+    "bucket,rung",
+    [((440, 1024), 8), ((1088, 1920), 1)],
+    ids=["sintel-440x1024-rung8", "hd1080-1088x1920"],
+)
+def test_stream_swap_interpolates_in_blocks(one_chip, bucket, rung):
+    """``stream_swap`` with warm start (the session table's gather, the
+    in-place row writes and upstream's ``forward_interpolate`` a lane)
+    for one v5e: the nearest-point search runs in blocks of target cells,
+    so no ``Q x Q`` array exists — Q is 7,040 here (198 MB whole) and
+    32,640 at 1088x1920 (4.3 GB) — and the program's temporaries stay
+    under 64 MB at both, whatever the rung (lanes are walked one after
+    another). The table is written in place: the output aliases it."""
+    from raft_tpu.serve.stream_cache import StreamPrograms
+
+    sessions = 48 if bucket == (440, 1024) else 4
+    h8, w8 = bucket[0] // 8, bucket[1] // 8
+    rows = lambda n, c, dt: jax.ShapeDtypeStruct((n, h8, w8, c), dt)
+    table = {"fmap": rows(sessions, 256, jnp.bfloat16),
+             "ctx": rows(sessions, 256, jnp.bfloat16),
+             "flow": rows(sessions, 2, jnp.float32)}
+    lane = lambda dt: jax.ShapeDtypeStruct((rung,), dt)
+    compiled = StreamPrograms(warm_start=True).swap.lower(*_on(one_chip, (
+        table, rows(rung, 256, jnp.bfloat16), rows(rung, 256, jnp.bfloat16),
+        lane(jnp.int32), lane(jnp.bool_), lane(jnp.bool_),
+    ))).compile()
+    mem = compiled.memory_analysis()
+    table_bytes = sessions * h8 * w8 * (2 * 256 * 2 + 2 * 4)
+    assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= table_bytes - 4096   # in place
+    assert " conditional(" in compiled.as_text()   # a cold lane skips the search

@@ -506,6 +506,9 @@ ENGINE_STATS_KEYS = frozenset({
     "stream_evictions", "stream_invalidations", "stream_primes",
     "stream_warm_starts", "submitted", "variables_hash", "watchdog_trips",
     "worker_errors",
+    # PR 36: the device-resident session cache — frames admitted through
+    # it, sessions remembered, bytes of table rows they hold
+    "stream_frames", "stream_sessions", "stream_cache_bytes",
     # ISSUE 20: the waste-aware tile fan-out block (envelope-level
     # tiled-request accounting; schema pinned by TILER_STATS_KEYS)
     "tiler",

@@ -12,11 +12,12 @@ Coverage map:
   converged mask arrives on the pacing fetch the tick loop already pays.
 * **Model level** (tier-1) — ``begin_refinement(init_flow=0)`` is
   bitwise the cold start, a nonzero seed lands exactly on
-  ``coords0 + init_flow``, and ``forward_warp_flow`` splat semantics.
+  ``coords0 + init_flow``, and ``forward_interpolate``'s nearest-point
+  semantics (upstream's warm start).
 * **Engine level** (tiny model, tier-1) — exit-reason split (converged
   exits counted distinctly from deadline exits, per-reason iters-saved
   attribution, ``early_exit`` back-compat property), warm-start flag and
-  flow8 cache lifecycle (invalidation clears the seed — no warm start
+  flow-row lifecycle (invalidation clears the seed — no warm start
   across a gap), pre-ISSUE-12 artifact version refusal degrading to
   compile, and the serve_bench adaptive-A/B machinery smoke.
 * **Trained fixture** (slow) — the equal-EPE gate: at the calibrated
@@ -47,9 +48,9 @@ from raft_tpu.serve.engine import ServeResult
 from raft_tpu.serve.pool import (
     RESID_SENTINEL,
     PoolPrograms,
-    forward_warp_flow,
     unpack_converged,
 )
+from raft_tpu.serve.stream_cache import forward_interpolate
 
 FIXTURE = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "fixtures", "epe_golden"
@@ -306,18 +307,30 @@ class TestWarmStartModel:
                 train=False, method="begin_pair",
             )
 
-    def test_forward_warp_splat_semantics(self):
+    def test_forward_interpolate_semantics(self):
+        """Upstream's warm start (``forward_interpolate``): every cell
+        takes the flow of the nearest point that landed strictly inside
+        the frame — no holes, no wrap-around, zeros only when nothing
+        lands."""
+        interp = lambda f: np.asarray(forward_interpolate(f))
         flow = np.zeros((4, 6, 2), np.float32)
-        assert np.array_equal(forward_warp_flow(flow), flow)   # identity
-        # a single vector (+2 in x) splats to its landing cell
+        assert np.array_equal(interp(flow), flow)              # identity
+        # one vector (+2 in x) lands on cell (1, 3), where that cell's own
+        # point (zero flow) already is: equidistant, and the lower source
+        # index — (1, 1) — wins
         flow[1, 1] = (2.0, 0.0)
-        out = forward_warp_flow(flow)
+        out = interp(flow)
         assert tuple(out[1, 3]) == (2.0, 0.0)
-        assert tuple(out[1, 1]) == (0.0, 0.0)                  # hole = cold
-        # out-of-bounds targets are dropped, never wrap
-        flow2 = np.zeros((4, 6, 2), np.float32)
-        flow2[0, 5] = (3.0, 0.0)
-        assert (forward_warp_flow(flow2) == 0).all()
+        # the cell it left is no hole: it takes a neighbour's (zero) flow
+        assert tuple(out[1, 1]) == (0.0, 0.0)
+        assert (out[..., 1] == 0).all()
+        # a point that leaves the frame is dropped, never wrapped, and the
+        # cell it left is filled from the nearest point that stayed
+        flow2 = np.full((4, 6, 2), (1.0, 0.0), np.float32)
+        flow2[0, 5] = (30.0, 0.0)
+        assert (interp(flow2) == np.asarray((1.0, 0.0), np.float32)).all()
+        # nothing lands inside: zeros
+        assert (interp(np.full((4, 6, 2), 99.0, np.float32)) == 0).all()
 
 
 # ---------------------------------------------------------------------------
