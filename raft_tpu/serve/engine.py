@@ -315,6 +315,7 @@ class _SchedLoop:
 
     __slots__ = (
         "trace", "cpu0", "t_end", "phases", "ticked", "admitted", "retired",
+        "deferred",
     )
 
     def __init__(self, trace):
@@ -324,6 +325,47 @@ class _SchedLoop:
         self.ticked = 0
         self.admitted: List[int] = []
         self.retired: List[int] = []
+        self.deferred = 0  # retirements settled after this loop's tick
+
+
+class _Retiring:
+    """A retirement cohort between its dispatch and its settle: its
+    ``pool_gather`` / ``pool_final`` (and ``stream_store_flow``) are
+    enqueued, the host copies of what its requests get are started, and
+    its slots are free for the loop's admission."""
+
+    __slots__ = ("due", "live", "t_f", "flow", "res", "c1", "flags")
+
+    def __init__(self, due, live, t_f, flow, res, c1):
+        self.due, self.live, self.t_f = due, live, t_f
+        self.flow, self.res, self.c1 = flow, res, c1
+        # the retiring stream pairs' frame-finite flag arrays, one each
+        # admission cohort they came from
+        self.flags = list({
+            id(m.frame_ok[0]): m.frame_ok[0]
+            for _, m, _ in due if m.frame_ok is not None
+        }.values())
+
+    def arrays(self) -> list:
+        return [self.flow, self.res] + (
+            [] if self.c1 is None else [self.c1]
+        ) + self.flags
+
+    def holds(self, sessions) -> bool:
+        return any(
+            r.kind == "stream" and r.stream_id in sessions for r in self.live
+        )
+
+    def fetch(self):
+        """The flows, residual histories, coordinates and frame flags on
+        the host: a wait on the device only where the copies are not in
+        yet."""
+        return (
+            np.asarray(self.flow),
+            np.asarray(self.res),
+            None if self.c1 is None else np.asarray(self.c1),
+            [m.frame_finite() for _, m, _ in self.due],
+        )
 
 
 class _StagingPool:
@@ -542,6 +584,9 @@ class ServeEngine:
         # settled by the pool worker once the encode that computes the
         # flag has run (_stream_settle)
         self._stream_checks: "collections.deque[Tuple]" = collections.deque()
+        # retirements between their dispatch and their settle, oldest
+        # first (_pool_finalize parks, _pool_settle reads and completes)
+        self._retiring: "collections.deque[_Retiring]" = collections.deque()
         self._lock = threading.Lock()
         # Observability spine (ISSUE 10): the unified metrics registry,
         # the per-request tracer, and the fault flight recorder. The
@@ -580,6 +625,10 @@ class ServeEngine:
                 # history, warm-start coords): beside `completed`, the
                 # size of the blocking fetch a completion pays
                 "fetched_bytes",
+                # retirements read after a later tick was dispatched, and
+                # of those, how many had all their arrays on the host
+                # when read (_pool_settle)
+                "retire_deferred", "retire_ready_at_settle",
                 "idle_slot_iters", "dispatched_slot_iters",
                 "early_exit_iters_saved", "early_exits_deadline",
                 "early_exits_converged", "early_exit_iters_saved_deadline",
@@ -843,9 +892,12 @@ class ServeEngine:
         """Idle check for :meth:`drain`: nothing queued, no batch popped
         from the queue but not yet reflected in dispatch bookkeeping
         (``queue.forming()``), nothing dispatched-but-unfetched, no pool
-        residents."""
+        residents, no retirement parked between dispatch and settle (its
+        slots are free already)."""
         if self._queue.depth() or self._queue.forming():
             return False
+        if self._retiring:
+            return False  # flows dispatched, not yet read and handed over
         if self.config.pool_capacity > 0:
             return all(
                 p.occupied_count() == 0 for p in self._pools.values()
@@ -2677,6 +2729,11 @@ class ServeEngine:
         batch, a tick failure costs the residents of that pool, never the
         worker thread.
 
+        A retirement is dispatched before admission and read after the
+        tick (``_pool_finalize`` parks it, ``_pool_settle`` reads it), so
+        the wait for a flow and its transfer run while the device has
+        the loop's admission and tick queued behind them.
+
         Every step of the loop runs inside one ``_phase`` (flat: never
         two open at once), so a profiler capture and a sampled loop's
         ``"sched"`` record both show where an iteration went
@@ -2694,11 +2751,15 @@ class ServeEngine:
                 for pool in list(self._pools.values()):
                     self._pool_retire(pool)
                 self._pool_admit()
+                ticked = False
                 for pool in list(self._pools.values()):
                     if pool.occupied_count():
                         self._pool_tick(pool)
+                        ticked = True
                         if loop is not None:
                             loop.ticked += 1
+                # with no resident nothing else would run: read at once
+                self._pool_settle(deferred=ticked)
             except Exception as e:  # isolation: fail residents, not the worker
                 self._count("worker_errors")
                 self._pool_fail_all(ServeError(f"pool tick failed: {e!r}"))
@@ -2709,6 +2770,11 @@ class ServeEngine:
             r.finish(error=EngineStopped("engine stopping"))
 
     def _pool_fail_all(self, err: ServeError) -> None:
+        while self._retiring:
+            for r in self._retiring.popleft().live:
+                r.finish(error=err)
+                if r.kind == "stream":
+                    self._invalidate_stream(r.stream_id)
         for pool in self._pools.values():
             metas = pool.clear()
             for m in metas:
@@ -2777,7 +2843,7 @@ class ServeEngine:
             pending=sum(len(p.pending) for p in pools),
             admitted=len(closed.admitted), retired=len(closed.retired),
             admitted_rids=closed.admitted, retired_rids=closed.retired,
-            cpu_ms=(cpu - closed.cpu0) * 1e3,
+            deferred=closed.deferred, cpu_ms=(cpu - closed.cpu0) * 1e3,
         )
 
     def _pool_retire(self, pool: BucketPool) -> None:
@@ -2851,10 +2917,14 @@ class ServeEngine:
     def _pool_finalize(
         self, pool: BucketPool, due: List[Tuple[int, _SlotMeta, str]]
     ) -> None:
-        """Gather finished slots' carry, run the final upsample, and
-        complete their requests. A non-finite flow quarantines exactly
-        its own request — slots are isolated by construction (inference
-        is per-sample end to end), so no singles retry is needed.
+        """Dispatch a retirement: gather the finished slots' carry, run
+        the final upsample, start the host copies of what the requests
+        get, free the slots and park the cohort; ``_pool_settle`` reads
+        it once the loop's tick is dispatched. The slots can be refilled
+        by this loop's admission: their carry is in the gathered arrays,
+        the device runs programs in dispatch order, and a later
+        ``insert`` (or tick) donating the state waits for the gather's
+        read of it.
 
         Retirement runs at the warmed admission rungs: more due slots
         than the top rung (possible when ``pool_capacity > max_batch``)
@@ -2896,28 +2966,64 @@ class ServeEngine:
             flow = self._run_pool_final(c1, hid)
             if flow_rows:
                 self._run_stream_store_flow(pool.bucket, c1, *flow_rows)
-            # the residual trajectories (and the finite flags of the
-            # retiring stream pairs' frames, computed by their encode;
-            # and the 1/8-grid coordinates where a caller asked for its
-            # flow8) ride the fetch the finalize already pays — the flow
-            # asarray below is the sync point, the rest is computed and
-            # resident by then. The wait on the device has a phase to
-            # itself.
-            with self._phase("serve/sched/fetch"):
-                return (
-                    np.asarray(flow),
-                    np.asarray(res),
-                    np.asarray(c1) if fetch_c1 else None,
-                    [m.frame_finite() for _, m, _ in due],
-                )
+            return flow, res, c1 if fetch_c1 else None
 
         out, tripped = self._guarded_dispatch(live, run)
-        with self._phase("serve/sched/complete"):
-            self._pool_complete(pool, due, live, t_f, out, tripped)
+        with self._phase("serve/sched/gather"):
+            if not tripped:
+                # the residual trajectories (and the finite flags of the
+                # retiring stream pairs' frames, computed by their
+                # encode; and the 1/8-grid coordinates where a caller
+                # asked for its flow8) travel with the flow: each copy
+                # starts as soon as its array is computed. Parked before
+                # the slots free up, so that drain() never sees the
+                # cohort in neither place.
+                co = _Retiring(due, live, t_f, *out)
+                for a in co.arrays():
+                    a.copy_to_host_async()
+                self._retiring.append(co)
+            for i, _, _ in due:
+                pool.release(i)
+        if tripped:
+            with self._phase("serve/sched/complete"):
+                self._pool_complete(due, live, t_f, None, True)
 
-    def _pool_complete(self, pool, due, live, t_f, out, tripped) -> None:
+    def _pool_settle(self, deferred: bool, sessions=None) -> None:
+        """Read the parked retirements, oldest first, and complete their
+        requests. After the loop's tick (``deferred``) the wait for a
+        flow runs while the device has that tick and the admission before
+        it queued. With ``sessions``, only as far as the newest cohort
+        that holds a pair of one of them: a session's next frame is
+        planned only once its last pair is settled (quarantine,
+        invalidation and ``mark_flow`` first)."""
+        n = len(self._retiring)
+        if sessions is not None:
+            n = max(
+                (k + 1 for k, co in enumerate(self._retiring)
+                 if co.holds(sessions)),
+                default=0,
+            )
+        for _ in range(n):
+            co = self._retiring[0]
+            with self._phase("serve/sched/fetch"):
+                ready = deferred and all(a.is_ready() for a in co.arrays())
+                out, tripped = self._guarded_dispatch(co.live, co.fetch)
+            with self._phase("serve/sched/complete"):
+                self._pool_complete(co.due, co.live, co.t_f, out, tripped)
+                self._retiring.popleft()
+                if deferred:
+                    with self._lock:
+                        self._counters["retire_deferred"] += 1
+                        self._counters["retire_ready_at_settle"] += ready
+                    if self._loop is not None:
+                        self._loop.deferred += 1
+
+    def _pool_complete(self, due, live, t_f, out, tripped) -> None:
         """Hand the fetched flows to their requests (crop, ``finish``,
-        callbacks) and free the slots."""
+        callbacks); their slots were freed at dispatch. A non-finite flow
+        quarantines exactly its own request — slots are isolated by
+        construction (inference is per-sample end to end), so no singles
+        retry is needed."""
         self._trace_span(live, "fetch", t_f)
         with self._lock:
             self._counters["batches"] += 1
@@ -2926,18 +3032,22 @@ class ServeEngine:
                     a.nbytes for a in out[:3] if a is not None
                 )
         if tripped:
-            # requests already failed by the watchdog callback; their
-            # slots are dead weight now — free them
-            for i, meta, _ in due:
-                pool.release(i)
+            # requests already failed by the watchdog callback
+            for _, meta, _ in due:
                 if meta.req.kind == "stream":
                     self._invalidate_stream(meta.req.stream_id)
             return
         if self._loop is not None:
             self._loop.retired.extend(r.rid for r in live)
         flows, resids, c1_rows, frame_ok = out
-        for pos, (i, meta, reason) in enumerate(due):
+        for pos, (_, meta, reason) in enumerate(due):
             r = meta.req
+            if r.done:
+                # its caller's deadline finished it while it retired: as
+                # in _pool_due, the session never pairs across it
+                if r.kind == "stream":
+                    self._invalidate_stream(r.stream_id)
+                continue
             f = self._request_flow(r, flows[pos])
             # a converged slot froze on device at converged_done
             # iterations — ticks dispatched after that changed nothing
@@ -2965,7 +3075,6 @@ class ServeEngine:
                 # (its flow may be finite all the same: only the context
                 # would have been, and the NEXT pair reads that)
                 self._poisoned_frame(r)
-                pool.release(i)
             elif np.isfinite(f).all():
                 saved = max(0, self._controller.ladder[meta.level] - eff)
                 with self._lock:
@@ -3008,10 +3117,8 @@ class ServeEngine:
                         if (k and r.trace is not None) else None
                     ),
                 )
-                pool.release(i)
             else:
                 self._quarantine(r)
-                pool.release(i)
                 if r.kind == "stream":
                     self._invalidate_stream(r.stream_id)
 
@@ -3029,7 +3136,7 @@ class ServeEngine:
             return self._pool_cap if pool is None else pool.free_count()
 
         with self._phase("serve/sched/poll"):
-            busy = bool(self._stream_checks) or any(
+            busy = bool(self._stream_checks or self._retiring) or any(
                 p.occupied_count() or p.pending for p in self._pools.values()
             )
             batch = self._queue.next_batch(
@@ -3158,7 +3265,9 @@ class ServeEngine:
         fed the last one's device arrays. The host sends the frames and
         the sessions' row numbers and fetches nothing; a prime's answer
         waits for its frame's finite flag (``_stream_settle``), a pair's
-        flag is read at its retirement."""
+        flag is read at its retirement — which is settled before the
+        session's next frame is planned."""
+        self._pool_settle(deferred=False, sessions={r.stream_id for r in live})
         with self._phase("serve/sched/stage"):
             bh, bw = pool.bucket
             rung = self._rung_admit(len(live))

@@ -610,10 +610,13 @@ class BucketPool:
         — both slots free while clients are still joining — held 13.4 GB
         where 10.0 is the pool's peak, 92.7% of the chip with the
         programs' reservation (builder's chip runs, PR 34; the same race
-        read as 16.3 GB in PR 33's). Behind a retirement the wait is
-        over before it starts: the retirement's fetch has drained the
-        device. The pyramid leaves are ``insert``'s own outputs, and no
-        tick replaces them."""
+        read as 16.3 GB in PR 33's). The pyramid leaves are ``insert``'s
+        own outputs, and no tick replaces them, so this waits for the
+        last admission's ``insert`` and for nothing dispatched after it.
+        Admission runs before the loop reads its retirement, so the wait
+        can be real; but that ``insert`` was dispatched a loop ago or
+        more, and the tick and the retirement dispatched since are queued
+        behind it: the device stays busy while the host waits."""
         jax.block_until_ready(self.state["pyramid"])
 
     def note_drain(self, now: float, token=None) -> None:

@@ -493,6 +493,9 @@ ENGINE_STATS_KEYS = frozenset({
     "encode_cache_misses", "encoder_cache_hit_rate", "expired",
     # PR 30: host bytes the pool's retirements fetched (beside completed)
     "fetched_bytes",
+    # PR 37: retirements read after a later tick's dispatch, and of those
+    # the ones whose arrays were all on the host when read
+    "retire_deferred", "retire_ready_at_settle",
     "idle_slot_iters", "inflight_peak", "invalid", "latency", "ledger",
     "mesh_devices", "nonfinite_batches", "obs", "padded_rows",
     "padding_waste", "pool", "pool_admitted", "pool_resets", "pool_ticks",
@@ -626,6 +629,13 @@ TRACE_RECORD_KEYS = frozenset({
     "error", "spans",
 })
 TRACE_SPAN_BASE_KEYS = frozenset({"name", "t0_ms", "dur_ms"})
+# the pool scheduler's loop record (docs/observability.md section 1):
+# what the loop did; PR 37 adds `deferred`, the retirements it read
+# after its tick was dispatched
+SCHED_RECORD_KEYS = TRACE_RECORD_KEYS | frozenset({
+    "ticked", "occupied", "pending", "admitted", "retired",
+    "admitted_rids", "retired_rids", "deferred", "cpu_ms",
+})
 # ISSUE 17: the QoS block every engine stats() carries (and the router
 # aggregates): per-class counters + the policy's per-tenant view. The
 # per-class value dict is pinned in tests/test_serve_zzz_qos.py next to
@@ -765,6 +775,22 @@ class TestStatsSchemaPin:
         for sp in rec["spans"]:
             assert TRACE_SPAN_BASE_KEYS <= frozenset(sp)
         assert rec["spans"][1]["proc"] == "worker-1"
+
+    def test_sched_record_schema(self, pool_engine):
+        rng = np.random.default_rng(3)
+        reqs = pool_engine.submit_many(
+            [{"image1": _image(rng), "image2": _image(rng)} for _ in range(3)]
+        )
+        for r in reqs:
+            assert r.wait(120.0) and r.error is None, r.error
+        time.sleep(0.2)  # the last loop's record closes one poll later
+        recs = [r for r in pool_engine.tracer.snapshot()
+                if r["kind"] == "sched"]
+        assert recs
+        for rec in recs:
+            assert frozenset(rec) == SCHED_RECORD_KEYS
+        # a retirement settled after its loop's tick says so
+        assert any(r["deferred"] for r in recs if r["retired"] and r["ticked"])
 
 
 # ---------------------------------------------------------------------------

@@ -242,6 +242,37 @@ class TestLoopRecords:
         # (the fetch is a bare ``np.asarray``: nothing of the engine's to time)
         assert seen == (ADMIT | RETIRE | TICK) - {"serve/sched/fetch"}
 
+    def test_a_retirement_is_read_after_the_loops_tick(self, tiny_model):
+        """A loop that retires and ticks opens ``serve/pool_step`` before
+        ``serve/sched/fetch`` (PR 37): the retirement is dispatched before
+        admission and read after the tick, ``complete`` after the read.
+        A loop that retires and has no resident left to tick reads at
+        once. The phase set is the one the tests above pin."""
+        with _pool_engine(tiny_model, trace_sample_rate=1.0) as eng:
+            _serve(eng, 6)
+            _quiesce(eng)
+            recs = _sched(eng)
+            stats = eng.stats()
+        retiring = [r for r in recs if r["retired"]]
+        assert retiring
+        for rec in retiring:
+            names = [s["name"] for s in rec["spans"][1:]]
+            assert set(names) <= ADMIT | RETIRE | TICK
+            # dispatched first, read last
+            assert names.index("serve/pool_final") < names.index(
+                "serve/sched/fetch"
+            ) < names.index("serve/sched/complete")
+            if rec["ticked"]:
+                assert names.index("serve/pool_step") < names.index(
+                    "serve/sched/fetch"
+                )
+                assert rec["deferred"] >= 1
+            else:
+                assert rec["deferred"] == 0
+        assert any(r["ticked"] for r in retiring)
+        assert sum(r["deferred"] for r in recs) == stats["retire_deferred"]
+        assert 0 <= stats["retire_ready_at_settle"] <= stats["retire_deferred"]
+
     def test_loop_records_stay_out_of_the_flight_recorder(self, tiny_model):
         with _pool_engine(tiny_model, trace_sample_rate=1.0) as eng:
             _serve(eng, 2)

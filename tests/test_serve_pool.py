@@ -545,6 +545,244 @@ class TestPoolChaos:
 # ---------------------------------------------------------------------------
 
 
+class TestDeferredRetirement:
+    """PR 37: a retirement is dispatched before the loop's admission (its
+    slots free at once) and read after the loop's tick. What must hold as
+    before: every request finishes once, drain waits for what is parked,
+    a session's next frame is planned only after its last pair settled,
+    no new host sync, and the same answers."""
+
+    @staticmethod
+    def _counting(eng, names):
+        calls = {n: 0 for n in names}
+        for n in names:
+            real = getattr(eng, n)
+
+            def counted(*a, _real=real, _n=n, **k):
+                calls[_n] += 1
+                return _real(*a, **k)
+
+            setattr(eng, n, counted)
+        return calls
+
+    @pytest.mark.parametrize("how", ["drain", "close"])
+    def test_drain_waits_for_a_parked_retirement(self, tiny_model, rng, how):
+        model, variables = tiny_model
+        eng = ServeEngine(model, variables, _config(
+            pool_capacity=2, max_batch=2, ladder=(2, 1), stream_cache_size=0,
+        ))
+        parked, release = threading.Event(), threading.Event()
+        real = eng._pool_settle
+
+        def settle(deferred, sessions=None):
+            if eng._retiring and not release.is_set():
+                parked.set()
+                release.wait(60.0)
+            return real(deferred, sessions)
+
+        eng._pool_settle = settle
+        eng.start()
+        fired, out = [], {}
+        try:
+            reqs = eng.submit_many(
+                [{"image1": _image(rng), "image2": _image(rng)}
+                 for _ in range(2)]
+            )
+            for r in reqs:
+                r.add_done_callback(lambda r_: fired.append(r_.rid))
+            assert parked.wait(120.0)
+            # both retired in one cohort: slots free, flows not yet read
+            assert all(p.occupied_count() == 0 for p in eng._pools.values())
+            assert not eng._quiesced()
+
+            def quiesce():
+                if how == "drain":
+                    out["ok"] = eng.drain(timeout=60.0)
+                else:
+                    eng.close(graceful=True, timeout=60.0)
+                    out["ok"] = True
+
+            t = threading.Thread(target=quiesce)
+            t.start()
+            time.sleep(0.3)
+            assert t.is_alive() and not any(r.done for r in reqs)
+            release.set()
+            t.join(60.0)
+            assert out == {"ok": True}
+        finally:
+            release.set()
+            eng.stop()
+        for r in reqs:
+            assert r.error is None and np.isfinite(r.result.flow).all()
+        assert sorted(fired) == sorted(r.rid for r in reqs)   # once each
+
+    def test_a_sessions_next_frame_waits_for_its_parked_pair(
+        self, tiny_model, rng, monkeypatch
+    ):
+        """Two frames of one session queued (the first one's caller still
+        waiting), the first pair poisoned: its retirement is settled —
+        quarantined, the session invalidated — before the second frame is
+        planned, so the second frame primes instead of pairing with what
+        the poisoned pair left, and the one after pairs cold."""
+        model, variables = tiny_model
+        eng = ServeEngine(model, variables, _config(
+            pool_capacity=2, max_batch=2, ladder=(2, 1), stream_cache_size=2,
+            stream_warm_start=True,
+        ))
+        frames = [_image(rng) for _ in range(5)]
+        inj = FaultInjector()
+        seen, out, planned = {}, {}, []
+        with eng:
+            stream = eng.open_stream()
+            sid = stream.stream_id
+            assert stream.submit(frames[0]).primed
+            assert np.isfinite(stream.submit(frames[1]).flow).all()
+            cache = eng._stream_cache
+            real_begin, real_plan = cache.begin_frame, cache.plan
+            real_retire = eng._pool_retire
+
+            def third():
+                # the caller of the poisoned pair still waits: queue the
+                # session's next frame beside it
+                monkeypatch.setattr(
+                    cache, "begin_frame",
+                    lambda s, b, hw: (cache.end_frame(s), real_begin(s, b, hw))[1],
+                )
+                out["third"] = stream.submit(frames[3])
+
+            def retire(pool):
+                real_retire(pool)
+                held = [r for co in eng._retiring for r in co.live
+                        if r.stream_id == sid]
+                if held and "first" not in seen:
+                    seen["first"] = held[0]
+                    threading.Thread(target=third, daemon=True).start()
+                    t_end = time.monotonic() + 10.0
+                    while not eng._queue.depth() and time.monotonic() < t_end:
+                        time.sleep(0.001)
+                    assert eng._queue.depth() == 1
+
+            def plan(live, rung):
+                first = seen.get("first")
+                planned.extend(
+                    (r.rid, first is not None and first.done) for r in live
+                )
+                return real_plan(live, rung)
+
+            monkeypatch.setattr(cache, "plan", plan)
+            with inj.patch_engine(eng):
+                inj.on(
+                    "infer.nan_flow",
+                    when=lambda i, ctx: ctx["rid"] == seen["first"].rid,
+                    action=FaultInjector.nan_flow,
+                )
+                monkeypatch.setattr(eng, "_pool_retire", retire)
+                with pytest.raises(PoisonedInput):
+                    stream.submit(frames[2])
+                t_end = time.monotonic() + 30.0
+                while "third" not in out and time.monotonic() < t_end:
+                    time.sleep(0.01)
+            assert out["third"].primed and out["third"].flow is None
+            # planned once the poisoned pair was finished and forgotten
+            third_rid = out["third"].rid
+            assert [done for rid, done in planned if rid == third_rid] == [True]
+            last = stream.submit(frames[4])
+            assert not last.warm_started and np.isfinite(last.flow).all()
+            stream.close()
+        st = eng.stats()
+        assert st["quarantined"] == 1 and st["stream_invalidations"] >= 1
+        assert st["worker_errors"] == 0
+
+    @pytest.mark.parametrize(
+        "clients", [1, 4], ids=["one_at_a_time", "closed_loop"]
+    )
+    def test_no_new_host_sync(self, tiny_model, rng, clients, monkeypatch):
+        """``HostSyncTripwire`` over a window of served pairs, with the
+        engine's ``np.asarray`` of a device array counted beside it (on
+        the CPU numpy reads such an array through the buffer protocol,
+        which the tripwire does not see): the syncs are the parent's
+        sites and no more — one ``await_rows`` an admission, one pacing
+        fetch a drained tick, flow + residuals a retirement cohort
+        (``copy_to_host_async`` and ``is_ready`` are no syncs). Alone in
+        the pool a request costs 4, as before."""
+        import jax
+
+        from raft_tpu.serve import engine as engine_mod
+        from raft_tpu.utils.tripwire import HostSyncTripwire
+
+        class Fetches:
+            n = 0
+
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            def asarray(self, a, *args, **kw):
+                if isinstance(a, jax.Array):
+                    Fetches.n += 1
+                return np.asarray(a, *args, **kw)
+
+        model, variables = tiny_model
+        eng = ServeEngine(model, variables, _config(
+            pool_capacity=2, max_batch=2, ladder=(3, 1), stream_cache_size=0,
+            warmup=True,
+        ))
+        pairs = [(_image(rng), _image(rng)) for _ in range(8)]
+        with eng:
+            for a, b in pairs[:2]:       # every program has run once
+                eng.submit(a, b)
+            calls = self._counting(
+                eng, ("_pool_insert_live", "_pool_tick_drain",
+                      "_pool_complete"),
+            )
+            monkeypatch.setattr(engine_mod, "np", Fetches())
+            with HostSyncTripwire() as tw:
+                with ThreadPoolExecutor(clients) as ex:
+                    res = list(ex.map(lambda p: eng.submit(*p), pairs))
+                syncs = tw.total + Fetches.n
+            monkeypatch.undo()
+        assert all(np.isfinite(r.flow).all() for r in res)
+        assert syncs == (
+            calls["_pool_insert_live"] + calls["_pool_tick_drain"]
+            + 2 * calls["_pool_complete"]
+        ), (tw.snapshot(), calls)
+        if clients == 1:
+            assert syncs == 4 * len(pairs)
+
+    def test_closed_loop_answers_as_one_at_a_time(self, tiny_model, rng):
+        """Flows, residual trajectories, iteration counts and exit reasons
+        of a closed loop (retirements read after a later tick) are those
+        of the same requests served one at a time (each read at once),
+        bit for bit: ``max_batch`` 1 keeps admission and retirement at
+        rung 1 on both sides, and a slot's step does not depend on its
+        neighbours."""
+        model, variables = tiny_model
+        eng = ServeEngine(model, variables, _config(
+            pool_capacity=2, max_batch=1, ladder=(3, 2, 1),
+            stream_cache_size=0, trace_sample_rate=1.0,
+        ))
+        asks = [3, 2, 1, 3, 1, 2, 3, 3]
+        pairs = [(_image(rng), _image(rng)) for _ in asks]
+
+        def serve(a_b_n):
+            a, b, n = a_b_n
+            return eng.submit(a, b, num_flow_updates=n)
+
+        work = [(a, b, n) for (a, b), n in zip(pairs, asks)]
+        with eng:
+            alone = [serve(w) for w in work]
+            before = eng.stats()["retire_deferred"]
+            with ThreadPoolExecutor(4) as ex:
+                loop = list(ex.map(serve, work))
+            deferred = eng.stats()["retire_deferred"] - before
+        assert deferred >= 1                     # the closed loop deferred
+        for x, y in zip(alone, loop):
+            assert np.array_equal(x.flow, y.flow)
+            assert x.residuals == y.residuals and x.residuals
+            assert (x.num_flow_updates, x.exit_reason) == (
+                y.num_flow_updates, y.exit_reason
+            )
+
+
 class TestPoolWarmup:
     def test_no_compile_after_warmup(self, tiny_model, rng):
         """After warmup no admitted traffic pattern — mixed per-request
