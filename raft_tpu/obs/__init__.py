@@ -1,7 +1,7 @@
 """raft_tpu.obs — the shared observability spine (ISSUE 10 + 11 + 15).
 
-Six pillars, one seam across frontend -> router -> engine -> pool ->
-trainer (docs/observability.md). The sixth (ISSUE 15) is *trace
+Five pillars, one seam across frontend -> router -> engine -> pool ->
+trainer (docs/observability.md). The fifth is *trace
 propagation*: a ``trace_id`` born at the HTTP front door rides the
 dispatch path and the IPC wire (:class:`~raft_tpu.obs.trace
 .TraceContext`), every process's spans are stitched back into ONE
@@ -23,33 +23,30 @@ lanes.
     dumped as a postmortem bundle when a ``Watchdog`` trips, a replica
     is evicted, ``DivergenceError`` raises, or a page-severity alert
     fires (``scripts/postmortem.py`` reads the bundle back).
-  * **Device-time ledger** (:mod:`raft_tpu.obs.ledger`, ISSUE 11) —
-    deterministic counter-sampled timed dispatches per program family
-    (pool begin/insert/step/final, pairwise rungs, encode, the trainer
-    window step): EWMA + sub-ms histograms of device milliseconds,
-    exposed as ``engine.device_time_breakdown()`` / the ``ledger``
-    stats block / Prometheus.
   * **Burn-rate alerting** (:mod:`raft_tpu.obs.alerts`, ISSUE 11) —
     multi-window burn-rate rules over registry snapshots (SLO miss
-    fraction, shed, quarantine, watchdog trips, device-time drift,
-    tier evictions); fire/resolve are flight-recorder events and
-    page-severity rules auto-dump a postmortem.
+    fraction, shed, quarantine, watchdog trips, tier evictions);
+    fire/resolve are flight-recorder events and page-severity rules
+    auto-dump a postmortem.
 
 :mod:`raft_tpu.obs.profile` additionally toggles ``jax.profiler`` trace
-annotations around the dispatch windows.
+annotations around the dispatch windows, and keeps the pool scheduler's
+phase totals (:class:`~raft_tpu.obs.profile.PhaseTable`): every phase of
+its loop — a wait on the device (``WAIT_PHASES`` in ``serve/engine.py``)
+or host work, never both — timed and counted at full pace, sampled or
+not, as ``engine.stats()["sched"]`` and the ``sched`` counter group in
+Prometheus. Device time itself is the device trace's to give
+(``jax.profiler``; the benchmark's ``device_trace`` metrics).
 """
 
 from raft_tpu.obs import profile
 from raft_tpu.obs.alerts import (
     AlertEngine,
     AlertRule,
-    gauge_value,
     rate,
     ratio_rate,
 )
-from raft_tpu.obs.ledger import DeviceTimeLedger
 from raft_tpu.obs.metrics import (
-    DEVICE_TIME_BUCKETS_MS,
     LATENCY_BUCKETS_MS,
     RESIDUAL_BUCKETS,
     Counter,
@@ -80,14 +77,11 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "LATENCY_BUCKETS_MS",
-    "DEVICE_TIME_BUCKETS_MS",
     "RESIDUAL_BUCKETS",
-    "DeviceTimeLedger",
     "AlertEngine",
     "AlertRule",
     "rate",
     "ratio_rate",
-    "gauge_value",
     "FlightRecorder",
     "SCHEMA",
     "file_sink",

@@ -21,9 +21,8 @@ burn), so alert history rides every postmortem bundle; a rule with
 operator gets around to it.
 
 Wiring (ISSUE 11): ``ServeEngine`` evaluates a default engine rule set
-(SLO burn = expired+shed fraction, quarantine, watchdog trips,
-device-time EWMA drift via :class:`~raft_tpu.obs.ledger
-.DeviceTimeLedger`) from its worker loop; ``ServeRouter`` evaluates tier
+(SLO burn = expired+shed fraction, quarantine, watchdog trips) from
+its worker loop; ``ServeRouter`` evaluates tier
 rules (evictions, heartbeat misses, fleet-wide shed) from its monitor
 thread; both expose ``alerts()`` and per-rule Prometheus gauges. The
 engine never raises into the loop that drives it.
@@ -42,7 +41,6 @@ __all__ = [
     "AlertEngine",
     "rate",
     "ratio_rate",
-    "gauge_value",
 ]
 
 BurnFn = Callable[[Dict[str, float], Dict[str, float], float], float]
@@ -74,16 +72,6 @@ def ratio_rate(num_keys, den_key: str) -> BurnFn:
             max(0.0, cur.get(k, 0) - prev.get(k, 0)) for k in num_keys
         )
         return num / den
-
-    return burn
-
-
-def gauge_value(key: str) -> BurnFn:
-    """Burn = the current value of a gauge-like snapshot key (e.g. the
-    device-time drift ratio) — windows then just demand persistence."""
-
-    def burn(prev, cur, dt):
-        return float(cur.get(key, 0.0))
 
     return burn
 

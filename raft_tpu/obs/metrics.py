@@ -44,7 +44,6 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "LATENCY_BUCKETS_MS",
-    "DEVICE_TIME_BUCKETS_MS",
     "RESIDUAL_BUCKETS",
     "relabel_prometheus",
 ]
@@ -56,14 +55,8 @@ LATENCY_BUCKETS_MS: Tuple[float, ...] = (
     1000.0, 2500.0, 5000.0, 10000.0, 30000.0,
 )
 
-# Per-instrument bucket sets (ISSUE 11). Device-time samples need sub-ms
-# resolution — a pool tick on a warm accelerator is fractions of a
-# millisecond, far below the request-latency buckets' floor — and
-# flow-update residuals live on a log scale in 1/8-grid pixels.
-DEVICE_TIME_BUCKETS_MS: Tuple[float, ...] = (
-    0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
-    250.0, 500.0, 1000.0, 5000.0,
-)
+# Per-instrument bucket sets: flow-update residuals live on a
+# log scale in 1/8-grid pixels, far below the request-latency buckets.
 RESIDUAL_BUCKETS: Tuple[float, ...] = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
     2.5, 5.0, 10.0,
@@ -286,6 +279,13 @@ class MetricsRegistry:
                     g._values.setdefault(k, 0)
             return g
 
+    def add_group(self, group) -> None:
+        """Register a counter group kept elsewhere: anything with a
+        ``name`` and a ``snapshot()`` of ``{key: number}`` (the pool
+        scheduler's :class:`~raft_tpu.obs.profile.PhaseTable`)."""
+        with self._lock:
+            self._groups[group.name] = group
+
     def gauge(
         self, name: str, fn: Optional[Callable[[], float]] = None,
         help: str = "",
@@ -308,9 +308,9 @@ class MetricsRegistry:
         (``LATENCY_BUCKETS_MS`` on first registration). Explicit bounds
         are honored on first registration; explicitly re-registering an
         instrument with *different* bounds raises instead of silently
-        keeping the old ones (ISSUE 11 fix — device-time needs finer
-        sub-ms buckets than request latency, and a dropped bucket spec
-        must fail loudly, not misbucket quietly)."""
+        keeping the old ones (residuals need other
+        buckets than request latency, and a dropped bucket spec must
+        fail loudly, not misbucket quietly)."""
         with self._lock:
             h = self._histograms.get(name)
             if h is None:

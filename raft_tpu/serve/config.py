@@ -254,28 +254,15 @@ class ServeConfig:
             request. Sampled traces feed ``stats()['obs']``, the flight
             recorder's last-N ring, and ``serve_bench
             --trace-sample``'s per-phase latency breakdown.
-        ledger_sample_every: device-time ledger cadence (ISSUE 11,
-            :mod:`raft_tpu.obs.ledger`): every Kth execution of each
-            program family (pool begin/insert/step/final per
-            bucket+rung, pairwise rungs, encode) runs as a timed
-            dispatch — ``block_until_ready`` around the enqueue — and
-            feeds per-family EWMA + sub-ms histograms of device
-            milliseconds (``engine.device_time_breakdown()``, the
-            ``ledger`` stats block, Prometheus). Deterministic
-            counter-based sampling, same no-RNG discipline as
-            ``trace_sample_rate``; 0 (default) disables, 1 times every
-            dispatch (exact attribution, serializes the pipeline at
-            each sampled seam — overhead A/B-bounded < 5% on the tiny
-            smoke).
         alert_short_window_s / alert_long_window_s: the two windows of
             the burn-rate alert engine (:mod:`raft_tpu.obs.alerts`). A
             rule fires only when its burn exceeds threshold over BOTH
             windows (fast detection + blip rejection) and resolves with
             hysteresis. Engine rules: SLO burn (expired+shed fraction of
             submissions, page severity — fires the postmortem dump),
-            quarantine fraction, watchdog-trip rate, device-time EWMA
-            drift. Exposed via ``engine.alerts()`` / the ``alerts``
-            stats block / per-rule Prometheus gauges.
+            quarantine fraction, watchdog-trip rate. Exposed via
+            ``engine.alerts()`` / the ``alerts`` stats block / per-rule
+            Prometheus gauges.
         latency_window: per-bucket ring-buffer size for p50/p99 tracking.
         log_every_batches: serving-counter cadence through ``MetricLogger``.
         qos_enabled: multi-tenant QoS enforcement (ISSUE 17). Off
@@ -345,7 +332,6 @@ class ServeConfig:
     corr_impl: Optional[str] = None
     drain_retry_after_ms: float = 2000.0
     trace_sample_rate: float = 0.0
-    ledger_sample_every: int = 0
     alert_short_window_s: float = 5.0
     alert_long_window_s: float = 60.0
     latency_window: int = 256
@@ -602,11 +588,6 @@ class ServeConfig:
             raise ValueError(
                 f"trace_sample_rate must be in [0, 1], got "
                 f"{self.trace_sample_rate}"
-            )
-        if self.ledger_sample_every < 0:
-            raise ValueError(
-                f"ledger_sample_every must be >= 0 (0 = off), got "
-                f"{self.ledger_sample_every}"
             )
         if not (0 < self.alert_short_window_s <= self.alert_long_window_s):
             raise ValueError(

@@ -126,9 +126,9 @@ import numpy as np
 
 from raft_tpu.inference import FlowEstimator
 from raft_tpu.obs import (
-    RESIDUAL_BUCKETS, AlertEngine, AlertRule, DeviceTimeLedger,
-    FlightRecorder, MetricsRegistry, TraceContext, Tracer, gauge_value,
-    logger_sink, profile, rate, ratio_rate,
+    RESIDUAL_BUCKETS, AlertEngine, AlertRule, FlightRecorder,
+    MetricsRegistry, TraceContext, Tracer, logger_sink, profile, rate,
+    ratio_rate,
 )
 from raft_tpu.serve import aot
 from raft_tpu.serve.bucketing import BucketRouter, TokenBucket
@@ -326,6 +326,16 @@ def _bytes_limit(device) -> Optional[int]:
 # engine trace ring: a 6 s traced window of the busiest cell finishes
 # ~160 request records and ~310 scheduler-loop records
 _TRACE_RING = 4096
+
+# The pool scheduler's phases that wait on the device, and nothing else:
+# the rows guard before an admission (_await_rows), the oldest tick's
+# pacing token (drain), and the reads of a retirement's flows or a stream
+# prime's finite flag (fetch). Every other phase is host work, so device
+# idle under one of these is the device's own doing, idle under another
+# the host's (docs/observability.md, section 1).
+WAIT_PHASES = AWAIT_ROWS, DRAIN, FETCH = (
+    "serve/sched/await_rows", "serve/sched/drain", "serve/sched/fetch",
+)
 
 
 class _SchedLoop:
@@ -651,6 +661,9 @@ class ServeEngine:
         # the pool scheduler's current loop record, when that loop is
         # sampled (see _sched_turn); _phase appends to it
         self._loop: Optional[_SchedLoop] = None
+        # the scheduler's phases and its loop, timed and counted at full
+        # pace whether or not a loop is sampled (stats()["sched"])
+        self._phases = profile.PhaseTable("sched")
         if logger is not None:
             # postmortem bundles persist through the logger's structured
             # events file (MetricLogger.log_event)
@@ -687,12 +700,7 @@ class ServeEngine:
             ),
         )
         self._latency_hist = self.metrics.histogram("latency_ms")
-        # Device-time ledger (ISSUE 11): counter-sampled timed dispatches
-        # per program family; registry-backed so every family's sub-ms
-        # histogram reaches Prometheus with no extra wiring.
-        self.ledger = DeviceTimeLedger(
-            cfg.ledger_sample_every, registry=self.metrics
-        )
+        self.metrics.add_group(self._phases)
         # Convergence telemetry (ISSUE 11, pool mode): final-residual
         # distribution + the iters-vs-residual table (per-iteration sums
         # and counts, host-side, a few floats per retirement).
@@ -719,10 +727,6 @@ class ServeEngine:
                 AlertRule(
                     "watchdog_trips", rate("watchdog_trips"), 0.0, s_w, l_w,
                     severity="page",
-                ),
-                AlertRule(
-                    "device_time_drift", gauge_value("device_time_drift"),
-                    1.5, s_w, l_w,
                 ),
             ),
             snapshot_fn=self._alert_snapshot,
@@ -1924,10 +1928,11 @@ class ServeEngine:
                 "events_recorded": self.recorder.events_recorded,
                 "postmortem_dumps": self.recorder.dumps,
             },
-            # device-time ledger (ISSUE 11): slot-iter cost priced in
-            # milliseconds — the full per-family table lives on
-            # engine.device_time_breakdown()
-            "ledger": self.ledger.breakdown(),
+            # the pool scheduler's loop by phase, at full pace: loops,
+            # their wall and thread-CPU ms, and per phase (per bucket
+            # where it has one) its ms and entries
+            # (docs/observability.md, section 1)
+            "sched": self._phases.report(),
             # burn-rate alerting (ISSUE 11)
             "alerts": self._alerts.snapshot(),
             # convergence telemetry (ISSUE 11, pool mode): final-residual
@@ -1978,11 +1983,12 @@ class ServeEngine:
 
     def prometheus(self) -> str:
         """Prometheus text exposition of this engine's metrics registry
-        (counters, queue/degradation/pool gauges, latency + device-time
-        histograms, per-alert-rule gauges), plus the QoS series: per-class
-        counters labeled ``class=`` and per-tenant quota state labeled
-        ``tenant=`` (ISSUE 17) — dashboards slice overload by who paid
-        for it, not just how much of it there was."""
+        (counters, the scheduler's phase totals, queue/degradation/pool
+        gauges, latency histograms, per-alert-rule gauges), plus the QoS
+        series: per-class counters labeled ``class=`` and per-tenant
+        quota state labeled ``tenant=`` — dashboards slice
+        overload by who paid for it, not just how much of it there
+        was."""
         text = self.metrics.prometheus_text()
 
         def esc(s: str) -> str:
@@ -2009,17 +2015,6 @@ class ServeEngine:
                     )
         return text + "\n".join(lines) + "\n"
 
-    def device_time_breakdown(self) -> Dict[str, Any]:
-        """Per-program-family device-time attribution (ISSUE 11).
-
-        Each family the ledger has sampled reports executions, sampled
-        count, mean/EWMA/p50/p99 device ms, the extrapolated total, and
-        its ``share`` of estimated device time — milliseconds, not row
-        counts. Empty (``families == 0``) when
-        ``config.ledger_sample_every == 0``.
-        """
-        return self.ledger.breakdown()
-
     def alerts(self) -> Dict[str, Any]:
         """The burn-rate alert surface: active alerts (rule, severity,
         live burn), fire/resolve counters, and the configured rules."""
@@ -2028,12 +2023,10 @@ class ServeEngine:
         return snap
 
     def _alert_snapshot(self) -> Dict[str, float]:
-        """What the alert rules see: the engine counters plus the
-        device-time drift gauge, one flat dict."""
+        """What the alert rules see: the engine counters, one flat
+        dict."""
         with self._lock:
-            snap: Dict[str, float] = dict(self._counters)
-        snap["device_time_drift"] = self.ledger.drift()
-        return snap
+            return dict(self._counters)
 
     def program_counts(self) -> Dict[str, int]:
         """Compiled-program count per program family (-1 if unsupported).
@@ -2603,11 +2596,13 @@ class ServeEngine:
         t0 = time.monotonic()
 
         def run_encode():
-            fm, cx, finite = self._run_encode(frames)
+            with profile.annotate("serve/encode"):
+                fm, cx, finite = self._run_encode(frames)
             co = self._stream_cache.plan(live, rung)
-            f1, c1, _ = self._run_stream_swap(
-                bucket, fm, cx, co.idx, co.put, co.warm
-            )
+            with profile.annotate("serve/stream_swap"):
+                f1, c1, _ = self._run_stream_swap(
+                    bucket, fm, cx, co.idx, co.put, co.warm
+                )
             return fm, f1, c1, co, np.asarray(finite)
 
         out, tripped = self._guarded_dispatch(live, run_encode)
@@ -2874,13 +2869,15 @@ class ServeEngine:
             by_rung = self._admits_by_rung.setdefault(pool.bucket, {})
             by_rung[rung] = by_rung.get(rung, 0) + 1
 
-    def _await_rows(self) -> None:
-        """Block until the last admission's rows are in their slots —
-        whatever its bucket — so that the buffer they were computed into
-        is free before the next admission's is allocated (a dispatch
-        allocates its outputs when it is enqueued, not when it runs): one
-        admission's rows are on the device at a time, engine-wide, which
-        is what the budget counts. A row is the size of a slot: 3.3 GB at
+    def _await_rows(self) -> float:
+        """In a phase of its own (``serve/sched/await_rows``, a wait on
+        the device and nothing else), block until the last admission's
+        rows are in their slots — whatever its bucket — so that the
+        buffer they were computed into is free before the next
+        admission's is allocated (a dispatch allocates its outputs when
+        it is enqueued, not when it runs): one admission's rows are on
+        the device at a time, engine-wide, which is what the budget
+        counts. A row is the size of a slot: 3.3 GB at
         1088x1920, where two admissions enqueued a tick apart — both
         slots free while clients are still joining — held 13.4 GB where
         10.0 is the pool's peak, 92.7% of the chip with the programs'
@@ -2891,9 +2888,12 @@ class ServeEngine:
         Admission runs before the loop reads its retirement, so the wait
         can be real; but that ``insert`` was dispatched a loop ago or
         more, and the ticks and the retirement dispatched since are
-        queued behind it: the device stays busy while the host waits."""
-        if self._rows_pool is not None:
-            jax.block_until_ready(self._rows_pool.state["pyramid"])
+        queued behind it: the device stays busy while the host waits.
+        Returns the moment the wait ended: the next dispatch's start."""
+        with self._phase(AWAIT_ROWS):
+            if self._rows_pool is not None:
+                jax.block_until_ready(self._rows_pool.state["pyramid"])
+        return time.monotonic()
 
     def _worker_pool(self) -> None:
         """The iteration-pool worker: one GRU iteration per dispatch.
@@ -2915,8 +2915,10 @@ class ServeEngine:
         the loop's admission and tick queued behind them.
 
         Every step of the loop runs inside one ``_phase`` (flat: never
-        two open at once), so a profiler capture and a sampled loop's
-        ``"sched"`` record both show where an iteration went
+        two open at once), so a profiler capture, a sampled loop's
+        ``"sched"`` record and the always-on totals (``stats()["sched"]``)
+        all show where an iteration went; a phase either waits on the
+        device (``WAIT_PHASES``) or works on the host, never both
         (docs/observability.md lists the phases).
         """
         while not self._stop.is_set():
@@ -2926,7 +2928,7 @@ class ServeEngine:
                 self._sched_seal(closed, loop)
                 self._log_counters()
                 self._alerts.maybe_observe()
-                self._stream_settle()
+            self._stream_settle()
             try:
                 for pool in list(self._pools.values()):
                     self._pool_guard(pool, self._pool_retire, "retirement")
@@ -2996,44 +2998,45 @@ class ServeEngine:
         for pool in list(self._pools.values()):
             self._pool_fail(pool, err)
 
-    def _phase(self, name: str, **args):
-        """The one phase helper of the pool scheduler's loop: a profiler
-        region when ``obs.profile`` is on, and ``(name, t0, t1)`` on the
-        current loop's record when that loop is sampled (``args``, such
-        as the bucket a tick is for, ride both). Both off: two truth
-        tests, no clock read, no allocation."""
-        loop = self._loop
-        return profile.phase(
-            name, None if loop is None else loop.phases, **args
-        )
+    def _phase(self, name: str, bucket: Optional[str] = None):
+        """The one phase helper of the pool scheduler's loop
+        (``obs.profile.PhaseTable.phase``): a phase built once, whose
+        every entry is timed and counted in ``stats()["sched"]``, is a
+        profiler region when ``obs.profile`` is on, and lands as
+        ``(name, t0, t1)`` on the current loop's record when that loop is
+        sampled (``bucket``, the pool a tick or an admission is for,
+        rides both and keys a total of its own). Two clock reads, no
+        allocation."""
+        return self._phases.phase(name, bucket)
 
     def _sched_turn(self, last: bool = False) -> Optional[_SchedLoop]:
-        """Top of a scheduler-loop iteration: close the previous
-        iteration's record, if it was sampled, and open this one's, if it
-        is. One clock reading serves as the end of one ``loop`` span and
-        the start of the next, so sampled loops tile the thread's time.
-        Reads no clock when neither iteration is sampled. Returns the
-        closed record, for ``_sched_seal`` (which the loop calls inside
-        its first phase: the turn itself stays a few microseconds)."""
+        """Top of a scheduler-loop iteration: count the loop that ended
+        in the phase totals, close its record, if it was sampled, and
+        open this one's, if it is. One clock reading serves as the end of
+        one loop and the start of the next, so loops tile the thread's
+        time. Returns the closed record, for ``_sched_seal`` (which the
+        loop calls inside its first phase: the turn itself stays a few
+        microseconds)."""
         prev = self._loop
         trace = None if last else self.tracer.start_loop("sched")
-        if prev is None and trace is None:
-            return None
+        now = time.monotonic() if trace is None else trace.t_start
+        self._phases.turn(now)
         if prev is not None:
-            prev.t_end = time.monotonic() if trace is None else trace.t_start
+            prev.t_end = now
         self._loop = None if trace is None else _SchedLoop(trace)
+        self._phases.sink = None if trace is None else self._loop.phases
         return prev
 
     def _sched_seal(
         self, closed: Optional[_SchedLoop], opened: Optional[_SchedLoop]
     ) -> None:
         """Read the thread's CPU clock once, for the loop that ended and
-        the one that began, and finish the ended loop's ``"sched"``
-        record: the ``loop`` span, its phases as children, and what the
-        iteration did. Nothing sampled: nothing read."""
-        if closed is None and opened is None:
-            return
+        the one that began (the phase totals' ``cpu_ms``), and finish the
+        ended loop's ``"sched"`` record, where it was sampled: the
+        ``loop`` span, its phases as children, and what the iteration
+        did."""
         cpu = time.thread_time()
+        self._phases.cpu(cpu)
         if opened is not None:
             opened.cpu0 = cpu
         if closed is None or not (
@@ -3176,9 +3179,11 @@ class ServeEngine:
                     pool.state["coords1"], pool.state["hidden"],
                     pool.state["resid_hist"], idx,
                 )
-            flow = self._run_pool_final(c1, hid)
+            with self._phase("serve/pool_final"):
+                flow = self._run_pool_final(c1, hid)
             if flow_rows:
-                self._run_stream_store_flow(pool.bucket, c1, *flow_rows)
+                with self._phase("serve/stream_store_flow"):
+                    self._run_stream_store_flow(pool.bucket, c1, *flow_rows)
             return flow, res, c1 if fetch_c1 else None
 
         out, tripped = self._guarded_dispatch(live, run)
@@ -3221,7 +3226,7 @@ class ServeEngine:
                 return  # a failed cohort took its pool's others with it
             co = self._retiring[0]
             try:
-                with self._phase("serve/sched/fetch"):
+                with self._phase(FETCH):
                     ready = deferred and all(
                         a.is_ready() for a in co.arrays()
                     )
@@ -3413,9 +3418,10 @@ class ServeEngine:
                 return
             live = plain
         p1, p2, rung, t0 = self._pool_stage_pairs(pool, live)
-        rows, tripped = self._guarded_dispatch(
-            live, lambda: self._run_pool_begin(p1, p2)
-        )
+        with self._phase("serve/pool_begin", pool.label):
+            rows, tripped = self._guarded_dispatch(
+                live, lambda: self._run_pool_begin(p1, p2)
+            )
         if tripped:
             return
         self._trace_span(live, "dispatch", t0, rung=rung)
@@ -3423,8 +3429,9 @@ class ServeEngine:
 
     def _pool_stage_pairs(self, pool: BucketPool, live: List[Request]):
         """Copy the cohort's frames into the rotating staging buffers
-        (the ``batch_form`` span). Returns ``(p1, p2, rung, t)`` with
-        ``t`` the moment staging ended and the dispatch begins."""
+        (the ``batch_form`` span), then wait for the last admission's
+        rows. Returns ``(p1, p2, rung, t)`` with ``t`` the moment the
+        wait ended and the dispatch begins."""
         with self._phase("serve/sched/stage"):
             bh, bw = pool.bucket
             rung = self._rung_admit(pool, len(live))
@@ -3438,11 +3445,8 @@ class ServeEngine:
             p2 = self._staging.fill(
                 ("pool_p2", pool.bucket), shape, [r.p2 for r in live], rung
             )
-            # one admission's rows on the device at a time
-            self._await_rows()
-            t0 = time.monotonic()
-            self._trace_span(live, "batch_form", t_form, t0, rung=rung)
-        return p1, p2, rung, t0
+            self._trace_span(live, "batch_form", t_form, rung=rung)
+        return p1, p2, rung, self._await_rows()
 
     def _pool_admit_pairs_seeded(
         self, pool: BucketPool, live: List[Request], ctrl_iters: int,
@@ -3461,9 +3465,10 @@ class ServeEngine:
         like the stream path's.
         """
         p1, p2, rung, t_e = self._pool_stage_pairs(pool, live)
-        out, tripped = self._guarded_dispatch(
-            live, lambda: (self._run_encode(p1), self._run_encode(p2))
-        )
+        with self._phase("serve/encode"):
+            out, tripped = self._guarded_dispatch(
+                live, lambda: (self._run_encode(p1), self._run_encode(p2))
+            )
         if tripped:
             return
         (f1, c1, _), (f2, _c2, _) = out
@@ -3475,9 +3480,10 @@ class ServeEngine:
                 [r.init8 for r in live], rung,
             )
             t0 = time.monotonic()
-        rows, tripped = self._guarded_dispatch(
-            live, lambda: self._run_pool_begin_features(f1, f2, c1, ifl)
-        )
+        with self._phase("serve/pool_begin_features"):
+            rows, tripped = self._guarded_dispatch(
+                live, lambda: self._run_pool_begin_features(f1, f2, c1, ifl)
+            )
         if tripped:
             return
         self._trace_span(live, "dispatch", t0, rung=rung)
@@ -3508,19 +3514,20 @@ class ServeEngine:
             )
             co = self._stream_cache.plan(live, rung)
             self._count_stream_frames(co)
-            # one admission's rows on the device at a time
-            self._await_rows()
-            t_e = time.monotonic()
+        t_e = self._await_rows()
 
         def run():
-            fm, cx, finite = self._run_encode(frames)
-            f1, c1, ifl = self._run_stream_swap(
-                pool.bucket, fm, cx, co.idx, co.put, co.warm
-            )
+            with self._phase("serve/encode"):
+                fm, cx, finite = self._run_encode(frames)
+            with self._phase("serve/stream_swap"):
+                f1, c1, ifl = self._run_stream_swap(
+                    pool.bucket, fm, cx, co.idx, co.put, co.warm
+                )
             t0 = time.monotonic()
             if not co.pairs:
                 return finite, None, t0
-            rows = self._run_pool_begin_features(f1, fm, c1, ifl)
+            with self._phase("serve/pool_begin_features"):
+                rows = self._run_pool_begin_features(f1, fm, c1, ifl)
             return finite, rows, t0
 
         (finite, rows, t0), tripped = self._guarded_dispatch(live, run)
@@ -3544,7 +3551,9 @@ class ServeEngine:
         has run — which the loop learns without waiting (``is_ready``)
         while the pool ticks, and by waiting where nothing else would
         run on the device anyway (no resident, so no tick, drain or
-        retirement fetch this loop)."""
+        retirement fetch this loop). The read is the loop's ``fetch`` (a
+        wait on the device where it is one), the answers its
+        ``complete``."""
         if not self._stream_checks:
             return
         idle = not any(p.occupied_count() for p in self._pools.values())
@@ -3553,7 +3562,10 @@ class ServeEngine:
             if not (idle or finite.is_ready()):
                 return
             self._stream_checks.popleft()
-            self._settle_primes(primes, np.asarray(finite), iters, level)
+            with self._phase(FETCH):
+                finite = np.asarray(finite)
+            with self._phase("serve/sched/complete"):
+                self._settle_primes(primes, finite, iters, level)
 
     def _pool_insert_live(
         self, pool: BucketPool, rows, live: List[Request], ctrl_iters: int,
@@ -3634,7 +3646,7 @@ class ServeEngine:
         ):
             # the wait on the device (the oldest tick's pacing token)
             # has a phase to itself, apart from the dispatch above
-            with self._phase("serve/sched/drain"):
+            with self._phase(DRAIN):
                 if not self._pool_tick_drain(pool, live):
                     return
 
@@ -3746,30 +3758,20 @@ class ServeEngine:
         """Dispatch one pool admission (pair encode + state init); seam."""
         key = ("pool_begin_pair", p1.shape[0], p1.shape[1], p1.shape[2])
         ex = self._aot_execs.get(key)
-        with self._phase("serve/pool_begin", bucket=f"{key[2]}x{key[3]}"):
-            if ex is not None:
-                return self.ledger.run(key, lambda: ex(self._dev_vars, p1, p2))
-            return self.ledger.run(
-                key,
-                lambda: self._pool_progs.begin_pair(self._dev_vars, p1, p2),
-            )
+        if ex is not None:
+            return ex(self._dev_vars, p1, p2)
+        return self._pool_progs.begin_pair(self._dev_vars, p1, p2)
 
     def _run_pool_begin_features(self, f1, f2, ctx, init_flow):
         """Dispatch one pool admission from cached stream features (with
         the traced warm-start seed, zeros for a cold start); seam."""
         key = ("pool_begin_features", f1.shape[0], f1.shape[1], f1.shape[2])
         ex = self._aot_execs.get(key)
-        with self._phase("serve/pool_begin_features"):
-            if ex is not None:
-                return self.ledger.run(
-                    key, lambda: ex(self._dev_vars, f1, f2, ctx, init_flow)
-                )
-            return self.ledger.run(
-                key,
-                lambda: self._pool_progs.begin_features(
-                    self._dev_vars, f1, f2, ctx, init_flow
-                ),
-            )
+        if ex is not None:
+            return ex(self._dev_vars, f1, f2, ctx, init_flow)
+        return self._pool_progs.begin_features(
+            self._dev_vars, f1, f2, ctx, init_flow
+        )
 
     def _run_pool_step(self, state):
         """Dispatch ONE refinement iteration across all pool slots; seam.
@@ -3780,16 +3782,9 @@ class ServeEngine:
         key = ("pool_step", c.shape[0], c.shape[1], c.shape[2])
         ex = self._aot_execs.get(key)
         th, sk, mi = self._conv_thresh, self._conv_streak, self._conv_min
-        # no region of its own: _pool_tick opens "serve/pool_step" around
-        # this call and the tick's bookkeeping
         if ex is not None:
-            return self.ledger.run(
-                key, lambda: ex(self._dev_vars, state, th, sk, mi)
-            )
-        return self.ledger.run(
-            key,
-            lambda: self._pool_progs.step(self._dev_vars, state, th, sk, mi),
-        )
+            return ex(self._dev_vars, state, th, sk, mi)
+        return self._pool_progs.step(self._dev_vars, state, th, sk, mi)
 
     def _run_pool_final(self, coords1, hidden):
         """Dispatch the final-upsample stage for retiring slots; seam."""
@@ -3798,17 +3793,9 @@ class ServeEngine:
             coords1.shape[2],
         )
         ex = self._aot_execs.get(key)
-        with self._phase("serve/pool_final"):
-            if ex is not None:
-                return self.ledger.run(
-                    key, lambda: ex(self._dev_vars, coords1, hidden)
-                )
-            return self.ledger.run(
-                key,
-                lambda: self._pool_progs.final(
-                    self._dev_vars, coords1, hidden
-                ),
-            )
+        if ex is not None:
+            return ex(self._dev_vars, coords1, hidden)
+        return self._pool_progs.final(self._dev_vars, coords1, hidden)
 
     def _pool_insert(self, state, rows, idx, mask):
         """Write the admission cohort's rows into their slots — one
@@ -3820,10 +3807,8 @@ class ServeEngine:
         idx = np.asarray(idx, np.int32)
         mask = np.asarray(mask, bool)
         if ex is not None:
-            return self.ledger.run(key, lambda: ex(state, rows, idx, mask))
-        return self.ledger.run(
-            key, lambda: self._pool_progs.insert(state, rows, idx, mask)
-        )
+            return ex(state, rows, idx, mask)
+        return self._pool_progs.insert(state, rows, idx, mask)
 
     def _pool_gather(self, coords1, hidden, resid_hist, idx):
         """Pull the recurrent carry + residual history of the slots in
@@ -3831,13 +3816,8 @@ class ServeEngine:
         key = ("pool_gather", len(idx), coords1.shape[1], coords1.shape[2])
         ex = self._aot_execs.get(key)
         if ex is not None:
-            return self.ledger.run(
-                key, lambda: ex(coords1, hidden, resid_hist, idx)
-            )
-        return self.ledger.run(
-            key,
-            lambda: self._pool_progs.gather(coords1, hidden, resid_hist, idx),
-        )
+            return ex(coords1, hidden, resid_hist, idx)
+        return self._pool_progs.gather(coords1, hidden, resid_hist, idx)
 
     def _run_stream_swap(self, bucket, fmap, ctx, idx, put, warm):
         """Dispatch one cohort's cache transaction: the sessions' previous
@@ -3845,11 +3825,7 @@ class ServeEngine:
         cache = self._stream_cache
         key = ("stream_swap", fmap.shape[0], fmap.shape[1], fmap.shape[2])
         ex = self._aot_execs.get(key) or cache.programs.swap
-        with self._phase("serve/stream_swap"):
-            table, f1, c1, init = self.ledger.run(
-                key,
-                lambda: ex(cache.table(bucket), fmap, ctx, idx, put, warm),
-            )
+        table, f1, c1, init = ex(cache.table(bucket), fmap, ctx, idx, put, warm)
         cache.set_table(bucket, table)
         return f1, c1, init
 
@@ -3862,10 +3838,7 @@ class ServeEngine:
             coords1.shape[2],
         )
         ex = self._aot_execs.get(key) or cache.programs.store_flow
-        with self._phase("serve/stream_store_flow"):
-            cache.set_table(bucket, self.ledger.run(
-                key, lambda: ex(cache.table(bucket), coords1, idx, mask)
-            ))
+        cache.set_table(bucket, ex(cache.table(bucket), coords1, idx, mask))
 
     def _stream_row_spec(self, bucket):
         """Shape/dtype of ``encode_frame``'s feature map and context
@@ -3973,21 +3946,16 @@ class ServeEngine:
         ex = self._aot_execs.get(key)
         with profile.annotate("serve/pairwise"):
             if ex is not None:
-                return self.ledger.run(key, lambda: ex(self._dev_vars, p1, p2))
-            return self.ledger.run(
-                key, lambda: self._apply(self._dev_vars, p1, p2, int(iters))
-            )
+                return ex(self._dev_vars, p1, p2)
+            return self._apply(self._dev_vars, p1, p2, int(iters))
 
     def _run_encode(self, frames: np.ndarray):
         """Dispatch one frame-encode batch (stream path); seam."""
         key = ("encode", frames.shape[0], frames.shape[1], frames.shape[2])
         ex = self._aot_execs.get(key)
-        with self._phase("serve/encode"):
-            if ex is not None:
-                return self.ledger.run(key, lambda: ex(self._dev_vars, frames))
-            return self.ledger.run(
-                key, lambda: self._encode(self._dev_vars, frames)
-            )
+        if ex is not None:
+            return ex(self._dev_vars, frames)
+        return self._encode(self._dev_vars, frames)
 
     def _run_iterate(self, f1, f2, ctx, iters: int):
         """Dispatch one refinement batch from encoded features; seam."""
@@ -3995,13 +3963,8 @@ class ServeEngine:
         ex = self._aot_execs.get(key)
         with profile.annotate("serve/iterate"):
             if ex is not None:
-                return self.ledger.run(
-                    key, lambda: ex(self._dev_vars, f1, f2, ctx)
-                )
-            return self.ledger.run(
-                key,
-                lambda: self._iterate(self._dev_vars, f1, f2, ctx, int(iters)),
-            )
+                return ex(self._dev_vars, f1, f2, ctx)
+            return self._iterate(self._dev_vars, f1, f2, ctx, int(iters))
 
     def _request_flow(self, req: Request, flow: np.ndarray) -> np.ndarray:
         """Per-request output hook; the ``infer.nan_flow`` seam."""

@@ -135,13 +135,6 @@ class TrainConfig:
     # Eval-EPE regression tolerated before a checkpoint stops being tagged
     # known-good (fraction of the best EPE so far; only with eval_every).
     good_epe_slack: float = 0.2
-    # Device-time ledger (ISSUE 11, raft_tpu.obs.ledger): every Kth
-    # window dispatch runs timed — block_until_ready around the fused
-    # window step — pricing one window of device work in milliseconds
-    # (EWMA + sub-ms histogram, family 'train_window_step/<k>'). A
-    # sampled window is a deliberate host sync; 0 (default) keeps the
-    # hot loop sync-free exactly as the tripwire tests pin it.
-    ledger_sample_every: int = 0
 
     def replace(self, **kw):
         return dataclasses.replace(self, **kw)
@@ -230,11 +223,6 @@ class Trainer:
             raise ValueError(
                 f"window_size must be >= 1, got {config.window_size}"
             )
-        if config.ledger_sample_every < 0:
-            raise ValueError(
-                f"ledger_sample_every must be >= 0 (0 = off), got "
-                f"{config.ledger_sample_every}"
-            )
         if config.window_size > 1:
             # Boundaries (log, checkpoint, eval, preemption) happen only at
             # whole-window steps: a misaligned interval would silently
@@ -273,18 +261,10 @@ class Trainer:
         # spans), a metrics registry of phase histograms, and a flight
         # recorder that the stability ladder and the stall watchdog dump
         # through when they fire.
-        from raft_tpu.obs import (
-            DeviceTimeLedger, FlightRecorder, MetricsRegistry, Tracer,
-        )
+        from raft_tpu.obs import FlightRecorder, MetricsRegistry, Tracer
 
         self.metrics = MetricsRegistry("train")
         self.recorder = FlightRecorder(proc="trainer")
-        # device-time ledger (ISSUE 11): the trainer's one device family
-        # is the fused window step — every Kth window dispatch is timed
-        # (a deliberate sync; 0 keeps the loop sync-free)
-        self.ledger = DeviceTimeLedger(
-            config.ledger_sample_every, registry=self.metrics
-        )
         self.tracer = Tracer(
             1.0, capacity=64, prefix="trn",
             on_finish=self.recorder.add_trace,
@@ -946,18 +926,12 @@ class Trainer:
                     from raft_tpu.obs import profile
 
                     with profile.annotate("train/window_dispatch"):
-                        # the ledger times every Kth window dispatch end
-                        # to device-ready (family train_window_step/<k>);
-                        # off (the default) this is fn() verbatim
                         fn = (
                             self.window_fn
                             if self.window_fn is not None
                             else self.step_fn
                         )
-                        self.state, metrics = self.ledger.run(
-                            ("train_window_step", wsize),
-                            lambda: fn(self.state, batch),
-                        )
+                        self.state, metrics = fn(self.state, batch)
                 t_c = time.monotonic()
                 if wtrace is not None:
                     wtrace.add_span("data_wait", t_a, t_b)

@@ -78,15 +78,13 @@ HTTP + transport cost the engine-side SLOs undercount). Combined with
 frontend/router/transport/worker and the phase breakdown covers all
 lanes.
 
-Device time + convergence (ISSUE 11): `--ledger-sample K` turns on the
-device-time ledger (`ServeConfig.ledger_sample_every` — every Kth
-execution per program family is a timed, blocked dispatch) and emits a
-`serve_device_time` BENCH line: per-family device-ms p50/p99/EWMA and
-each family's share of estimated device time. Pool runs additionally
-emit `serve_convergence`: final-residual p50/p99 plus the
-residual-vs-iters table (mean RMS ||delta flow|| per iteration number)
-— the measured evidence base for residual-driven early exit.
-`scripts/perf_ledger.py` gates both on the BENCH trajectory.
+Convergence: pool runs emit `serve_convergence`:
+final-residual p50/p99 plus the residual-vs-iters table (mean RMS
+||delta flow|| per iteration number) — the measured evidence base for
+residual-driven early exit; `scripts/perf_ledger.py` gates it on the
+BENCH trajectory. The report's `sched` block is the pool scheduler's
+loop by phase (`stats()["sched"]`: loops, wall and CPU ms, ms and
+entries per phase).
 
 Convergence-adaptive compute (ISSUE 12): `--converge-thresh T` (with
 `--converge-streak K`) turns on residual-driven early exit
@@ -226,7 +224,6 @@ def build_config(args, **extra):
         warmup_artifact=args.warmup_artifact,
         compilation_cache_dir=args.compilation_cache_dir,
         trace_sample_rate=args.trace_sample,
-        ledger_sample_every=args.ledger_sample,
         pool_converge_thresh=args.converge_thresh,
         pool_converge_streak=args.converge_streak,
         stream_warm_start=args.warm_start,
@@ -2082,11 +2079,10 @@ def run_bench(args) -> dict:
         "trace_sample": args.trace_sample,
         "traces_collected": len(traces),
         "phase_breakdown": phase_breakdown(traces) if traces else {},
-        # device-time ledger + convergence telemetry (ISSUE 11). Behind
-        # a router these are the FIRST replica's view (per-replica device
-        # time; the aggregate would average away a slow replica)
-        "ledger_sample": args.ledger_sample,
-        "ledger": one_engine.get("ledger", {}),
+        # the scheduler's loop by phase + convergence telemetry. Behind
+        # a router these are the FIRST replica's view (the aggregate
+        # would average away a slow replica)
+        "sched": one_engine.get("sched", {}),
         "convergence": one_engine.get("convergence", {}),
         "alerts": (
             stats.get("alerts", {}) if is_router
@@ -2151,22 +2147,6 @@ def emit(report: dict, args) -> None:
             "trace_sample": report["trace_sample"],
             "traces": report["traces_collected"],
             "phases": report["phase_breakdown"],
-            "config": config,
-        }), flush=True)
-    ledger = report.get("ledger") or {}
-    if ledger.get("sampled_dispatches"):
-        print(_line({
-            "metric": "serve_device_time",
-            "sample_every": ledger.get("sample_every"),
-            "est_total_device_ms": ledger.get("est_total_device_ms"),
-            "families": {
-                name: {
-                    k: fam.get(k)
-                    for k in ("p50_ms", "p99_ms", "ewma_ms", "executions",
-                              "est_total_ms", "share")
-                }
-                for name, fam in (ledger.get("by_family") or {}).items()
-            },
             "config": config,
         }), flush=True)
     conv = report.get("convergence") or {}
@@ -2482,14 +2462,6 @@ def main(argv=None) -> dict:
                          "interleaved A/B, shadow->canary->promote "
                          "ladder, and a bad-candidate auto-rollback "
                          "arm, emitted as one serve_rollout BENCH line")
-    ap.add_argument("--ledger-sample", type=int, default=0,
-                    help="device-time ledger cadence K "
-                         "(ServeConfig.ledger_sample_every): every Kth "
-                         "execution per program family is a timed "
-                         "blocked dispatch; > 0 emits a "
-                         "serve_device_time BENCH line (and "
-                         "serve_convergence in pool mode) — the inputs "
-                         "scripts/perf_ledger.py gates on")
     args = ap.parse_args(argv)
     if args.bucket is None:
         args.bucket = "48x64" if args.tiny else "440x1024"

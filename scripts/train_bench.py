@@ -161,23 +161,6 @@ def bench_one(model, variables, args, window_size, mesh_n=1):
     dt = time.perf_counter() - t0
     boundary_syncs = tw.total - sum(tw_window.values())
 
-    # Device-time ledger (ISSUE 11): price one window dispatch in
-    # milliseconds. Runs AFTER the timed loop — a timed dispatch is a
-    # deliberate block_until_ready, which would poison the tripwire's
-    # zero-syncs-inside-windows claim above.
-    from raft_tpu.obs import DeviceTimeLedger
-
-    ledger = DeviceTimeLedger(sample_every=1)
-    lstate = state
-    for d in range(min(args.ledger_dispatches, dispatches)):
-        lstate, _ = ledger.run(
-            ("train_window_step", k, mesh_n),
-            lambda: fn(lstate, feed(d * k)),
-        )
-    ledger_fam = next(
-        iter(ledger.breakdown()["by_family"].values()), {}
-    )
-
     losses = (
         [float(m["loss"]) for m in host]
         if k == 1
@@ -195,9 +178,6 @@ def bench_one(model, variables, args, window_size, mesh_n=1):
         "boundary_syncs": boundary_syncs,
         "final_loss": losses[-1],
         "finite": bool(np.isfinite(losses).all()),
-        "window_device_ms_p50": ledger_fam.get("p50_ms"),
-        "window_device_ms_mean": ledger_fam.get("mean_ms"),
-        "window_device_samples": ledger_fam.get("sampled", 0),
     }
 
 
@@ -232,11 +212,6 @@ def main(argv=None):
                         "(1-vs-N A/B; batch size must divide by N). On "
                         "CPU, virtual devices are provisioned "
                         "automatically (ISSUE 8)")
-    p.add_argument("--ledger-dispatches", type=int, default=3,
-                   help="timed window dispatches for the device-time "
-                        "ledger line (run after the tripwire-verified "
-                        "loop; each is a deliberate block_until_ready). "
-                        "0 disables the train_device_time line")
     args = p.parse_args(argv)
     args.steps = args.steps or (32 if args.tiny else 64)
     args.batch_size = args.batch_size or (
@@ -351,17 +326,6 @@ def main(argv=None):
         emit({"metric": "train_dispatches_per_step",
               "value": round(r["dispatches_per_step"], 5),
               "unit": "dispatches/step", "config": c})
-        if r.get("window_device_samples"):
-            # the window-step ledger line (ISSUE 11): one fused window
-            # of device work, in milliseconds — perf_ledger.py gates it
-            emit({
-                "metric": "train_device_time",
-                "family": f"train_window_step/{r['window_size']}",
-                "p50_ms": r["window_device_ms_p50"],
-                "mean_ms": r["window_device_ms_mean"],
-                "samples": r["window_device_samples"],
-                "config": c,
-            })
     emit({"metric": "train_bench_report", "value": report})
     return report
 

@@ -21,7 +21,7 @@ What 1080p frames change is shapes, so the rules under test read shapes:
     say what was read.
 
 CPU, interpret mode, seeded random weights; the real widths are compiled
-for a described v5e in ``tests/test_chip_compile.py``.
+for a described v5e in the ``tests/test_chip_compile*.py`` files.
 """
 
 import dataclasses

@@ -22,7 +22,8 @@ contractive and a warm start means something):
   frame the encoders poison never pairs with the next one.
 
 The described-v5e compiles of the stream programs live in
-``tests/test_chip_compile.py`` (one file holds every such compile).
+``tests/test_chip_compile_admission.py`` and
+``tests/test_chip_compile_kernel.py`` (the 32-slot step).
 """
 
 import threading
